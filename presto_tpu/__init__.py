@@ -26,10 +26,11 @@ import jax
 # it's safe.
 jax.config.update("jax_enable_x64", True)
 
-# PRESTO_TPU_COMPILE_CACHE_DIR: persistent XLA compilation cache so worker
-# restarts warm-start their executables (exec/qcache.py). Configured at
-# import — before any compile can latch the cache uninitialized — and a
-# pure config update, so no backend is touched here.
+# Persistent XLA compilation cache so process restarts warm-start their
+# executables: at JAX_COMPILATION_CACHE_DIR when the caller sets it, else
+# at <checkout>/.jax_cache (exec/qcache.py). Configured at import — before
+# any compile can latch the cache uninitialized — and a pure config
+# update, so no backend is touched here.
 from .exec.qcache import enable_persistent_compile_cache  # noqa: E402
 
 enable_persistent_compile_cache()

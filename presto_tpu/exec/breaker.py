@@ -45,6 +45,12 @@ CLOSED = "closed"
 OPEN = "open"
 HALF_OPEN = "half_open"
 
+# What a bug in this repo raises (a name the installed JAX no longer has,
+# a typo) — not a kernel the compiler or the device refused. Dispatch
+# sites re-raise these instead of degrading: a fallback would compare the
+# XLA path with itself and nothing would say the kernel never ran.
+PROGRAMMING_ERRORS = (ImportError, AttributeError, NameError)
+
 
 class KernelCircuitBreaker:
     """One kernel's failure state machine. Thread-safe: executors on

@@ -55,23 +55,10 @@ from .executor import ExecutionError, Executor
 
 
 def _shard_map(step, mesh, in_specs, out_specs):
-    """shard_map across jax versions: jax>=0.8 exposes jax.shard_map with
-    check_vma; older releases only have the experimental home with
-    check_rep (same benchmark/micro.py compat shim)."""
-    try:
-        from jax import shard_map as _sm  # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    try:
-        return _sm(
-            step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:
-        return _sm(
-            step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    return jax.shard_map(
+        step, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
 
 
 @dataclasses.dataclass
@@ -120,6 +107,9 @@ replicated subtrees delegate to the single-node Executor."""
         # backpressure role). None = materialize whole intermediates.
         self.exchange_budget = exchange_budget
         self.exchange_events: List[dict] = []
+        # ids of the devices that held a sharded stage's output: what a
+        # run on real chips checks against the mesh width
+        self.shard_devices: set = set()
         # dynamic filters shared with the local delegate: sharded joins
         # publish, and scans (which run through local.exec_node before
         # sharding) consume (exec/dynfilter.py)
@@ -221,6 +211,7 @@ replicated subtrees delegate to the single-node Executor."""
             tuple(sp.counts for sp in spages),
             tuple(rep_pages),
         )
+        self.shard_devices.update(d.id for d in out_counts.devices())
         sp = SPage(tuple(out_leaves), out_schema, out_counts, self.n)
         return sp, tuple(extras)
 

@@ -228,7 +228,8 @@ def _key_stats(data, valid):
         sentinel = jnp.asarray(jnp.inf, data.dtype)
     else:
         sentinel = jnp.asarray(jnp.iinfo(data.dtype).max, data.dtype)
-    s = jnp.sort(jnp.where(valid, data, sentinel))
+    # one operand: stability changes nothing but the TPU compile time
+    s = jnp.sort(jnp.where(valid, data, sentinel), stable=False)
     n = jnp.sum(valid.astype(jnp.int64))
     cap = data.shape[0]
     idx = jnp.arange(cap, dtype=jnp.int64)

@@ -24,9 +24,9 @@ Three stacked caches, all process-wide and observable:
 * KERNEL_CACHE — process-wide LRU of jitted per-node kernels keyed on
   (backend, jit flag, node + static config). Promotes the per-Executor
   compile-once dict so back-to-back queries from different sessions
-  reuse traced executables. PRESTO_TPU_COMPILE_CACHE_DIR additionally
-  enables JAX's persistent compilation cache so worker restarts
-  warm-start from disk.
+  reuse traced executables. JAX's persistent compilation cache
+  (enable_persistent_compile_cache below) additionally lets process
+  restarts warm-start from disk.
 
 Validity rule shared by the plan and result caches: every entry records
 the tables it read and their connector snapshot versions AT PLAN/EXECUTE
@@ -542,47 +542,44 @@ HISTORY_CACHE = LRUCache(
     name="history",
 )
 
-_persistent_enabled = [False]
+# fixed path inside the checkout: the directory is part of the cache key,
+# so a temporary, pid- or time-derived name would never hit
+_DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+_persistent_dir: List[Optional[str]] = [None]
 
 
-def enable_persistent_compile_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    PRESTO_TPU_COMPILE_CACHE_DIR (idempotent; no-op when unset or on a
-    jax without the knob). Worker restarts then warm-start their XLA
-    executables from disk instead of re-tracing + re-compiling."""
-    cache_dir = os.environ.get("PRESTO_TPU_COMPILE_CACHE_DIR")
-    if not cache_dir or _persistent_enabled[0]:
-        return cache_dir if _persistent_enabled[0] else None
-    try:
+def enable_persistent_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache (idempotent) and return
+    its directory. A caller-given JAX_COMPILATION_CACHE_DIR is left
+    alone — JAX reads it itself and no directory is set in code; without
+    it the cache lives at the fixed in-checkout path above. Process
+    restarts then warm-start their XLA executables from disk instead of
+    re-compiling."""
+    if _persistent_dir[0] is None:
         import jax
+        from jax.experimental.compilation_cache import (
+            compilation_cache as _cc,
+        )
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update(
+                "jax_compilation_cache_dir", _DEFAULT_COMPILE_CACHE_DIR
+            )
         # cache every executable, however small/fast — dashboard-query
         # kernels are exactly the small ones the default thresholds skip
-        for knob, val in (
-            ("jax_persistent_cache_min_entry_size_bytes", 0),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — older jax: keep defaults
-                pass
-        try:
-            # a compile that ran BEFORE the dir was configured latches the
-            # cache in its initialized-without-a-backend state; reset so
-            # the next compile re-initializes against the new dir
-            from jax.experimental.compilation_cache import (
-                compilation_cache as _cc,
-            )
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — older jax: best effort
-            pass
-        _persistent_enabled[0] = True
-        return cache_dir
-    except Exception:  # noqa: BLE001 — never fail serving for a cache dir
-        return None
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # a compile that ran BEFORE the dir was configured latches the
+        # cache in its initialized-without-a-backend state; reset so the
+        # next compile re-initializes against the configured dir
+        _cc.reset_cache()
+        _persistent_dir[0] = jax.config.jax_compilation_cache_dir
+    return _persistent_dir[0]
 
 
 def snapshot_all() -> Dict[str, dict]:

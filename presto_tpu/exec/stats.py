@@ -86,7 +86,8 @@ class StatsCollector:
     int32 scalars (or lists of them) for rows_in/rows_out and parks them
     unresolved — reading a device scalar is a blocking host sync, and one
     per plan node was the dominant term in on-chip SQL wall time
-    (TPU_STATUS §4b: ~5 syncs ≈ 2.5 s around a 14 ms aggregation).
+    (2026-08-01 chip session: ~5 syncs ≈ 2.5 s around a 14 ms
+    aggregation).
     `resolve()` drains them in one batch at query end, which is when the
     EXPLAIN ANALYZE renderer needs integers anyway. Pass
     `sync_counts=True` to restore the old per-node blocking reads (then

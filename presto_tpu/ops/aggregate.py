@@ -38,7 +38,7 @@ from .. import types as T
 from ..expr.compiler import evaluate
 from ..expr.functions import Val
 from ..page import Block, Page
-from .hashing import hash_rows
+from .hashing import argsort_hashes, hash_rows
 
 SUPPORTED = (
     "count", "count_star", "sum", "min", "max", "avg", "checksum",
@@ -660,7 +660,7 @@ def grouped_aggregate_sorted(
     h = hash_rows(keys)
     # dead rows sort to the end: flip to max sentinel
     h = jnp.where(live, h, jnp.uint64(0xFFFFFFFFFFFFFFFF))
-    order = jnp.argsort(h)
+    order = argsort_hashes(h)
 
     live_s = live[order]
     keys_s = [

@@ -19,18 +19,34 @@ from ..expr.compiler import evaluate
 from ..page import Block, Page
 
 
+def kept_first_permutation(keep: jnp.ndarray) -> jnp.ndarray:
+    """Row permutation with kept rows first, each half in its original
+    order — exactly `jnp.argsort(~keep, stable=True)` — as ONE
+    single-operand sort: the key is the row id, plus `cap` for dropped
+    rows, so all keys are distinct and the sort need not be stable.
+
+    The TPU compiler's time for a sort grows with its operands and with
+    stability: compiled for a described v5e at 2M rows the stable
+    two-operand argsort took 63 s and this form 4.5 s (sandbox compile,
+    PR 22) — and every filter compiles one per page capacity."""
+    cap = keep.shape[0]
+    dtype = jnp.int32 if cap < (1 << 30) else jnp.int64
+    idx = jnp.arange(cap, dtype=dtype)
+    key = jax.lax.sort(jnp.where(keep, idx, idx + cap), is_stable=False)
+    return jnp.where(key >= cap, key - cap, key)
+
+
 def compact(page: Page, keep: jnp.ndarray) -> Page:
     """Keep rows where `keep & live`, moved to the front, count updated.
 
-    TPU note: implemented as a stable argsort on the drop flag + gathers.
-    Scatter (the obvious cumsum+scatter formulation) serializes on TPU and
-    measured ~6x slower than sort+gather at 6M rows; XLA's sort is the
-    fastest reorder primitive available."""
+    TPU note: implemented as a sort + gathers. Scatter (the obvious
+    cumsum+scatter formulation) serializes on TPU and measured ~6x slower
+    than sort+gather at 6M rows; XLA's sort is the fastest reorder
+    primitive available."""
     keep = keep & page.live_mask()
-    cap = page.capacity
     # int32 count invariant (page.py): x64 mode would promote the sum
     count = jnp.sum(keep.astype(jnp.int32)).astype(jnp.int32)
-    perm = jnp.argsort(~keep, stable=True)  # kept rows first, stable
+    perm = kept_first_permutation(keep)
     blocks = [b.take_rows(perm) for b in page.blocks]
     return Page(tuple(blocks), page.names, count)
 

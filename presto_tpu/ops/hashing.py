@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -64,6 +65,16 @@ def combine_hashes(hashes: Sequence[jnp.ndarray]):
         out = (out * jnp.uint64(31)) + h
         out = mix64(out + _GOLDEN)
     return out
+
+
+def argsort_hashes(h: jnp.ndarray) -> jnp.ndarray:
+    """`jnp.argsort(h)` (stable) for uint64 row hashes, with the row id as
+    a second sort key instead of stability: the keys are then distinct,
+    the permutation is the same, and the TPU compiler takes about half
+    the time (described v5e, 2M rows: 107 s stable, 53 s this form;
+    sandbox compile, PR 22)."""
+    idx = jnp.arange(h.shape[0], dtype=jnp.int32)
+    return jax.lax.sort((h, idx), num_keys=2, is_stable=False)[1]
 
 
 def hash_rows(columns) -> jnp.ndarray:

@@ -47,7 +47,12 @@ from .. import types as T
 from ..expr.compiler import evaluate
 from ..expr.functions import Val, and_valid
 from ..page import Block, Page
-from .hashing import hash_rows, hash_rows_values, value_hashable
+from .hashing import (
+    argsort_hashes,
+    hash_rows,
+    hash_rows_values,
+    value_hashable,
+)
 
 
 def _want_value_hash(keys, count) -> bool:
@@ -110,10 +115,10 @@ def _pick_bucket_bits(capacity: int) -> int:
 
 def build(page: Page, key_exprs):
     """Prepare a build side for probing. First choice: the linear-probe
-    hash-table layout (ops/pallas_join.py — Pallas kernels on TPU, the
-    numpy twin as the CPU engine default), behind the pallas_join_build /
-    pallas_join_probe breakers. Fallback — and the only path for traced
-    operands or cross joins — is the sorted-hash layout of build_sorted."""
+    hash-table layout (ops/pallas_join.py — the numpy twin, the CPU
+    engine default; off on TPU), behind the pallas_join_build /
+    pallas_join_probe breakers. Otherwise — on TPU, for traced operands
+    and for cross joins — the sorted-hash layout of build_sorted."""
     if key_exprs:
         from ..exec.breaker import BREAKERS
 
@@ -131,6 +136,19 @@ def build(page: Page, key_exprs):
                     BREAKERS.record_success("pallas_join_build")
                     return jt
     return build_sorted(page, key_exprs)
+
+
+def sorted_probe_layout() -> str:
+    """Which probe layout build_sorted produces now: 'directory' unless
+    PRESTO_TPU_JOIN_PROBE says otherwise or the join_probe breaker is
+    open (exec/breaker.py: a faulting directory build degrades every
+    join in the process to 'searchsorted' until the recovery window
+    elapses)."""
+    if os.environ.get("PRESTO_TPU_JOIN_PROBE", "directory") != "directory":
+        return "searchsorted"
+    from ..exec.breaker import BREAKERS
+
+    return "directory" if BREAKERS.allow("join_probe") else "searchsorted"
 
 
 def build_sorted(page: Page, key_exprs) -> BuildSide:
@@ -154,19 +172,9 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     else:
         h = hash_rows(keys)
     h = jnp.where(live, h, MAX_HASH)  # dead rows cluster at the end
-    order = jnp.argsort(h)
+    order = argsort_hashes(h)
     sh = h[order]
-    use_directory = (
-        os.environ.get("PRESTO_TPU_JOIN_PROBE", "directory") == "directory"
-    )
-    if use_directory:
-        # kernel-fault circuit breaker (exec/breaker.py): a faulting
-        # directory build degrades every join in the process to the
-        # searchsorted probe until the recovery window elapses
-        from ..exec.breaker import BREAKERS
-
-        use_directory = BREAKERS.allow("join_probe")
-    if not use_directory:
+    if sorted_probe_layout() != "directory":
         # chip-diagnosis escape hatch / open breaker: searchsorted probe
         return BuildSide(
             sh, order, page, tuple(keys), page.count,

@@ -378,7 +378,7 @@ class HttpScheduler:
         )
         # wall ceiling on any single task's results stream: a wedged
         # worker (RUNNING forever, producing nothing) fails the pull
-        # instead of hanging the coordinator — the round-5 relay stall
+        # instead of hanging the coordinator
         self.task_deadline = (
             knobs.task_deadline_s()
             if task_deadline is None else task_deadline

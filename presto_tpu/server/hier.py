@@ -173,11 +173,6 @@ def _collective_regroup_fn(n_dev: int, nparts: int, names: Tuple[str, ...]):
         return fn
     import jax
     import jax.numpy as jnp
-
-    try:
-        from jax import shard_map  # jax >= 0.8 home
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.exchange import all_to_all_page, shuffle_write_parts
@@ -217,10 +212,7 @@ def _collective_regroup_fn(n_dev: int, nparts: int, names: Tuple[str, ...]):
         in_specs=(P(axis), P(axis)),
         out_specs=(P(axis), P(axis), P(axis)),
     )
-    try:
-        smapped = shard_map(shard_fn, check_vma=False, **kw)
-    except TypeError:
-        smapped = shard_map(shard_fn, check_rep=False, **kw)
+    smapped = jax.shard_map(shard_fn, check_vma=False, **kw)
     fn = jax.jit(smapped)
     _COLLECTIVE_CACHE[key] = fn
     return fn

@@ -1389,8 +1389,8 @@ def _pull_buffer(uri: str, task_id: str, buffer_id: int, ack: bool = True,
 
     `deadline` caps the wall time between PAGES (a progress deadline): a
     wedged producer (RUNNING forever, producing nothing) must fail the
-    pull — retryably — instead of hanging its consumer forever (the
-    round-5 relay stall). None reads PRESTO_TPU_TASK_DEADLINE_S
+    pull — retryably — instead of hanging its consumer forever. None
+    reads PRESTO_TPU_TASK_DEADLINE_S
     (default 600)."""
     from .exchange import ack_pages, fetch_pages
 

@@ -32,6 +32,26 @@ def test_compact():
     assert int(out.count) == 4
 
 
+@pytest.mark.parametrize("n", [1, 7, 128, 5000])
+def test_sort_forms_match_the_stable_argsorts(n):
+    """The compile-cheap sort forms (distinct keys, unstable sort) give
+    exactly the permutations of the stable argsorts they replace."""
+    from presto_tpu.ops.filter import kept_first_permutation
+    from presto_tpu.ops.hashing import argsort_hashes
+
+    rng = np.random.default_rng(n)
+    keep = jnp.asarray(rng.random(n) < 0.3)
+    np.testing.assert_array_equal(
+        kept_first_permutation(keep), jnp.argsort(~keep, stable=True)
+    )
+    # few distinct hashes -> long tie runs; then a dead-row sentinel run
+    h = jnp.asarray(rng.integers(0, 50, n).astype(np.uint64) << np.uint64(58))
+    for hashes in (h, h.at[: n // 2].set(np.uint64(0xFFFFFFFFFFFFFFFF))):
+        np.testing.assert_array_equal(
+            argsort_hashes(hashes), jnp.argsort(hashes, stable=True)
+        )
+
+
 def test_filter_page():
     p = Page.from_dict({"a": np.arange(10, dtype=np.int64)}, pad_to=16)
     out = filter_page(p, comparison("ge", col("a", T.BIGINT), lit(7)))
