@@ -1,0 +1,7 @@
+"""Host orchestration: mean `execute` span wall (session.py)."""
+
+from stats import mean_span_ms
+
+
+def compute(run):
+    return mean_span_ms(run.records, "execute")
