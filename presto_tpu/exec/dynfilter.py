@@ -50,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import types as T
+from ..obs.span import host_read
 from ..ops.bloomfilter import (
     bloom_build,
     bloom_build_host,
@@ -265,14 +266,14 @@ def derive_filter(val, live: jnp.ndarray) -> Optional[DynamicFilter]:
         valid = valid & ~jnp.isnan(data)
 
     n_d, ndv_d, s = _key_stats(data, valid)
-    n, ndv = (int(x) for x in jax.device_get((n_d, ndv_d)))
+    n, ndv = (int(host_read(x)) for x in (n_d, ndv_d))
     if n == 0:
         return DynamicFilter(
             "minmax", typ, 0, empty_build=True
         )
     lo = s[0]
     hi = jnp.max(jnp.where(valid, data, s[0]))
-    lo_h, hi_h = jax.device_get((lo, hi))
+    lo_h, hi_h = host_read(lo), host_read(hi)
     if ndv <= in_list_limit():
         boundary = jnp.concatenate([jnp.ones(1, jnp.bool_), s[1:] != s[:-1]])
         pos = jnp.nonzero(boundary, size=ndv, fill_value=0)[0]
@@ -280,7 +281,7 @@ def derive_filter(val, live: jnp.ndarray) -> Optional[DynamicFilter]:
         return DynamicFilter(
             "inlist", typ, n, lo=lo, hi=hi, values=values,
             lo_host=lo_h, hi_host=hi_h,
-            values_host=np.asarray(jax.device_get(values)),
+            values_host=host_read(values),
         )
     log2_bits = choose_log2_bits(ndv)
     words = bloom_build(hash_column(data), valid, log2_bits)
@@ -308,7 +309,7 @@ def _derive_varchar(val, valid) -> Optional[DynamicFilter]:
         .at[jnp.where(valid, jnp.clip(val.data, 0, nbits - 1), nbits)]
         .set(True)
     )
-    seen_h = np.asarray(jax.device_get(seen[:nbits]))
+    seen_h = host_read(seen[:nbits])
     n = int(seen_h.sum())
     if n == 0:
         return DynamicFilter("minmax", val.type, 0, empty_build=True)
@@ -404,9 +405,9 @@ class HostFilterAccumulator:
                 self.unsupported = True
             return
         b = page.block(self.channel)
-        n = int(page.count)
-        data = np.asarray(b.data[:n])
-        valid = None if b.valid is None else np.asarray(b.valid[:n])
+        n = int(host_read(page.count))
+        data = host_read(b.data[:n])
+        valid = None if b.valid is None else host_read(b.valid[:n])
         self.add_numpy(data, valid, b.type)
 
     def add_numpy(self, data: np.ndarray, valid, typ) -> None:

@@ -30,6 +30,8 @@ from ..ops.filter import compact, filter_page
 from ..ops.join import build, join_expand, join_n1, sorted_probe_layout
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
 from ..expr.compiler import project_page
+from ..obs.span import current as current_span
+from ..obs.span import host_read
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
 
@@ -90,11 +92,14 @@ class Executor:
         # masks never repeat across batches/workers (ops/filter.py)
         self._sample_pos: Dict[int, int] = {}
         self.sample_salt = 0
+        self._inputs_wall_s = 0.0  # _run: walls of the inputs run so far
 
-    def _kernel(self, key, make_fn):
+    def _kernel(self, name, key, make_fn):
         """Compile-once cache for per-node kernels. jax.jit retraces per
         input shape bucket automatically; `key` carries the static config
-        (the node itself plus capacity-like ints). The store is the
+        (the node itself plus capacity-like ints); `name` is the call
+        site's (XLA calls the program `jit_<name>`, and a profile lists
+        device time under it). The store is the
         process-wide bounded LRU in exec/qcache.py, keyed additionally on
         (backend, jit) — kernels close over plan-node config only, never
         the catalog, so cross-executor reuse is sound."""
@@ -119,18 +124,19 @@ class Executor:
             # the pre-cache behavior (one session reuses its own trace).
             fn = self._local_kernels.get(key)
             if fn is None:
-                fn = self._build_kernel(make_fn)
+                fn = self._build_kernel(name, make_fn)
                 self._local_kernels[key] = fn
             return fn
         gkey = (self._backend, self.jit, key)
         fn = KERNEL_CACHE.get(gkey)
         if fn is None:
-            fn = self._build_kernel(make_fn)
+            fn = self._build_kernel(name, make_fn)
             KERNEL_CACHE.put(gkey, fn)
         return fn
 
-    def _build_kernel(self, make_fn):
-        """Cache-fill: jit (compilation itself is lazy, paid at the first
+    def _build_kernel(self, name, make_fn):
+        """Cache-fill: name the function for its call site, jit
+        (compilation itself is lazy, paid at the first
         call) and, when the observability plane is on, wrap in the
         compile-vs-execute profiler. The wrapper is stored in the cache
         so "first call" stays attached to the entry's lifetime; it is
@@ -138,13 +144,17 @@ class Executor:
         classifies faults by the escaping exception)."""
         from ..obs.kernelprof import KERNEL_PROFILE, profiling_enabled
 
-        fn = jax.jit(make_fn()) if self.jit else make_fn()
+        fn = make_fn()
+        fn.__name__ = fn.__qualname__ = name
+        if self.jit:
+            fn = jax.jit(fn)
         if profiling_enabled():
             fn = KERNEL_PROFILE.wrap(fn)
         return fn
 
-    def _kernel_guarded(self, breaker_name, key, make_fn, *args):
-        """Run a jitted kernel under a kernel-fault circuit breaker
+    def _kernel_guarded(self, breaker_name, name, key, make_fn, *args):
+        """Run a jitted kernel (`name`, `key`, `make_fn`: as `_kernel`)
+        under a kernel-fault circuit breaker
         (exec/breaker.py). The op layer consults `BREAKERS.allow(name)`
         at TRACE time to pick the experimental path vs. the safe XLA
         composition, so the breaker decision is part of the cache key.
@@ -165,7 +175,9 @@ class Executor:
                 ctx = BREAKERS.forced_fallback(breaker_name)
             with ctx:
                 try:
-                    fn = self._kernel((key, breaker_name, allowed), make_fn)
+                    fn = self._kernel(
+                        name, (key, breaker_name, allowed), make_fn
+                    )
                     out = fn(*args)
                 except Exception as exc:
                     if attempt == 0 and allowed:
@@ -194,20 +206,24 @@ class Executor:
         return self.run(node).to_pylist()
 
     # -- dispatch --
-    def _run_children(self, node: N.PlanNode) -> List[Page]:
+    def _run_children(self, node: N.PlanNode, pos=None) -> List[Page]:
         """Execute a node's children — BUILD SIDE FIRST for dynamic-filter
         joins, so the derived filter is published before the probe side's
         scans run (the single-process analog of the reference's
-        LocalDynamicFiltersCollector ordering)."""
+        LocalDynamicFiltersCollector ordering). `pos` is the node's
+        position in the plan where spans are on (`_run`), else None."""
         if (
             isinstance(node, (N.Join, N.SemiJoin))
             and getattr(node, "dynamic_filters", ())
         ):
-            build = self._run(node.children[1])
+            build = self._run(node.children[1], pos and pos + ".1")
             self._publish_dynamic_filters(node, build)
-            probe = self._run(node.children[0])
+            probe = self._run(node.children[0], pos and pos + ".0")
             return [probe, build]
-        return [self._run(c) for c in node.children]
+        return [
+            self._run(c, pos and f"{pos}.{i}")
+            for i, c in enumerate(node.children)
+        ]
 
     def _star_spec(self, node: N.PlanNode):
         """The inner Join of a fusable star shape: two stacked inner n1
@@ -242,7 +258,7 @@ class Executor:
             return None
         return inner
 
-    def _run_star_join(self, node: N.Join, inner: N.Join) -> Page:
+    def _run_star_join(self, node: N.Join, inner: N.Join, pos=None) -> Page:
         """Fused multiway execution of a star pair; an ineligible side
         degrades to plain nested execution on the pages already run
         (materialized plan results — nothing is consume-once; the
@@ -250,13 +266,13 @@ class Executor:
         idempotent re-filter)."""
         from ..ops.pallas_join import table_multiway_n1
 
-        dim1 = self._run(inner.right)
+        dim1 = self._run(inner.right, pos and pos + ".0.1")
         if getattr(inner, "dynamic_filters", ()):
             self._publish_dynamic_filters(inner, dim1)
-        dim2 = self._run(node.right)
+        dim2 = self._run(node.right, pos and pos + ".1")
         if getattr(node, "dynamic_filters", ()):
             self._publish_dynamic_filters(node, dim2)
-        fact = self._run(inner.left)
+        fact = self._run(inner.left, pos and pos + ".0.0")
         if getattr(inner, "dynamic_filters", ()):
             fact = self._apply_preprobe(inner, fact)
         if getattr(node, "dynamic_filters", ()):
@@ -293,53 +309,79 @@ class Executor:
         )
         return self._shrink(out, node)
 
-    def _run(self, node: N.PlanNode) -> Page:
-        inner = self._star_spec(node)
-        if inner is not None:
-            if self.collector is None:
-                return self._run_star_join(node, inner)
-            import time
-
-            from .stats import page_device_bytes
-
-            t0 = time.perf_counter()
-            out = self._run_star_join(node, inner)
-            # fused execution: the outer node carries the pair's stats
-            # (child scans/builds record their own rows via self._run)
-            self.collector.record(
-                node, time.perf_counter() - t0, [], out.count,
-                page_device_bytes(out),
-            )
-            return out
-        pages = self._run_children(node)
-        if self.collector is None:
-            return self.exec_node(node, *pages)
+    def _run(self, node: N.PlanNode, pos=None) -> Page:
+        """One plan node: its inputs, then the node. Where a trace is
+        open on this thread (obs/span.py; never under PRESTO_TPU_TRACE=0)
+        the node runs inside a span named by its class, nested as the
+        plan is, with its position in the plan (`0.1.0`: child indices
+        from the root) and its strategy note as attributes; what the
+        node books (`host_read`, compiles) lands on it. The span's two
+        clock readings are the node's one timing site: with a collector
+        (EXPLAIN ANALYZE) they give its wall too."""
+        cur = current_span()
+        collector = self.collector
+        if cur is None and collector is None:
+            return self._run_node(node, None)[0]
         import time
 
-        from .stats import page_device_bytes
-
-        sync = getattr(self.collector, "sync_counts", True)
-        if sync:
-            rows_in = sum(int(p.count) for p in pages)
-        retries_before = self._retries
-        t0 = time.perf_counter()
-        out = self.exec_node(node, *pages)
-        if sync:
-            rows_out = int(out.count)  # blocks until the kernel finishes
+        span = t0 = None
+        if cur is not None:
+            pos = pos or "0"
+            span = cur[0].enter(type(node).__name__, pos=pos)
         else:
-            # keep row counts as device scalars — each int() here is a
-            # blocking host round trip per plan node (the cost PR-1's
-            # _shrink already avoids); the collector resolves
-            # them in one batch at query end. Wall then measures dispatch
-            # + any syncs the node itself performs.
-            rows_in = [p.count for p in pages]
-            rows_out = out.count
-        wall = time.perf_counter() - t0
-        self.collector.record(
-            node, wall, rows_in, rows_out, page_device_bytes(out),
-            self._retries - retries_before,
-        )
+            t0 = time.perf_counter()
+        if collector is not None:
+            # the inputs' walls, to take off this node's: one executor
+            # serves one EXPLAIN ANALYZE (session._collector_executor)
+            below, self._inputs_wall_s = self._inputs_wall_s, 0.0
+        try:
+            out, pages, retries = self._run_node(node, pos)
+            if collector is not None:
+                rows_in, rows_out = self._row_counts(pages, out)
+        except BaseException:
+            if span is not None:
+                cur[0].leave(span, "error")
+            raise
+        if span is not None:
+            wall = cur[0].leave(span).wall_s
+        else:
+            wall = time.perf_counter() - t0
+        if collector is not None:
+            from .stats import page_device_bytes
+
+            collector.record(
+                node, max(0.0, wall - self._inputs_wall_s), rows_in,
+                rows_out, page_device_bytes(out), retries,
+            )
+            self._inputs_wall_s = below + wall
         return out
+
+    def _run_node(self, node: N.PlanNode, pos):
+        """(output, input pages, adaptive re-runs of this node)."""
+        inner = self._star_spec(node)
+        if inner is not None:
+            # fused execution: the outer node carries the pair's stats
+            # (child scans/builds record their own rows via self._run)
+            return self._run_star_join(node, inner, pos), [], 0
+        pages = self._run_children(node, pos)
+        retries_before = self._retries
+        out = self.exec_node(node, *pages)
+        return out, pages, self._retries - retries_before
+
+    def _row_counts(self, pages, out: Page):
+        """A node's rows in and out for the collector."""
+        if getattr(self.collector, "sync_counts", True):
+            # blocks until the kernel finishes
+            return (
+                sum(int(host_read(p.count)) for p in pages),
+                int(host_read(out.count)),
+            )
+        # keep row counts as device scalars — each int() here is a
+        # blocking host round trip per plan node (the cost PR-1's
+        # _shrink already avoids); the collector resolves
+        # them in one batch at query end. Wall then measures dispatch
+        # + any syncs the node itself performs.
+        return [p.count for p in pages], out.count
 
     def exec_node(self, node: N.PlanNode, *pages: Page) -> Page:
         """Apply one plan node to already-materialized input pages — the
@@ -363,7 +405,7 @@ class Executor:
             est = self._est_rows(node)
             if est is not None and est >= 0.5 * page.capacity:
                 return page  # expected near-full: skip the sync
-        n = int(page.count)
+        n = int(host_read(page.count))
         cap = round_capacity(max(n, 1))
         if cap >= page.capacity:
             return page
@@ -486,12 +528,12 @@ class Executor:
                 # cheap pack arithmetic is noise.
                 fn = make_fn()
             else:
-                fn = self._kernel((node, label, plan), make_fn)
+                fn = self._kernel(label, (node, label, plan), make_fn)
             out, ok = fn(page)
         except Exception as exc:  # noqa: BLE001 — degrade, don't fail
             BREAKERS.record_failure(breaker_name, repr(exc))
             return None
-        if ok is not None and not bool(ok):
+        if ok is not None and not bool(host_read(ok)):
             self._strategy_note(
                 node, f"keypack={plan.strategy}->legacy(range)"
             )
@@ -647,7 +689,7 @@ class Executor:
 
         keep = keep & page.live_mask()
         if jax.default_backend() == "cpu":
-            nz = np.flatnonzero(np.asarray(keep))
+            nz = np.flatnonzero(host_read(keep))
             n = int(nz.size)
             cap = round_capacity(max(n, 1))
             idx = np.zeros(cap, np.int64)
@@ -662,7 +704,7 @@ class Executor:
                 n,
             )
         out = compact(page, keep)
-        n = int(out.count)
+        n = int(host_read(out.count))
         cap = round_capacity(max(n, 1))
         if cap < out.capacity:
             idx = slice(0, cap)
@@ -697,7 +739,7 @@ class Executor:
                 )
                 m = df.mask(val)
                 keep = m if keep is None else (keep & m)
-            before = int(page.count)
+            before = int(host_read(page.count))
             out, n = self._dyn_compact(page, keep)
             pruned = before - n
         except Exception as exc:  # noqa: BLE001 — degrade, don't fail
@@ -799,7 +841,7 @@ class Executor:
         from ..ops.unnest import unnest_page
 
         fn = self._kernel(
-            node,
+            "unnest", node,
             lambda: lambda p: unnest_page(
                 p, node.array_exprs, node.elem_channels,
                 node.ordinality_channel,
@@ -822,7 +864,7 @@ class Executor:
             (self.sample_salt + pos) & 0xFFFFFFFFFFFFFFFF, jnp.uint64
         )
         fn = self._kernel(
-            node,
+            "sample", node,
             lambda: lambda p, off: sample_page(
                 p, node.fraction, node.seed, off
             ),
@@ -835,7 +877,9 @@ class Executor:
             for fid, _ch in node.dynamic_filters
         ):
             return self._exec_filter_dyn(node, page)
-        fn = self._kernel(node, lambda: lambda p: filter_page(p, node.predicate))
+        fn = self._kernel(
+            "filter", node, lambda: lambda p: filter_page(p, node.predicate)
+        )
         return self._shrink(fn(page), node)
 
     def _exec_filter_dyn(self, node: N.Filter, page: Page) -> Page:
@@ -866,11 +910,12 @@ class Executor:
             live_keep = keep & page.live_mask()
             would_keep = jnp.sum(live_keep.astype(jnp.int32))
             out, n = self._dyn_compact(page, live_keep & dmask)
-            pruned = int(would_keep) - n
+            pruned = int(host_read(would_keep)) - n
         except Exception as exc:  # noqa: BLE001 — degrade, don't fail
             BREAKERS.record_failure("dynamic_filter", repr(exc))
             fn = self._kernel(
-                node, lambda: lambda p: filter_page(p, node.predicate)
+                "filter", node,
+                lambda: lambda p: filter_page(p, node.predicate),
             )
             return self._shrink(fn(page), node)
         BREAKERS.record_success("dynamic_filter")
@@ -882,7 +927,8 @@ class Executor:
 
     def _exec_project(self, node: N.Project, page: Page) -> Page:
         fn = self._kernel(
-            node, lambda: lambda p: project_page(p, node.exprs, node.names)
+            "project", node,
+            lambda: lambda p: project_page(p, node.exprs, node.names),
         )
         return fn(page)
 
@@ -893,7 +939,12 @@ class Executor:
     def _strategy_note(self, node, name: str) -> None:
         """Record which aggregation strategy ran (EXPLAIN ANALYZE
         surfaces it — the 4-strategy choice is the engine's hottest
-        decision and should be observable, not guessed)."""
+        decision and should be observable, not guessed): on the node's
+        span, which is this thread's innermost while the node executes,
+        and in the collector's stats."""
+        cur = current_span()
+        if cur is not None:
+            cur[1].attrs["strategy"] = name
         if self.collector is not None:
             self.collector.stats_for(node).detail = f"strategy={name}"
 
@@ -901,7 +952,7 @@ class Executor:
     def _exec_aggregate(self, node: N.Aggregate, page: Page) -> Page:
         if not node.group_exprs:
             fn = self._kernel(
-                node,
+                "global_aggregate", node,
                 lambda: lambda p: global_aggregate(p, node.aggs, node.mask),
             )
             return fn(page)
@@ -970,20 +1021,20 @@ class Executor:
         while True:
             mg, me = max_groups, max_elems
             fn = self._kernel(
-                (node, mg, me),
+                "grouped_aggregate_sorted", (node, mg, me),
                 lambda: lambda p: grouped_aggregate_sorted(
                     p, node.group_exprs, node.group_names, node.aggs, mg,
                     node.mask, max_elems=me,
                 ),
             )
             out = fn(page)
-            true_groups = int(out.count)
+            true_groups = int(host_read(out.count))
             if true_groups > max_groups:
                 max_groups = round_capacity(true_groups)
                 self._retries += 1
                 continue
             if "$collect_need" in out.names:
-                need = int(out.block("$collect_need").data[0])
+                need = int(host_read(out.block("$collect_need").data[0]))
                 if need > max_elems:
                     max_elems = round_capacity(need)
                     self._retries += 1
@@ -1046,7 +1097,7 @@ class Executor:
             from ..ops.sort import distinct_packed
 
             out = self._run_packed(
-                node, "keypack_distinct", "pdistinct",
+                node, "keypack_distinct", "distinct_packed",
                 lambda: lambda p: distinct_packed(p, plan),
                 page, plan,
             )
@@ -1075,7 +1126,9 @@ class Executor:
                 self._strategy_note(node, "mxu-occupancy")
                 return self._shrink(out, node)
         self._strategy_note(node, "hash-sort")
-        fn = self._kernel(node, lambda: lambda p: distinct_page(p, p.capacity))
+        fn = self._kernel(
+            "distinct", node, lambda: lambda p: distinct_page(p, p.capacity)
+        )
         return self._shrink(fn(page), node)
 
     # -- joins --
@@ -1143,9 +1196,10 @@ class Executor:
                     [(n, n) for n in right_names], out_capacity=cap,
                     kind=node.kind,
                 )
-                if int(overflow) == 0:
+                over = int(host_read(overflow))
+                if over == 0:
                     break
-                cap = round_capacity(cap + int(overflow))
+                cap = round_capacity(cap + over)
                 self._retries += 1
         if node.residual is not None:
             if node.kind != "inner":
@@ -1168,6 +1222,7 @@ class Executor:
         if node.unique_build:
             out = self._kernel_guarded(
                 "join_probe",
+                "join_n1",
                 (node, "n1"),
                 lambda: lambda l, r: join_n1(
                     l,
@@ -1197,6 +1252,7 @@ class Executor:
             c = cap
             out, overflow = self._kernel_guarded(
                 "join_probe",
+                "join_expand",
                 (node, "expand", c),
                 lambda: lambda l, r: join_expand(
                     l,
@@ -1209,9 +1265,10 @@ class Executor:
                 ),
                 left, right,
             )
-            if int(overflow) == 0:
+            over = int(host_read(overflow))
+            if over == 0:
                 break
-            cap = round_capacity(cap + int(overflow))
+            cap = round_capacity(cap + over)
             self._retries += 1
         if node.residual is not None:
             if node.kind != "inner":
@@ -1256,9 +1313,10 @@ class Executor:
                 out_capacity=cap,
                 kind="inner",
             )
-            if int(overflow) == 0:
+            over = int(host_read(overflow))
+            if over == 0:
                 break
-            cap = round_capacity(cap + int(overflow))
+            cap = round_capacity(cap + over)
             self._retries += 1
         matched = (
             filter_page(expanded, node.residual)
@@ -1338,8 +1396,8 @@ class Executor:
                 # the collision scan's while_loop re-traces and compiles
                 # on every execution of the statement.
                 out = self._kernel_guarded(
-                    "join_probe", (node, "semi"), lambda: probe_fn,
-                    probe, source,
+                    "join_probe", "semi_join", (node, "semi"),
+                    lambda: probe_fn, probe, source,
                 )
             else:
                 out = probe_fn(probe, source)
@@ -1365,9 +1423,10 @@ class Executor:
                 out_capacity=cap,
                 kind="inner",
             )
-            if int(overflow) == 0:
+            over = int(host_read(overflow))
+            if over == 0:
                 break
-            cap = round_capacity(cap + int(overflow))
+            cap = round_capacity(cap + over)
             self._retries += 1
         matched = filter_page(expanded, node.residual)
         matched = self._shrink(matched, node)
@@ -1421,7 +1480,7 @@ class Executor:
         return out
 
     def _exec_scalarapply(self, node: N.ScalarApply, page: Page, sub: Page) -> Page:
-        n = int(sub.count)
+        n = int(host_read(sub.count))
         if n > 1:
             raise ExecutionError("scalar subquery returned more than one row")
         cap = page.capacity
@@ -1456,7 +1515,7 @@ class Executor:
             from ..ops.window import window_op_packed
 
             out = self._run_packed(
-                node, "keypack_window", "pwindow",
+                node, "keypack_window", "window_packed",
                 lambda: lambda p: window_op_packed(
                     p, node.partition_exprs, node.order_keys, node.funcs,
                     plan,
@@ -1466,7 +1525,7 @@ class Executor:
             if out is not None:
                 return out
         fn = self._kernel(
-            node,
+            "window", node,
             lambda: lambda p: window_op(
                 p, node.partition_exprs, node.order_keys, node.funcs
             ),
@@ -1480,7 +1539,7 @@ class Executor:
             from ..ops.sort import sort_page_packed
 
             out = self._run_packed(
-                node, "keypack_sort", "psort",
+                node, "keypack_sort", "sort_packed",
                 lambda: lambda p: sort_page_packed(p, node.keys, plan),
                 page, plan,
             )
@@ -1488,6 +1547,7 @@ class Executor:
                 return out
         return self._kernel_guarded(
             "fused_sort",
+            "sort",
             (node, "sort"),
             lambda: lambda p: sort_page(p, node.keys),
             page,
@@ -1499,7 +1559,7 @@ class Executor:
             from ..ops.sort import top_n_packed
 
             out = self._run_packed(
-                node, "keypack_topn", "ptopn",
+                node, "keypack_topn", "top_n_packed",
                 lambda: lambda p: top_n_packed(
                     p, node.keys, node.count, plan
                 ),
@@ -1508,7 +1568,7 @@ class Executor:
             if out is not None:
                 return out
         fn = self._kernel(
-            node, lambda: lambda p: top_n(p, node.keys, node.count)
+            "top_n", node, lambda: lambda p: top_n(p, node.keys, node.count)
         )
         return fn(page)
 

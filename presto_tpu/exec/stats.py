@@ -108,9 +108,11 @@ class StatsCollector:
 
     @staticmethod
     def _count(x) -> int:
+        from ..obs.span import host_read
+
         if isinstance(x, (list, tuple)):
-            return sum(int(v) for v in x)
-        return int(x)
+            return sum(int(host_read(v)) for v in x)
+        return int(host_read(x))
 
     def record(self, node, wall_s: float, rows_in, rows_out,
                out_bytes: int, retries: int = 0) -> None:

@@ -18,25 +18,49 @@ status polled mid-flight (end=None) is upgraded by the final poll.
 Timebase is time.time() so coordinator and worker spans align on the
 wall clock; durations of remote spans are computed remotely, so clock
 skew shifts placement, not length.
+
+The served single-process path (docs/observability.md) keeps, per
+thread, the innermost open span: `Trace.enter` / `Trace.leave` open and
+close a span under it, hold a `jax.profiler.TraceAnnotation` named
+`presto.<span name>` for as long (so a profiler session has the same
+tree on the device trace's clock), and are where the counters live:
+`host_read` and the compile listener add to the thread's innermost
+span, `leave` folds a span's counters into its parent, so every span
+carries its subtree's totals.
+`begin` / `finish` stay the thread-free primitives the cluster path and
+the cross-thread `statement` / `queued` spans use.
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 import uuid
 from collections import OrderedDict
 from typing import Dict, Iterable, List, Optional, Tuple
 
+import jax
+import numpy as np
+from jax.profiler import TraceAnnotation
 
-def _new_id(n: int = 16) -> str:
-    return uuid.uuid4().hex[:n]
+
+# ids: this process's random prefix and a counter. Unique across the
+# fleet like the uuid4 slices they replace (workers' spans merge into the
+# coordinator's tree by id), without a read of the kernel's random pool
+# for every span of every statement.
+_ID_PREFIX = uuid.uuid4().hex[:8]
+_ids = itertools.count(1)
+
+
+def _new_id() -> str:
+    return f"{_ID_PREFIX}{next(_ids):08x}"
 
 
 class Span:
     __slots__ = (
         "name", "trace_id", "span_id", "parent_id", "start", "end",
-        "status", "attrs",
+        "status", "attrs", "counters", "_outer", "_annotation",
     )
 
     def __init__(self, name: str, trace_id: str, span_id: str,
@@ -49,6 +73,12 @@ class Span:
         self.end: Optional[float] = None
         self.status = "ok"
         self.attrs: Dict[str, object] = {}
+        # own bookings plus the folded totals of the children that have
+        # closed; `finish` copies them into `attrs`
+        self.counters: Dict[str, float] = {}
+        # enter(): the (Trace, Span) that was the thread's innermost
+        self._outer: Optional[tuple] = None
+        self._annotation = None
 
     @property
     def wall_s(self) -> float:
@@ -74,19 +104,25 @@ class Trace:
     trace's lock (begin/finish/add_remote), so status-poll merges from
     puller threads and the coordinator's own phase spans never race."""
 
-    def __init__(self, trace_id: Optional[str] = None):
+    def __init__(self, trace_id: Optional[str] = None,
+                 query_id: Optional[str] = None):
         self.trace_id = trace_id or _new_id()
+        # the served statement's id (server/state.py); TraceStore finds a
+        # trace by it and the profiler annotations carry it
+        self.query_id = query_id
+        self._mark = {} if query_id is None else {"query_id": query_id}
         self._lock = threading.Lock()
         self._spans: "OrderedDict[str, Span]" = OrderedDict()
 
     # -- recording --
 
     def begin(self, name: str, parent: Optional[Span] = None,
-              parent_id: Optional[str] = None, **attrs) -> Span:
+              parent_id: Optional[str] = None,
+              start: Optional[float] = None, **attrs) -> Span:
         span = Span(
-            name, self.trace_id, _new_id(12),
+            name, self.trace_id, _new_id(),
             parent.span_id if parent is not None else parent_id,
-            time.time(),
+            time.time() if start is None else start,
         )
         if attrs:
             span.attrs.update(attrs)
@@ -99,26 +135,40 @@ class Trace:
             if span.end is None:
                 span.end = time.time()
             span.status = status
+            if span.counters:
+                span.attrs.update(span.counters)
             if attrs:
                 span.attrs.update(attrs)
         return span
 
-    def add_synthetic(self, name: str, parent: Optional[Span],
-                      wall_s: float, status: str = "ok", **attrs) -> Span:
-        """A span with a known duration but no live start/stop — used to
-        graft per-node EXPLAIN ANALYZE stats into the same tree shape
-        the cluster path ships."""
-        now = time.time()
-        span = Span(
-            name, self.trace_id, _new_id(12),
-            parent.span_id if parent is not None else None,
-            now - max(0.0, wall_s),
-        )
-        span.end = now
-        span.status = status
-        span.attrs.update(attrs)
-        with self._lock:
-            self._spans[span.span_id] = span
+    def enter(self, name: str, **attrs) -> Span:
+        """`begin` under the calling thread's innermost open span, which
+        the new span then is until `leave`, on the SAME thread, gives
+        the place back. Held inside a profiler annotation
+        `presto.<name>`: no cost beyond a flag test while no profiler
+        session runs."""
+        outer = getattr(_OPEN, "cur", None)
+        parent = outer[1] if outer is not None and outer[0] is self else None
+        span = self.begin(name, parent=parent, **attrs)
+        span._outer = outer
+        span._annotation = TraceAnnotation("presto." + name, **self._mark)
+        span._annotation.__enter__()
+        _OPEN.cur = (self, span)
+        return span
+
+    def leave(self, span: Span, status: str = "ok", **attrs) -> Span:
+        """Close a span `enter` opened and fold its counters into the
+        span it was opened under."""
+        span._annotation.__exit__(None, None, None)
+        span._annotation = None
+        self.finish(span, status, **attrs)
+        outer, span._outer = span._outer, None
+        _OPEN.cur = outer
+        if span.counters and outer is not None and outer[0] is self:
+            with self._lock:
+                into = outer[1].counters
+                for k, v in span.counters.items():
+                    into[k] = into.get(k, 0) + v
         return span
 
     def add_remote(self, span_dicts: Iterable[dict]) -> int:
@@ -175,15 +225,23 @@ class Trace:
     def exclusive_walls(self) -> List[Tuple[Span, float]]:
         """(span, wall minus children's wall) — the time a span spent
         NOT delegated further down the tree, the critical-path unit."""
+        return self._exclusive(lambda s: s.wall_s)
+
+    def exclusive(self, counter: str) -> List[Tuple[Span, float]]:
+        """(span, its own bookings of `counter`): a closed span's
+        `attrs[counter]` is its subtree's total, as its wall is."""
+        return self._exclusive(lambda s: s.attrs.get(counter, 0))
+
+    def _exclusive(self, value) -> List[Tuple[Span, float]]:
         spans = self.spans()
         child_sum: Dict[str, float] = {}
         for s in spans:
             if s.parent_id is not None:
                 child_sum[s.parent_id] = (
-                    child_sum.get(s.parent_id, 0.0) + s.wall_s
+                    child_sum.get(s.parent_id, 0) + value(s)
                 )
         return [
-            (s, max(0.0, s.wall_s - child_sum.get(s.span_id, 0.0)))
+            (s, max(0, value(s) - child_sum.get(s.span_id, 0)))
             for s in spans
         ]
 
@@ -225,8 +283,9 @@ class TraceStore:
 
         return knobs.trace_keep()
 
-    def new_trace(self) -> Trace:
-        trace = Trace()
+    def new_trace(self, query_id: Optional[str] = None) -> Trace:
+        _listen_for_compiles()
+        trace = Trace(query_id=query_id)
         keep = self._keep()
         with self._lock:
             self._traces[trace.trace_id] = trace
@@ -237,6 +296,16 @@ class TraceStore:
     def get(self, trace_id: str) -> Optional[Trace]:
         with self._lock:
             return self._traces.get(trace_id)
+
+    def by_query_id(self, query_id: str) -> Optional[Trace]:
+        """The newest kept trace of a served statement (query ids are
+        one manager's: `q_7` of a second server in the process is
+        another statement)."""
+        with self._lock:
+            for trace in reversed(self._traces.values()):
+                if trace.query_id == query_id:
+                    return trace
+        return None
 
     def recent(self) -> List[Trace]:
         with self._lock:
@@ -251,6 +320,84 @@ def enabled() -> bool:
     from ..server import knobs
 
     return knobs.trace_enabled()
+
+
+# -- the calling thread's innermost open span, and what is booked on it --
+
+_OPEN = threading.local()  # .cur = (Trace, Span) while a span is open here
+
+
+def current() -> Optional[Tuple[Trace, Span]]:
+    """(trace, innermost open span) of the calling thread; None while it
+    has none, which is always the case under PRESTO_TPU_TRACE=0."""
+    return getattr(_OPEN, "cur", None)
+
+
+def adopt(trace: Trace, span: Span) -> None:
+    """Make `span`, opened on another thread (or by `begin`), the calling
+    thread's innermost: what `enter`s next on this thread is its child."""
+    _OPEN.cur = (trace, span)
+
+
+def release() -> None:
+    """Undo `adopt`: the calling thread has no open span again."""
+    _OPEN.cur = None
+
+
+def host_read(x):
+    """`np.asarray(x)`, the one call through which the served path reads
+    a device value on the host (`int(host_read(page.count))`). For a
+    device array whose value the host does not hold yet, the innermost
+    open span gets `host_reads` += 1 and `host_read_wait_s` += the time
+    the host stood here: waiting for the programs the value depends on,
+    then for the copy. An array keeps the copy an accelerator made, so a
+    second read of the same array object waits for nothing and is not
+    counted (the CPU backend reads in place, keeps none, and counts)."""
+    cur = getattr(_OPEN, "cur", None)
+    if (
+        cur is None
+        or not isinstance(x, jax.Array)
+        or getattr(x, "_npy_value", None) is not None
+    ):
+        return np.asarray(x)
+    t0 = time.perf_counter()
+    out = np.asarray(x)
+    waited = time.perf_counter() - t0
+    counters = cur[1].counters
+    counters["host_reads"] = counters.get("host_reads", 0) + 1
+    counters["host_read_wait_s"] = (
+        counters.get("host_read_wait_s", 0.0) + waited
+    )
+    return out
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_listening = False
+_listening_lock = threading.Lock()
+
+
+def _listen_for_compiles() -> None:
+    """Register, once a process, the listener that books `compiles` and
+    `compile_s` on the span open on the compiling thread. JAX fires the
+    event on that thread for every backend compile; a load from the
+    persistent cache counts (the program was not in this process yet),
+    a jit cache hit fires nothing."""
+    global _listening
+    with _listening_lock:
+        if _listening:
+            return
+        _listening = True
+    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+def _on_duration(event: str, secs: float, **_) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    cur = getattr(_OPEN, "cur", None)
+    if cur is not None:
+        counters = cur[1].counters
+        counters["compiles"] = counters.get("compiles", 0) + 1
+        counters["compile_s"] = counters.get("compile_s", 0.0) + secs
 
 
 # process-global: the coordinator's (or single-process session's) view

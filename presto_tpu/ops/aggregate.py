@@ -105,6 +105,7 @@ def _max_identity(dtype):
     return jnp.asarray(jnp.iinfo(dtype).min, dtype)
 
 
+@jax.named_scope("agg.reduce")
 def _segment_reduce(func, data, valid, gid, num_segments, wide: bool = False):
     """One aggregate over dense group ids; returns (values, group_has_value).
 
@@ -207,6 +208,7 @@ def _finalize(
     )
 
 
+@jax.named_scope("agg.inputs")
 def _eval_inputs(page: Page, group_exprs, aggs):
     keys = [evaluate(e, page) for e in group_exprs]
     ins = []
@@ -390,6 +392,7 @@ def _reduce_by(func, value: Val, key: Val, contributes, gid, num_groups: int):
     return vdat, vval
 
 
+@jax.named_scope("agg.mask")
 def _masked_live(page: Page, pre_mask) -> jnp.ndarray:
     """Liveness restricted by a fused selection mask (Aggregate.mask)."""
     live = page.live_mask()
@@ -457,6 +460,7 @@ def _neq_adjacent_nullaware(data, valid):
     return (neq & ~both_null) | vneq
 
 
+@jax.named_scope("agg.reduce")
 def _mask_reduce(func, data, contributes, gid, num_groups: int, wide=False):
     """_segment_reduce over a SMALL static group count via per-group masked
     full reductions — no scatter. On TPU, scatter-add (what segment_sum
@@ -657,41 +661,49 @@ def grouped_aggregate_sorted(
     live = _masked_live(page, pre_mask)
     keys, ins = _eval_inputs(page, group_exprs, aggs)
 
-    h = hash_rows(keys)
-    # dead rows sort to the end: flip to max sentinel
-    h = jnp.where(live, h, jnp.uint64(0xFFFFFFFFFFFFFFFF))
-    order = argsort_hashes(h)
+    with jax.named_scope("group.hash_sort"):
+        h = hash_rows(keys)
+        # dead rows sort to the end: flip to max sentinel
+        h = jnp.where(live, h, jnp.uint64(0xFFFFFFFFFFFFFFFF))
+        order = argsort_hashes(h)
 
-    live_s = live[order]
-    keys_s = [
-        Val(v.data[order], None if v.valid is None else v.valid[order], v.type, v.dict_id)
-        for v in keys
-    ]
+        live_s = live[order]
+        keys_s = [
+            Val(
+                v.data[order], None if v.valid is None else v.valid[order],
+                v.type, v.dict_id,
+            )
+            for v in keys
+        ]
 
-    # run boundaries on actual key values (collision-proof)
-    boundary = jnp.zeros(page.capacity, jnp.bool_).at[0].set(True)
-    for v in keys_s:
-        boundary = boundary | _neq_adjacent_nullaware(v.data, v.valid)
+    with jax.named_scope("group.runs"):
+        # run boundaries on actual key values (collision-proof)
+        boundary = jnp.zeros(page.capacity, jnp.bool_).at[0].set(True)
+        for v in keys_s:
+            boundary = boundary | _neq_adjacent_nullaware(v.data, v.valid)
 
-    boundary = boundary & live_s
-    gid_s = jnp.cumsum(boundary.astype(jnp.int32)) - 1
-    num_live_groups = jnp.maximum(gid_s[-1] + 1, 0) if page.capacity else 0
-    gid_s = jnp.where(live_s, gid_s, max_groups)
+        boundary = boundary & live_s
+        gid_s = jnp.cumsum(boundary.astype(jnp.int32)) - 1
+        num_live_groups = (
+            jnp.maximum(gid_s[-1] + 1, 0) if page.capacity else 0
+        )
+        gid_s = jnp.where(live_s, gid_s, max_groups)
 
-    # representative (first) row index per group, for key gather
-    first_idx = (
-        jnp.full((max_groups + 1,), page.capacity, jnp.int32)
-        .at[gid_s]
-        .min(jnp.arange(page.capacity, dtype=jnp.int32), mode="drop")
-    )
-    first_idx = jnp.minimum(first_idx, page.capacity - 1)[:max_groups]
+    with jax.named_scope("group.keys"):
+        # representative (first) row index per group, for key gather
+        first_idx = (
+            jnp.full((max_groups + 1,), page.capacity, jnp.int32)
+            .at[gid_s]
+            .min(jnp.arange(page.capacity, dtype=jnp.int32), mode="drop")
+        )
+        first_idx = jnp.minimum(first_idx, page.capacity - 1)[:max_groups]
 
-    blocks, names = [], []
-    for v, name in zip(keys_s, group_names):
-        kdata = v.data[first_idx]
-        kvalid = None if v.valid is None else v.valid[first_idx]
-        blocks.append(Block(kdata, v.type, kvalid, v.dict_id))
-        names.append(name)
+        blocks, names = [], []
+        for v, name in zip(keys_s, group_names):
+            kdata = v.data[first_idx]
+            kvalid = None if v.valid is None else v.valid[first_idx]
+            blocks.append(Block(kdata, v.type, kvalid, v.dict_id))
+            names.append(name)
 
     by_keys = _eval_by_keys(page, aggs)
     collect_need = None
@@ -919,8 +931,9 @@ def grouped_aggregate_sorted(
             contributes = live_s
             in_t = None
         else:
-            data_s = v.data[order]
-            valid_s = None if v.valid is None else v.valid[order]
+            with jax.named_scope("group.gather"):
+                data_s = v.data[order]
+                valid_s = None if v.valid is None else v.valid[order]
             contributes = live_s if valid_s is None else (live_s & valid_s)
             in_t = v.type
         raw, has = _segment_reduce(

@@ -36,6 +36,7 @@ def kept_first_permutation(keep: jnp.ndarray) -> jnp.ndarray:
     return jnp.where(key >= cap, key - cap, key)
 
 
+@jax.named_scope("compact")
 def compact(page: Page, keep: jnp.ndarray) -> Page:
     """Keep rows where `keep & live`, moved to the front, count updated.
 

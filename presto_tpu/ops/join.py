@@ -151,6 +151,7 @@ def sorted_probe_layout() -> str:
     return "directory" if BREAKERS.allow("join_probe") else "searchsorted"
 
 
+@jax.named_scope("join.build")
 def build_sorted(page: Page, key_exprs) -> BuildSide:
     """Sort the build side by key hash (HashBuilderOperator.finish analog).
     Empty key_exprs = all rows in one bucket (cross join support).
@@ -162,18 +163,20 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     the worst memory-access shape for the TPU; a directory lookup is a
     plain vectorized gather. Candidates inside a bucket that carry a
     different hash are rejected by the existing true-key-equality check."""
-    keys = [evaluate(e, page) for e in key_exprs]
-    live = page.live_mask()
-    value_hashed = _want_value_hash(keys, page.count)
-    if not keys:
-        h = jnp.zeros(page.capacity, jnp.uint64)
-    elif value_hashed:
-        h = hash_rows_values(keys)
-    else:
-        h = hash_rows(keys)
-    h = jnp.where(live, h, MAX_HASH)  # dead rows cluster at the end
-    order = argsort_hashes(h)
-    sh = h[order]
+    with jax.named_scope("hash"):
+        keys = [evaluate(e, page) for e in key_exprs]
+        live = page.live_mask()
+        value_hashed = _want_value_hash(keys, page.count)
+        if not keys:
+            h = jnp.zeros(page.capacity, jnp.uint64)
+        elif value_hashed:
+            h = hash_rows_values(keys)
+        else:
+            h = hash_rows(keys)
+        h = jnp.where(live, h, MAX_HASH)  # dead rows cluster at the end
+    with jax.named_scope("sort"):
+        order = argsort_hashes(h)
+        sh = h[order]
     if sorted_probe_layout() != "directory":
         # chip-diagnosis escape hatch / open breaker: searchsorted probe
         return BuildSide(
@@ -187,15 +190,17 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     # pure gather rounds. (A bincount/scatter-add builds the same counts
     # but XLA:TPU lowers large scatters to a serial loop; at a 1.5M-row
     # build side that serialization dominates the whole join.)
-    starts = jnp.searchsorted(
-        bucket, jnp.arange(nb + 1, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
+    with jax.named_scope("directory"):
+        starts = jnp.searchsorted(
+            bucket, jnp.arange(nb + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
     return BuildSide(
         sh, order, page, tuple(keys), page.count, starts, bits,
         value_hashed=value_hashed,
     )
 
 
+@jax.named_scope("join.probe_ranges")
 def _probe_ranges(bs: BuildSide, probe_keys: Sequence[Val], capacity: int):
     """For each probe row: [lo, hi) candidate range in the sorted build.
 
@@ -253,6 +258,7 @@ def _keys_equal(bs: BuildSide, probe_keys: Sequence[Val], build_rows):
     return eq
 
 
+@jax.named_scope("join.probe_loop")
 def _collision_scan(bs: BuildSide, probe_keys, lo, hi, max_scan: int = 4):
     """Resolve hash collisions: the first max_scan candidate slots are
     UNROLLED (64-bit hashes make >1 essentially impossible, so this is
@@ -370,12 +376,15 @@ def _join_n1_sorted(
 
     blocks = list(probe.blocks)
     names = list(probe.names)
-    for bname, oname in zip(build_names, out_build_names):
-        b = bs.page.block(bname)
-        data = b.data[build_row]
-        valid = matched if b.valid is None else (matched & b.valid[build_row])
-        blocks.append(Block(data, b.type, valid, b.dict_id))
-        names.append(oname)
+    with jax.named_scope("join.gather"):
+        for bname, oname in zip(build_names, out_build_names):
+            b = bs.page.block(bname)
+            data = b.data[build_row]
+            valid = (
+                matched if b.valid is None else (matched & b.valid[build_row])
+            )
+            blocks.append(Block(data, b.type, valid, b.dict_id))
+            names.append(oname)
     out = Page(tuple(blocks), tuple(names), probe.count)
     if kind == "inner":
         return compact(out, matched)
@@ -463,19 +472,22 @@ def _join_expand_sorted(
         has_match, _ = _collision_scan(bs, probe_keys, lo, hi)
         no_match = live & ~has_match
         counts = jnp.where(no_match, 1, counts)  # emit exactly one null row
-    offsets = jnp.cumsum(counts)
-    total = offsets[-1] if probe.capacity else jnp.asarray(0, jnp.int32)
-    starts = offsets - counts
+    with jax.named_scope("join.expand"):
+        offsets = jnp.cumsum(counts)
+        total = offsets[-1] if probe.capacity else jnp.asarray(0, jnp.int32)
+        starts = offsets - counts
 
-    out_i = jnp.arange(out_capacity, dtype=jnp.int32)
-    src = jnp.searchsorted(offsets, out_i, side="right").astype(jnp.int32)
-    src = jnp.minimum(src, probe.capacity - 1)
-    within = out_i - starts[src]
-    in_bounds = out_i < total
+        out_i = jnp.arange(out_capacity, dtype=jnp.int32)
+        src = jnp.searchsorted(
+            offsets, out_i, side="right"
+        ).astype(jnp.int32)
+        src = jnp.minimum(src, probe.capacity - 1)
+        within = out_i - starts[src]
+        in_bounds = out_i < total
 
-    sorted_pos = lo[src] + within
-    sorted_pos = jnp.minimum(sorted_pos, bs.sorted_hash.shape[0] - 1)
-    build_row = bs.order[sorted_pos].astype(jnp.int32)
+        sorted_pos = lo[src] + within
+        sorted_pos = jnp.minimum(sorted_pos, bs.sorted_hash.shape[0] - 1)
+        build_row = bs.order[sorted_pos].astype(jnp.int32)
 
     # verify true key equality for emitted pairs
     probe_keys_g = [
@@ -497,20 +509,21 @@ def _join_expand_sorted(
         build_valid_base = jnp.ones(out_capacity, jnp.bool_)
 
     blocks, names = [], []
-    for name in probe_out:
-        b = probe.block(name)
-        data = b.data[src]
-        valid = None if b.valid is None else b.valid[src]
-        blocks.append(Block(data, b.type, valid, b.dict_id))
-        names.append(name)
-    for bname, oname in build_out:
-        b = bs.page.block(bname)
-        data = b.data[build_row]
-        valid = build_valid_base if b.valid is None else (
-            build_valid_base & b.valid[build_row]
-        )
-        blocks.append(Block(data, b.type, valid, b.dict_id))
-        names.append(oname)
+    with jax.named_scope("join.gather"):
+        for name in probe_out:
+            b = probe.block(name)
+            data = b.data[src]
+            valid = None if b.valid is None else b.valid[src]
+            blocks.append(Block(data, b.type, valid, b.dict_id))
+            names.append(name)
+        for bname, oname in build_out:
+            b = bs.page.block(bname)
+            data = b.data[build_row]
+            valid = build_valid_base if b.valid is None else (
+                build_valid_base & b.valid[build_row]
+            )
+            blocks.append(Block(data, b.type, valid, b.dict_id))
+            names.append(oname)
 
     out = Page.from_blocks(blocks, names, count=out_capacity)
     from .filter import compact

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import types as T
+from ..obs.span import host_read
 
 # Per-lane payload budget: values stay < 2**62, strictly below the
 # INT64_MAX dead-row sentinel, and negation for the `lax.top_k` TopN path
@@ -476,7 +477,7 @@ def plan_from_page(
         lo = hi = None
         dtype = np.dtype(b.data.dtype)
         if b.data.ndim == 1 and dtype.kind in "if" and dtype.itemsize == 8:
-            n = int(page.count)
+            n = int(host_read(page.count))
             if n == 0:
                 lo, hi = 0, 0
             else:
@@ -488,12 +489,13 @@ def plan_from_page(
                     else:
                         data = jnp.where(v, data, data[0])
                 if dtype.kind == "f":
-                    flo = float(jnp.nanmin(data))
-                    fhi = float(jnp.nanmax(data))
+                    flo = float(host_read(jnp.nanmin(data)))
+                    fhi = float(host_read(jnp.nanmax(data)))
                     if np.isfinite(flo) and np.isfinite(fhi):
                         lo, hi = flo, fhi
                 else:
-                    lo, hi = int(jnp.min(data)), int(jnp.max(data))
+                    lo = int(host_read(jnp.min(data)))
+                    hi = int(host_read(jnp.max(data)))
         infos.append(key_info_from_block(b, lo=lo, hi=hi, exact=True))
     return plan_keypack(
         keys,

@@ -43,6 +43,7 @@ import numpy as np
 
 from .. import types as T
 from ..expr.compiler import evaluate
+from ..obs.span import host_read
 from ..page import Block, Page
 from .aggregate import AggSpec, avg_from_sum_count
 
@@ -133,13 +134,13 @@ def plan_matmul_grouped_aggregate(page: Page, group_exprs, aggs, pre_mask):
             d = 2
         elif v.data.ndim == 1 and jnp.issubdtype(v.data.dtype, jnp.integer):
             ok = live if v.valid is None else (live & v.valid)
-            if not bool(jnp.any(ok)):
+            if not bool(host_read(jnp.any(ok))):
                 d = 1
             else:
                 big = jnp.iinfo(jnp.int64)
                 data = v.data.astype(jnp.int64)
-                mn = int(jnp.min(jnp.where(ok, data, big.max)))
-                mx = int(jnp.max(jnp.where(ok, data, big.min)))
+                mn = int(host_read(jnp.min(jnp.where(ok, data, big.max))))
+                mx = int(host_read(jnp.max(jnp.where(ok, data, big.min))))
                 span = mx - mn + 1
                 if span > MATMUL_MAX_GROUPS:
                     return None

@@ -39,6 +39,7 @@ import numpy as np
 
 from .. import types as T
 from ..expr.compiler import evaluate
+from ..obs.span import host_read
 from ..page import Block, Page
 from .aggregate import AggSpec, avg_from_sum_count
 
@@ -148,9 +149,10 @@ def _pallas_partials_jit(gid, live, channels, count, num_groups,
     n = gid.shape[0]
     pad = -n % BLK_ROWS
     if pad:
-        gid = jnp.pad(gid, (0, pad))
-        live = jnp.pad(live, (0, pad))
-        channels = [jnp.pad(c, (0, pad)) for c in channels]
+        with jax.named_scope("partials.pad"):
+            gid = jnp.pad(gid, (0, pad))
+            live = jnp.pad(live, (0, pad))
+            channels = [jnp.pad(c, (0, pad)) for c in channels]
         n += pad
     blocks = n // BLK_ROWS
     view = lambda x: x.reshape(n // 128, 128)
@@ -162,16 +164,17 @@ def _pallas_partials_jit(gid, live, channels, count, num_groups,
         num_groups, len(channels), tuple(reduce_kinds), dtype
     )
     rpad = _rows_pad(num_groups, len(channels))
-    ins = (
-        count.reshape(1).astype(jnp.int32),
-        view(gid.astype(jnp.int32)),
-        view(live.astype(jnp.int32)),
-        *[view(c.astype(dtype)) for c in channels],
-    )
+    with jax.named_scope("partials.layout"):
+        ins = (
+            count.reshape(1).astype(jnp.int32),
+            view(gid.astype(jnp.int32)),
+            view(live.astype(jnp.int32)),
+            *[view(c.astype(dtype)) for c in channels],
+        )
     # trace with x64 OFF: under global x64 the BlockSpec index maps trace
     # to i64 functions, which Mosaic fails to legalize ("func.return
     # (i64)"); the kernel is explicit int32/float32 throughout.
-    with jax.enable_x64(False):
+    with jax.enable_x64(False), jax.named_scope("partials.kernel"):
         return pl.pallas_call(
             kernel,
             grid=(blocks,),
@@ -661,7 +664,7 @@ def maybe_grouped_aggregate_hash(
         if a.func in ("sum", "avg") and not jnp.issubdtype(
             v.data.dtype, jnp.floating
         ):
-            amax = int(np.abs(np.asarray(v.data)).max(initial=0))
+            amax = int(np.abs(host_read(v.data)).max(initial=0))
             if isinstance(a.input.type, T.DecimalType):
                 # decimal sums must stay EXACT: this path totals in
                 # int64 limbs (the sort strategy carries two-lane d128),
@@ -677,15 +680,15 @@ def maybe_grouped_aggregate_hash(
                 return None
         ins.append(v)
 
-    live = np.asarray(_masked_live(page, pre_mask))
-    h = np.asarray(hash_rows(keys))
+    live = host_read(_masked_live(page, pre_mask))
+    h = host_read(hash_rows(keys))
     tag = np.minimum(
         (h >> np.uint64(32)).astype(np.uint32), np.uint32(0xFFFFFFFE)
     )
     keys_np = [
         (
-            np.asarray(k.data),
-            None if k.valid is None else np.asarray(k.valid),
+            host_read(k.data),
+            None if k.valid is None else host_read(k.valid),
         )
         for k in keys
     ]
@@ -737,13 +740,13 @@ def maybe_grouped_aggregate_hash(
     out_blocks: List[Block] = []
     out_names: List[str] = []
     for v, nm in zip(keys, group_names):
-        data, valid = np.asarray(v.data), v.valid
+        data, valid = host_read(v.data), v.valid
         out_blocks.append(
             Block(
                 jnp.asarray(data[reps]),
                 v.type,
                 None if valid is None else jnp.asarray(
-                    np.asarray(valid)[reps]
+                    host_read(valid)[reps]
                 ),
                 v.dict_id,
             )
@@ -769,7 +772,7 @@ def _hash_groupby_mode() -> str:
 def _contrib_mask(live, v) -> np.ndarray:
     if v is None or v.valid is None:
         return live
-    return live & np.asarray(v.valid)
+    return live & host_read(v.valid)
 
 
 def _host_accumulate(gid, live, aggs, ins, G) -> Optional[List[Block]]:
@@ -816,7 +819,7 @@ def _host_accumulate(gid, live, aggs, ins, G) -> Optional[List[Block]]:
             )
             continue
         m = _contrib_mask(live, v)
-        data = np.asarray(v.data)
+        data = host_read(v.data)
         has = counts_for(ai, v) > 0
         if a.func in ("sum", "avg"):
             if np.issubdtype(data.dtype, np.floating):

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from .. import types as T
 from ..expr.compiler import evaluate
+from ..obs.span import host_read
 from ..page import Block, Page
 
 
@@ -264,7 +265,7 @@ def _host_argsort(*lanes):
     or numpy's sort runs ~3x slower through the buffer protocol."""
     import numpy as np
 
-    lanes = [np.asarray(l) for l in lanes]
+    lanes = [host_read(l) for l in lanes]
     if len(lanes) == 1:
         return np.argsort(lanes[0], kind="stable").astype(np.int32)
     return np.lexsort(tuple(reversed(lanes))).astype(np.int32)
@@ -277,7 +278,7 @@ def _host_topn(n: int):
     import numpy as np
 
     def select(k):
-        k = np.asarray(k)
+        k = host_read(k)
         part = np.argpartition(k, n - 1)[:n]
         thresh = k[part].max()
         cand = np.flatnonzero(k <= thresh)
@@ -386,9 +387,9 @@ def _host_distinct_sel(count, *lanes):
     padded to capacity, distinct count)."""
     import numpy as np
 
-    n = int(count)
+    n = int(host_read(count))
     cap = lanes[0].shape[0]
-    ls = [np.asarray(l)[:n] for l in lanes]
+    ls = [host_read(l)[:n] for l in lanes]
     if n == 0:
         return np.zeros(cap, np.int32), np.int32(0)
     if len(ls) == 1:

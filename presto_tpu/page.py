@@ -293,11 +293,13 @@ class Page:
     def to_pylist(self) -> list:
         """Materialize live rows as python tuples (decoding dictionaries;
         collection blocks decode to lists / dicts)."""
-        n = int(self.count)
+        from .obs.span import host_read
+
+        n = int(host_read(self.count))
         cols = []
         for b in self.blocks:
-            data = np.asarray(b.data[:n])
-            valid = None if b.valid is None else np.asarray(b.valid[:n])
+            data = host_read(b.data[:n])
+            valid = None if b.valid is None else host_read(b.valid[:n])
             if b.lengths is not None:
                 cols.append(_collection_pylist(b, data, valid, n))
                 continue

@@ -1480,9 +1480,17 @@ class HttpClusterSession:
         from ..obs.export import export_query
         from ..plan.fragment import fragment_plan
 
-        trace = obs_span.TRACES.new_trace() if obs_span.enabled() else None
+        # under a served statement (server/state.py) the tree is the one
+        # its `statement` span roots, and the manager exports it
+        served = obs_span.current()
+        if served is not None:
+            trace = served[0]
+        else:
+            trace = obs_span.TRACES.new_trace() if obs_span.enabled() else None
         root = (
-            trace.begin("query", sql=sql[:200])
+            trace.begin(
+                "query", parent=served[1] if served else None, sql=sql[:200]
+            )
             if trace is not None else None
         )
         status = "ok"
@@ -1577,7 +1585,8 @@ class HttpClusterSession:
         finally:
             if trace is not None:
                 trace.finish(root, status)
-                export_query(status, root.wall_s, phase_ms)
+                if served is None:
+                    export_query(status, root.wall_s, phase_ms)
 
     def query(self, sql: str):
         from ..session import QueryResult

@@ -50,6 +50,11 @@ class QueryInfo:
     # session traced the query; ride the query_completed event
     trace_id: Optional[str] = None
     phase_ms: Optional[dict] = None
+    # (Trace, `statement` span, `submit` span) while PRESTO_TPU_TRACE is
+    # on: the tree `submit` opens and the worker thread goes on with
+    trace_ctx: Optional[tuple] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
     @property
     def priority(self) -> int:  # query_priority scheduling policy input
@@ -122,7 +127,7 @@ class QueryManager:
     def submit(self, sql: str, user: str = "user",
                source: Optional[str] = None,
                properties: Optional[dict] = None) -> QueryInfo:
-        from .resource_groups import QueryRejected
+        from ..obs import span as obs_span
 
         with self._lock:
             qid = f"q_{next(self._ids)}"
@@ -133,6 +138,28 @@ class QueryManager:
             self.queries[qid] = info
             self._events[qid] = threading.Event()
             self._expire_locked()
+        sub = None
+        if obs_span.enabled():
+            # the statement's ONE tree (docs/observability.md): opened
+            # here on the handler thread, gone on with by the worker
+            # thread that runs it, closed by `_close_statement`
+            trace = obs_span.TRACES.new_trace(query_id=qid)
+            root = trace.begin("statement", query_id=qid, sql=sql[:200])
+            obs_span.adopt(trace, root)
+            sub = trace.enter("submit")
+            info.trace_ctx = (trace, root, sub)
+            info.trace_id = trace.trace_id
+        try:
+            return self._submit(info)
+        finally:
+            if sub is not None:
+                trace.leave(sub)
+                obs_span.release()
+
+    def _submit(self, info: QueryInfo) -> QueryInfo:
+        from .resource_groups import QueryRejected
+
+        qid, sql = info.query_id, info.sql
         self.events.fire_created(info)
         try:
             # multi-statement transactions are SESSION-scoped (an overlay
@@ -161,6 +188,7 @@ class QueryManager:
             info.state = FAILED
             info.error = str(e)
             info.finished_at = time.time()
+            self._close_statement(info)
             ev = self._events.get(qid)  # may already be expired from history
             if ev is not None:
                 ev.set()
@@ -204,6 +232,7 @@ class QueryManager:
             info.finished_at = time.time()
         if was_queued and self.groups.remove_queued(info):
             # never admitted: no slot to release
+            self._close_statement(info)
             self.events.fire_completed(info)
         ev = self._events.get(query_id)
         if ev is not None:
@@ -224,6 +253,8 @@ class QueryManager:
     # -- execution --
 
     def _run_loop(self):
+        from ..obs import span as obs_span
+
         while True:
             qid = self._queue.get()
             with self._lock:
@@ -238,8 +269,19 @@ class QueryManager:
                 # be gone from history)
                 self.groups.finished_by_id(qid, 0.0)
                 if info is not None:
+                    self._close_statement(info)
                     self.events.fire_completed(info)
                 continue
+            ctx = info.trace_ctx
+            if ctx is not None:
+                trace, root, sub = ctx
+                # `queued` crosses threads: it is placed by its neighbours,
+                # from where `submit` ended to this moment (a worker that
+                # got here before the handler thread left `submit`: empty)
+                trace.finish(
+                    trace.begin("queued", parent=root, start=sub.end)
+                )
+                obs_span.adopt(trace, root)
             try:
                 session = self.session
                 if info.properties and hasattr(session, "with_properties"):
@@ -261,9 +303,17 @@ class QueryManager:
                     {"name": t, "type": str(b.type)}
                     for t, b in zip(result.titles, result.page.blocks)
                 ]
-                info.rows = result.rows()
-                info.trace_id = getattr(result, "trace_id", None)
                 info.phase_ms = getattr(result, "phase_ms", None)
+                if ctx is None:
+                    info.rows = result.rows()
+                    info.trace_id = getattr(result, "trace_id", None)
+                else:
+                    # device-to-host copy and Python rows
+                    span = trace.enter("rows")
+                    try:
+                        info.rows = result.rows()
+                    finally:
+                        trace.leave(span)
                 with self._lock:
                     if info.state != CANCELED:
                         info.state = FINISHED
@@ -273,8 +323,34 @@ class QueryManager:
                     if info.state != CANCELED:
                         info.state = FAILED
             info.finished_at = time.time()
+            self._close_statement(info)
             self.groups.finished(info, info.finished_at - info.started_at)
             ev = self._events.get(qid)
             if ev is not None:
                 ev.set()
             self.events.fire_completed(info)
+
+    def _close_statement(self, info: QueryInfo) -> None:
+        """End a statement's tree, on whichever thread ended the
+        statement: close `statement` (and `submit`, for a statement
+        rejected inside it), put the lifecycle phases beside the
+        session's `plan` / `execute` in `info.phase_ms` (the completed
+        event carries it) and fold them into the metrics registry."""
+        ctx, info.trace_ctx = info.trace_ctx, None
+        if ctx is None:
+            return
+        from ..obs import span as obs_span
+        from ..obs.export import export_query
+
+        trace, root, sub = ctx
+        if sub.end is None:
+            trace.finish(sub)
+        status = "ok" if info.state == FINISHED else "error"
+        trace.finish(root, status)
+        obs_span.release()
+        phase_ms = dict(info.phase_ms or {})
+        for span in trace.children(root.span_id):
+            if span.name != "query":
+                phase_ms[span.name] = round(span.wall_s * 1e3, 3)
+        info.phase_ms = phase_ms
+        export_query(status, root.wall_s, phase_ms)

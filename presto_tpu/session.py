@@ -46,7 +46,9 @@ class QueryResult:
         return self.page.to_pylist()
 
     def row_count(self) -> int:
-        return int(self.page.count)
+        from .obs.span import host_read
+
+        return int(host_read(self.page.count))
 
 
 # system session properties: per-query engine overrides (reference
@@ -410,28 +412,35 @@ class Session:
         return QueryResult(pg, ("Query Plan",))
 
     def _run_select_traced(self, sql: str) -> QueryResult:
-        """Plan + execute with per-phase spans. The trace lands in the
+        """Plan + execute with per-phase spans. Under a served statement
+        the spans go into the tree `QueryManager.submit` opened (the
+        calling thread's current trace) and the manager exports it;
+        called directly, the trace is this query's own: it lands in the
         process TraceStore (system.runtime.tasks), the phase timings on
-        the QueryResult (and from there on the query_completed event),
-        and the completion counters in the metrics registry."""
+        the QueryResult, and the completion counters in the metrics
+        registry."""
         from .obs import span as obs_span
 
         if not obs_span.enabled():
             return self._execute_plan_cached(self.plan(sql))
-        from .obs.export import export_query
-
-        trace = obs_span.TRACES.new_trace()
-        root = trace.begin("query", sql=sql[:200])
+        cur = obs_span.current()
+        trace = cur[0] if cur is not None else obs_span.TRACES.new_trace()
+        root = trace.enter("query", sql=sql[:200])
         status = "ok"
         phase_ms: dict = {}
         try:
-            span = trace.begin("plan", parent=root)
-            node = self.plan(sql)
-            trace.finish(span)
+            span = trace.enter("plan")
+            try:
+                node = self.plan(sql)
+            finally:
+                trace.leave(span)
             phase_ms["plan"] = round(span.wall_s * 1e3, 3)
-            span = trace.begin("execute", parent=root)
-            res = self._execute_plan_cached(node)
-            trace.finish(span, rows=res.row_count())
+            span = trace.enter("execute")
+            try:
+                res = self._execute_plan_cached(node)
+                span.attrs["rows"] = res.row_count()
+            finally:
+                trace.leave(span)
             phase_ms["execute"] = round(span.wall_s * 1e3, 3)
             res.trace_id = trace.trace_id
             res.phase_ms = phase_ms
@@ -440,8 +449,11 @@ class Session:
             status = "error"
             raise
         finally:
-            trace.finish(root, status)
-            export_query(status, root.wall_s, phase_ms)
+            trace.leave(root, status)
+            if cur is None:
+                from .obs.export import export_query
+
+                export_query(status, root.wall_s, phase_ms)
 
     def _execute_plan_cached(self, node) -> QueryResult:
         """Execute a planned query through the result cache: a hit serves
@@ -1370,29 +1382,21 @@ class Session:
         kprof_before = KERNEL_PROFILE.snapshot()
         trace = root = exec_span = None
         if traced:
+            # a trace of its own, so that the `-- trace:` footer ranks
+            # this run alone; `Executor._run` hangs the live operator
+            # spans under `execute`, the units the cluster path ships
             trace = obs_span.TRACES.new_trace()
-            root = trace.begin("query")
-            exec_span = trace.begin("execute", parent=root)
-        ex.run(node)
+            root = trace.enter("query")
+            exec_span = trace.enter("execute")
+        try:
+            ex.run(node)
+        finally:
+            if traced:
+                trace.leave(exec_span)
+                trace.leave(root)
         # fold parked device row-count scalars in one batch (the lazy
         # collector avoids a blocking host sync per plan node)
         collector.resolve()
-        if traced:
-            trace.finish(exec_span)
-            trace.finish(root)
-            # graft per-node stats as synthetic spans so the -- trace:
-            # footer ranks the same units the cluster path ships
-            def _graft(n):
-                s = collector.lookup(n)
-                if s is not None:
-                    trace.add_synthetic(
-                        type(n).__name__, exec_span, s.wall_s,
-                        rows=s.rows_out, bytes=s.out_bytes_total,
-                    )
-                for c in n.children:
-                    _graft(c)
-
-            _graft(node)
         tree = N.plan_tree_str(node, collector=collector)
         total_ms = collector.total_wall_s() * 1e3
         peak = collector.peak_bytes / (1024 * 1024)

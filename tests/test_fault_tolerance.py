@@ -182,7 +182,7 @@ def test_kernel_guard_falls_back_per_call_even_when_breaker_cannot_open(
 
         return fn
 
-    assert ex._kernel_guarded("guard_test", "k", make_fn) == "fallback result"
+    assert ex._kernel_guarded("guard_test", "guard", "k", make_fn) == "fallback result"
     # disabled registry never opens, yet the call degraded per-call
     assert BREAKERS.allow("guard_test")
 

@@ -73,11 +73,19 @@ def test_retry_attempts_are_siblings():
     assert "!" + d1.name in render_critical_path(tr, topk=10)
 
 
-def test_exclusive_wall_and_critical_path():
+def test_exclusive_wall_and_critical_path(monkeypatch):
+    # begin/finish on a clock the test steps: query [0, 1] holds
+    # plan [0, 0.05] and execute [0.1, 1.0]
+    from presto_tpu.obs import span as obs_span
+
+    clock = iter([0.0, 0.0, 0.05, 0.1, 1.0, 1.0])
+    monkeypatch.setattr(obs_span.time, "time", lambda: next(clock))
     tr = Trace()
-    root = tr.add_synthetic("query", None, wall_s=1.0)
-    inner = tr.add_synthetic("execute", root, wall_s=0.9)
-    tr.add_synthetic("plan", root, wall_s=0.05)
+    root = tr.begin("query")
+    tr.finish(tr.begin("plan", parent=root))
+    inner = tr.finish(tr.begin("execute", parent=root))
+    tr.finish(root)
+    monkeypatch.undo()
     excl = {s.name: e for s, e in tr.exclusive_walls()}
     assert excl["query"] == pytest.approx(0.05, abs=1e-6)
     assert excl["execute"] == pytest.approx(0.9, abs=1e-6)
