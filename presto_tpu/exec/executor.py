@@ -500,12 +500,16 @@ class Executor:
             return None
 
     def _run_packed(self, node, breaker_name: str, label: str, make_fn,
-                    page: Page, plan):
+                    page: Page, plan, key=None):
         """Attempt one packed kernel behind its circuit breaker. Returns
         the output page, or None when the caller must run the legacy
         kernel (breaker open, kernel fault, or the plan's runtime range
         check tripped — sampled CBO bounds missed / a hash collided,
-        which is expected adaptivity rather than a kernel fault)."""
+        which is expected adaptivity rather than a kernel fault). `key`
+        is what the kernel closes over besides the plan, where that is
+        less than the node: a statement that differs only in a literal
+        of the node's subtree then hits the cached program."""
+        key = node if key is None else key
         from .breaker import BREAKERS
 
         if not BREAKERS.allow(breaker_name):
@@ -528,7 +532,7 @@ class Executor:
                 # cheap pack arithmetic is noise.
                 fn = make_fn()
             else:
-                fn = self._kernel(label, (node, label, plan), make_fn)
+                fn = self._kernel(label, (key, label, plan), make_fn)
             out, ok = fn(page)
         except Exception as exc:  # noqa: BLE001 — degrade, don't fail
             BREAKERS.record_failure(breaker_name, repr(exc))
@@ -961,32 +965,9 @@ class Executor:
 
             self.pallas_groupby = jax.default_backend() == "tpu"
         if self.pallas_groupby:
-            from .breaker import BREAKERS, PROGRAMMING_ERRORS
-            from ..ops.pallas_groupby import maybe_grouped_aggregate
-
-            out = None
-            if BREAKERS.allow("pallas_groupby"):
-                try:
-                    out = maybe_grouped_aggregate(
-                        page, node.group_exprs, node.group_names, node.aggs,
-                        node.mask,
-                    )
-                except PROGRAMMING_ERRORS:
-                    raise
-                except Exception as exc:
-                    # a Mosaic lowering/compile failure must degrade to
-                    # the XLA composition, not fail the query; the
-                    # breaker keeps the faulting kernel from being
-                    # re-attempted until its recovery window, and its
-                    # snapshot is where a run shows the kernel faulted
-                    BREAKERS.record_failure("pallas_groupby", repr(exc))
-                    out = None
-                else:
-                    if out is not None:
-                        BREAKERS.record_success("pallas_groupby")
+            out = self._try_pallas_groupby(node, page)
             if out is not None:
-                self._strategy_note(node, "pallas")
-                return self._shrink(out, node)
+                return out
         out = self._try_hash_groupby(node, page)
         if out is not None:
             return out
@@ -1050,6 +1031,56 @@ class Executor:
                     out.count,
                 )
             break
+        return self._shrink(out, node)
+
+    def _try_pallas_groupby(self, node: N.Aggregate, page: Page) -> Optional[Page]:
+        """Dense small-G group-by (ops/pallas_groupby.py) as ONE program
+        per plan shape, behind the pallas_groupby breaker. The static plan
+        step refuses an ineligible shape before anything is traced or
+        launched. The mask's scalar literals go in as operands and the
+        kernel's key is what the body closes over with their values
+        erased (qcache.lift_literals), so Q1 with a DELTA the process has
+        not seen is a kernel-cache hit and compiles nothing. None =
+        ineligible or faulted; the caller takes the next strategy."""
+        from ..ops import pallas_groupby as pg
+        from .breaker import BREAKERS, PROGRAMMING_ERRORS
+        from .qcache import lift_literals, rebind_plan
+
+        if not BREAKERS.allow("pallas_groupby"):
+            return None
+        if pg.plan_grouped_aggregate(page, node.group_exprs, node.aggs) is None:
+            return None
+        mask, operands = lift_literals(node.mask)
+        # the cached program keeps what it is keyed on, not the node
+        shape = (node.group_exprs, node.group_names, node.aggs)
+        try:
+            fn = self._kernel(
+                "grouped_aggregate_pallas",
+                ("grouped_aggregate_pallas", shape, mask),
+                lambda: lambda p, ops: pg.maybe_grouped_aggregate(
+                    p, *shape, rebind_plan(mask, ops)
+                ),
+            )
+            out = fn(page, operands)
+        except PROGRAMMING_ERRORS:
+            raise
+        except Exception as exc:  # noqa: BLE001 — degrade, don't fail
+            # a Mosaic lowering/compile failure (or any fault in trace or
+            # run of the fused program) must degrade to the next
+            # strategy, not fail the query; the breaker keeps the
+            # faulting kernel from being re-attempted until its recovery
+            # window, and its snapshot is where a run shows it faulted
+            BREAKERS.record_failure("pallas_groupby", repr(exc))
+            return None
+        if out is None:
+            return None
+        BREAKERS.record_success("pallas_groupby")
+        self._strategy_note(node, "pallas")
+        cur = current_span()
+        if cur is not None:
+            if self.jit:
+                cur[1].attrs["programs"] = 1
+            cur[1].attrs["bound_literals"] = len(operands)
         return self._shrink(out, node)
 
     def _try_hash_groupby(self, node: N.Aggregate, page: Page) -> Optional[Page]:
@@ -1541,7 +1572,7 @@ class Executor:
             out = self._run_packed(
                 node, "keypack_sort", "sort_packed",
                 lambda: lambda p: sort_page_packed(p, node.keys, plan),
-                page, plan,
+                page, plan, key=node.keys,
             )
             if out is not None:
                 return out

@@ -338,11 +338,74 @@ def rebind_plan(node, values: Tuple[Any, ...]):
     def fn(obj):
         if isinstance(obj, ir.Literal) and obj.param is not None:
             v = values[obj.param]
-            if v != obj.value:  # NaN != NaN: always replaced, still right
+            # identity, not ==: an equal value swapped in is still right,
+            # and a value may be a device operand (lift_literals), whose
+            # == is an array
+            if v is not obj.value:
                 return dataclasses.replace(obj, value=v)
         return obj
 
     return _walk_rebuild(node, fn)
+
+
+_COMPARISONS = frozenset(("eq", "ne", "lt", "le", "gt", "ge", "between"))
+UNBOUND = "<unbound operand>"  # a lifted literal's value in a skeleton
+
+
+def lift_literals(expr):
+    """Split a predicate into (skeleton, operands) so that ONE traced
+    program serves every value of its scalar literals: each non-NULL
+    boolean / integer / float / date / short-decimal literal that stands
+    as an argument of a comparison becomes, in the skeleton, a literal
+    tagged with its index into `operands` (`Literal.param`, value
+    UNBOUND), and `operands` holds the values as 0-d arrays in storage
+    units. `rebind_plan(skeleton, operands)` inside the traced function
+    puts them back, as tracers. Everything else keeps its value and so
+    stays part of the skeleton, which is the program's cache key: varchar
+    literals fix dictionary ids, and a literal outside a comparison may
+    be read at trace time (`Val.literal`). Any other param tag is
+    dropped: the skeleton's indices are its own."""
+    import numpy as np
+
+    from .. import types as T
+    from ..expr import ir
+    from ..expr.compiler import literal_scalar
+
+    operands: List[Any] = []
+
+    def liftable(a) -> bool:
+        t = a.type
+        return (
+            isinstance(a, ir.Literal)
+            and a.value is not None
+            and (
+                isinstance(t, (T.BooleanType, T.DateType))
+                or T.is_integral(t)
+                or T.is_floating(t)
+                or (isinstance(t, T.DecimalType) and not t.is_long)
+            )
+        )
+
+    def fn(obj):
+        if isinstance(obj, ir.Call) and obj.name in _COMPARISONS:
+            args = []
+            for a in obj.args:
+                if liftable(a):
+                    operands.append(
+                        np.asarray(literal_scalar(a), a.type.storage_dtype)
+                    )
+                    a = dataclasses.replace(
+                        a, value=UNBOUND, param=len(operands) - 1
+                    )
+                else:
+                    a = _walk_rebuild(a, fn)
+                args.append(a)
+            return dataclasses.replace(obj, args=tuple(args))
+        if isinstance(obj, ir.Literal) and obj.param is not None:
+            return dataclasses.replace(obj, param=None)
+        return obj
+
+    return _walk_rebuild(expr, fn), tuple(operands)
 
 
 def strip_params(node):

@@ -165,40 +165,53 @@ def evaluate(expr: RowExpression, page: Page, n: Optional[int] = None) -> Val:
 # ---------------------------------------------------------------------------
 
 
+def literal_scalar(expr: Literal):
+    """The host scalar a numeric/date/boolean literal stands for, in its
+    type's storage units (days for a date, the scaled integer for a short
+    decimal). What `_literal_val` broadcasts, and what goes to a program
+    as an operand when the literal is bound (exec/qcache.lift_literals)."""
+    t = expr.type
+    if isinstance(t, T.DateType) and isinstance(expr.value, str):
+        return dt.parse_date_literal(expr.value)
+    if isinstance(t, T.DecimalType):
+        # any numeric literal -> scaled int in the decimal's units
+        from decimal import Decimal
+
+        return int(
+            (Decimal(str(expr.value)) * (10**t.scale)).to_integral_value()
+        )
+    return expr.value
+
+
 def _literal_val(expr: Literal, cap: int) -> Val:
     t = expr.type
     if expr.value is None:
         return Val(
             jnp.zeros(cap, t.storage_dtype), jnp.zeros(cap, jnp.bool_), t
         )
+    if isinstance(expr.value, (jax.Array, np.ndarray)):
+        # bound as an operand of the program (qcache.rebind_plan over a
+        # lifted skeleton): already in storage units, and no `literal`,
+        # so the value is not part of what gets traced
+        return Val(jnp.full(cap, expr.value, t.storage_dtype), None, t)
     if isinstance(t, T.VarcharType):
         did = intern_dictionary((expr.value,))
         return Val(jnp.zeros(cap, jnp.int32), None, t, did, literal=expr.value)
+    scalar = literal_scalar(expr)
     if isinstance(t, T.DateType) and isinstance(expr.value, str):
-        days = dt.parse_date_literal(expr.value)
-        return Val(jnp.full(cap, days, jnp.int32), None, t, literal=days)
-    if isinstance(t, T.DecimalType):
-        # any numeric literal -> scaled int in the decimal's units
-        from decimal import Decimal
-
-        scaled = int(
-            (Decimal(str(expr.value)) * (10**t.scale)).to_integral_value()
-        )
-        if t.is_long:
-            # beyond int64: (hi, lo) radix-2^32 lanes (ops/decimal128.py)
-            if abs(scaled) >= (1 << 95):
-                raise ValueError(
-                    f"decimal literal {expr.value} exceeds the two-lane "
-                    "range (~2^95)"
-                )
-            lanes = np.array(
-                [[scaled >> 32, scaled & 0xFFFFFFFF]], np.int64
+        return Val(jnp.full(cap, scalar, jnp.int32), None, t, literal=scalar)
+    if isinstance(t, T.DecimalType) and t.is_long:
+        # beyond int64: (hi, lo) radix-2^32 lanes (ops/decimal128.py)
+        if abs(scalar) >= (1 << 95):
+            raise ValueError(
+                f"decimal literal {expr.value} exceeds the two-lane "
+                "range (~2^95)"
             )
-            data = jnp.broadcast_to(jnp.asarray(lanes), (cap, 2))
-            return Val(data, None, t, literal=expr.value)
-        return Val(jnp.full(cap, scaled, jnp.int64), None, t, literal=expr.value)
+        lanes = np.array([[scalar >> 32, scalar & 0xFFFFFFFF]], np.int64)
+        data = jnp.broadcast_to(jnp.asarray(lanes), (cap, 2))
+        return Val(data, None, t, literal=expr.value)
     return Val(
-        jnp.full(cap, expr.value, t.storage_dtype), None, t, literal=expr.value
+        jnp.full(cap, scalar, t.storage_dtype), None, t, literal=expr.value
     )
 
 

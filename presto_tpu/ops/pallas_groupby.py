@@ -16,9 +16,13 @@ outside in int64 — exact for |x| < 2^45, asserted against the input
 types' value bounds. min/max ride int32 channels directly (their
 storage is int32-safe for the eligible types).
 
-Eligibility (maybe_grouped_aggregate returns None otherwise): every
-group key is a small-domain dictionary/boolean column, G <= 32, every
-aggregate is count/count_star/sum/avg/min/max over integral storage.
+Eligibility (plan_grouped_aggregate, from types and dictionary lengths
+alone; None otherwise): every group key is a small-domain dictionary
+column or a boolean, G <= PALLAS_MAX_GROUPS, every aggregate is
+count/count_star/sum/avg/min/max over integral storage (float64 for
+sum/avg). The body around the kernel (mask, group id, limb channels, lane
+fold, recomposition) has no host read and no data-dependent branch: the
+executor traces it with the kernel as ONE program per plan shape.
 
 DEPLOYMENT: on a `tpu` backend the kernel is compiled by Mosaic and is
 the engine default for eligible aggregations (Executor._exec_aggregate);
@@ -40,7 +44,7 @@ import numpy as np
 from .. import types as T
 from ..expr.compiler import evaluate
 from ..obs.span import host_read
-from ..page import Block, Page
+from ..page import Block, Page, dictionary_by_id
 from .aggregate import AggSpec, avg_from_sum_count
 
 BLK_ROWS = 16384  # 128 x 128 rows per grid step
@@ -127,10 +131,10 @@ def _pallas_partials(gid, live, channels, count, num_groups, reduce_kinds,
     """(blocks, rows_pad, 128) per-block per-lane partials in `dtype`;
     row g*len(channels)+k = channel k of group g (see _kernel_factory).
 
-    Callers run eagerly, and an eager pallas_call of a fresh kernel
-    closure compiles on every call (PR 22 chip run: one compile per
-    repeat of Q1), so the call goes through one jit keyed on the static
-    plan."""
+    An eager pallas_call of a fresh kernel closure compiles on every
+    call (PR 22 chip run: one compile per repeat of Q1), so the call goes
+    through one jit keyed on the static plan; inside a traced caller (the
+    dense path's fused program) it is inlined."""
     return _pallas_partials_jit(
         gid, live, tuple(channels), count, num_groups, tuple(reduce_kinds),
         dtype, jax.default_backend() != "tpu",
@@ -192,84 +196,146 @@ def _pallas_partials_jit(gid, live, channels, count, num_groups,
         )(*ins)
 
 
-def _eligible_keys(page: Page, group_exprs) -> Optional[Tuple[list, list]]:
-    """Evaluated key Vals + domain sizes when every key is small-domain."""
-    keys, domains = [], []
+_SUPPORTED = {"count", "count_star", "sum", "avg", "min", "max"}
+
+
+def _eligible_keys(page: Page, group_exprs):
+    """((domain, nullable) per key, G) when every key is small-domain,
+    read off the page's blocks and the expressions' types: a dictionary
+    column's domain is its dictionary's length, a boolean's is 2. NULL
+    keys form their OWN group (SQL GROUP BY semantics), so a nullable key
+    gets one slot more; a computed boolean key is taken as nullable,
+    since only evaluating it would say. None when a key is anything else
+    or the groups pass PALLAS_MAX_GROUPS."""
+    from ..expr.ir import ColumnRef
+
+    keys = []
+    G = 1
     for e in group_exprs:
-        v = evaluate(e, page)
-        if isinstance(v.type, T.VarcharType) and v.dictionary is not None:
-            d = len(v.dictionary)
-        elif isinstance(v.type, T.BooleanType):
+        blk = page.block(e.name) if isinstance(e, ColumnRef) else None
+        if isinstance(e.type, T.BooleanType):
             d = 2
+        elif (
+            isinstance(e.type, T.VarcharType)
+            and blk is not None
+            and blk.dict_id is not None
+        ):
+            d = max(len(dictionary_by_id(blk.dict_id)), 1)
         else:
             return None
-        if d == 0:
-            d = 1
-        keys.append(v)
-        domains.append(d)
-    total = 1
-    for d in domains:
-        total *= d
-    if not 0 < total <= PALLAS_MAX_GROUPS:
+        nullable = blk is None or blk.valid is not None
+        keys.append((d, nullable))
+        G *= d + nullable
+    if G > PALLAS_MAX_GROUPS:
         return None
-    return keys, domains
+    return tuple(keys), G
 
 
-_SUPPORTED = {"count", "count_star", "sum", "avg", "min", "max"}
+def _input_kind(t: T.Type) -> Optional[str]:
+    """'int' / 'float' for a 1-D integral- or float-storage input type,
+    else None (two-lane decimals, collections)."""
+    if isinstance(t, (T.ArrayType, T.MapType)) or (
+        isinstance(t, T.DecimalType) and t.is_long
+    ):
+        return None
+    if isinstance(t, T.BooleanType) or jnp.issubdtype(
+        t.storage_dtype, jnp.integer
+    ):
+        return "int"
+    if jnp.issubdtype(t.storage_dtype, jnp.floating):
+        return "float"
+    return None
+
+
+def plan_grouped_aggregate(page: Page, group_exprs, aggs: Sequence[AggSpec]):
+    """The STATIC half of the dense small-G group-by: whether the shape is
+    eligible, decided from aggregate names, key and input types, dictionary
+    lengths, the channel count and the output tile bound. It touches no
+    device array, so a caller can ask before anything is traced or
+    launched. Returns the key plan of `_eligible_keys`, or None."""
+    if not group_exprs:
+        return None
+    if any(a.func not in _SUPPORTED for a in aggs):
+        return None
+    keys = _eligible_keys(page, group_exprs)
+    if keys is None:
+        return None
+    ch = fch = 0
+    for a in aggs:
+        if a.func in ("count", "count_star", "avg"):
+            ch += 1
+        if a.input is None:
+            continue
+        kind = _input_kind(a.input.type)
+        # float64 rides the hi/lo-split f32 channel path, sum/avg only
+        # (min/max would need 64-bit compares the kernel does not have)
+        if kind is None or (kind == "float" and a.func in ("min", "max")):
+            return None
+        if a.func in ("sum", "avg"):
+            if kind == "float":
+                fch += 2
+            else:
+                ch += 3
+        elif a.func in ("min", "max"):
+            ch += 1
+    if ch > MAX_CHANNELS or fch > MAX_CHANNELS:
+        return None
+    # bound the per-block output tile (rows x 128 lanes) to 512KB VMEM
+    if max(_rows_pad(keys[1], ch), _rows_pad(keys[1], fch)) > 1024:
+        return None
+    return keys
 
 
 def maybe_grouped_aggregate(
     page: Page, group_exprs, group_names, aggs: Sequence[AggSpec], pre_mask
 ) -> Optional[Page]:
     """Route an eligible aggregation through the Pallas kernel; None when
-    the shape is not eligible (caller falls back to the XLA path)."""
-    if not group_exprs:
+    the shape is not eligible (caller falls back to the XLA path). The
+    whole of it: the static plan step, then the body. The body has no host
+    read and no data-dependent branch, so it traces: the executor runs it
+    as ONE program (`Executor._exec_aggregate`, "grouped_aggregate_pallas"),
+    and run as it stands it is that program's operations one by one."""
+    key_plan = plan_grouped_aggregate(page, group_exprs, aggs)
+    if key_plan is None:
         return None
-    if any(a.func not in _SUPPORTED for a in aggs):
-        return None
-    elig = _eligible_keys(page, group_exprs)
-    if elig is None:
-        return None
-    keys, domains = elig
+    return _grouped_aggregate_body(
+        page, group_exprs, group_names, aggs, pre_mask, key_plan
+    )
+
+
+def _grouped_aggregate_body(
+    page: Page, group_exprs, group_names, aggs, pre_mask, key_plan
+) -> Optional[Page]:
+    """Mask, dense group id, limb channels, the partials kernel, the lane
+    fold, the recomposition and the compaction of empty groups. None when
+    an evaluated input is not what its type promised the plan step."""
+    slots, G = key_plan
+    keys = [evaluate(e, page) for e in group_exprs]
     ins = []
     for a in aggs:
         if a.input is None:
             ins.append(None)
             continue
         v = evaluate(a.input, page)
-        if v.data.ndim != 1:
-            return None
-        integral = jnp.issubdtype(v.data.dtype, jnp.integer) or isinstance(
-            v.type, T.BooleanType
-        )
-        # float64 rides the hi/lo-split f32 channel path, sum/avg only
-        # (min/max would need 64-bit compares the kernel does not have)
         floating = jnp.issubdtype(v.data.dtype, jnp.floating)
-        if not integral and not (floating and a.func in ("sum", "avg")):
+        if v.data.ndim != 1 or floating != (
+            _input_kind(a.input.type) == "float"
+        ):
             return None
         ins.append(v)
 
-    # dense mixed-radix group id. NULL keys form their OWN group (SQL
-    # GROUP BY semantics — dropping them was a silent wrong-result on
-    # the default-on TPU path): each nullable key gets one extra slot.
+    # dense mixed-radix group id; a nullable key's NULL slot is its last
     from .aggregate import _masked_live
 
     live = _masked_live(page, pre_mask)
     gid = jnp.zeros(page.capacity, jnp.int32)
-    eff_domains: List[int] = []
-    for v, d in zip(keys, domains):
+    domains = [d for d, _ in slots]
+    eff_domains = [d + nullable for d, nullable in slots]
+    for v, d, eff in zip(keys, domains, eff_domains):
         code = jnp.clip(v.data.astype(jnp.int32), 0, d - 1)
-        eff = d
         if v.valid is not None:
-            code = jnp.where(v.valid, code, d)  # null slot = last
-            eff = d + 1
+            code = jnp.where(v.valid, code, d)
         gid = gid * eff + code
-        eff_domains.append(eff)
-    G = 1
-    for d in eff_domains:
-        G *= d
-    if G > PALLAS_MAX_GROUPS:
-        return None
 
     # channel plan: (agg index, role, limb index, reduce kind)
     channels: List = []
@@ -324,14 +390,6 @@ def maybe_grouped_aggregate(
             add_channel(
                 x, (ai, a.func, 0), kind=a.func
             )  # masking happens in-kernel via `sel`
-    if len(channels) > MAX_CHANNELS or len(fchannels) > MAX_CHANNELS:
-        return None
-    # bound the per-block output tile (rows x 128 lanes) to 512KB VMEM
-    if max(
-        _rows_pad(G, len(channels)), _rows_pad(G, len(fchannels))
-    ) > 1024:
-        return None
-
     CH = len(channels)
     if CH:
         partials = _pallas_partials(

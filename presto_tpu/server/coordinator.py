@@ -75,6 +75,7 @@ class CoordinatorServer:
                 memory_budget=session.memory_budget,
                 access_control=session.access_control,
                 user=session.user,
+                pallas_groupby=session.pallas_groupby,
                 result_cache=session.result_cache,
             )
             self.syscat = syscat

@@ -104,11 +104,16 @@ def test_registry_snapshot_and_env_threshold(monkeypatch):
 # -- pallas group-by: fault -> fallback correct -> breaker open --------------
 
 
-def test_pallas_fault_degrades_to_xla_fallback(monkeypatch):
+@pytest.mark.parametrize(
+    "where", ["maybe_grouped_aggregate", "_pallas_partials"]
+)
+def test_pallas_fault_degrades_to_xla_fallback(monkeypatch, where):
     """Acceptance: with a forced kernel fault in the Pallas group-by
     path, an aggregation query completes via the XLA fallback with the
     breaker reported open in the exec/stats.py surface — and the
-    faulting kernel is NOT re-attempted while the breaker is open."""
+    faulting kernel is NOT re-attempted while the breaker is open. The
+    fault is the whole path's, or the kernel call's deep inside the
+    fused program: either way it comes out of the program's TRACE."""
     from presto_tpu.ops import pallas_groupby as pg
 
     calls = []
@@ -117,7 +122,7 @@ def test_pallas_fault_degrades_to_xla_fallback(monkeypatch):
         calls.append(1)
         raise RuntimeError("Mosaic lowering failed (injected fault)")
 
-    monkeypatch.setattr(pg, "maybe_grouped_aggregate", faulting)
+    monkeypatch.setattr(pg, where, faulting)
     sess = Session(TpchCatalog(sf=SF), pallas_groupby=True)
     sql = (
         "select o_orderpriority, count(*) c, sum(o_totalprice) s "
@@ -134,7 +139,7 @@ def test_pallas_fault_degrades_to_xla_fallback(monkeypatch):
     )
 
     snap = kernel_breaker_snapshot()["pallas_groupby"]
-    assert snap["state"] == "open"
+    assert snap["state"] == "open" and snap["total_failures"] == 1
     assert any("pallas_groupby: open" in ln for ln in kernel_breaker_lines())
 
     # open breaker: the faulting kernel is not re-attempted

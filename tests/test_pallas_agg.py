@@ -5,6 +5,8 @@ kernel compiles under Mosaic (tests/test_tpu_compile.py compiles it for
 a described v5e) and bench.py times it. Correctness of the limb decomposition and
 per-block combine is fully exercised either way."""
 
+import pytest
+
 from presto_tpu.benchmark.handcoded import (
     lineitem_q1_page,
     q1_local,
@@ -195,3 +197,99 @@ def test_pallas_groupby_g63_matches_sort_strategy():
         pg, (col("g", T.VARCHAR),), ("g",), aggs, 128
     )
     assert sorted(out.to_pylist()) == sorted(want.to_pylist())
+
+
+# -- the fused program: one trace per plan shape, mask literals as operands
+
+
+def _fused_case(name):
+    """(page, group_exprs, group_names, aggs, mask, expected groups)."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from presto_tpu import types as T
+    from presto_tpu.benchmark.handcoded import (
+        Q1_GROUP_NAMES,
+        Q1_GROUPS,
+        Q1_PREDICATE,
+        q1_aggs,
+    )
+    from presto_tpu.expr import ir
+    from presto_tpu.expr.ir import col
+    from presto_tpu.ops.aggregate import AggSpec
+    from presto_tpu.page import Block, Page, intern_dictionary
+
+    if name == "q1":
+        return (
+            lineitem_q1_page(0.003), Q1_GROUPS, Q1_GROUP_NAMES, q1_aggs(),
+            Q1_PREDICATE, 4,
+        )
+    rng = np.random.default_rng(3)
+    n = 20000
+    d = intern_dictionary(("A", "N", "R"))
+    blocks = (
+        Block(
+            jnp.asarray(rng.integers(0, 3, n).astype(np.int32)), T.VARCHAR,
+            jnp.asarray(rng.random(n) > 0.1) if name == "null_key" else None,
+            d,
+        ),
+        Block(jnp.asarray(rng.integers(-(10**9), 10**9, n)), T.BIGINT),
+        Block(jnp.asarray(rng.random(n) * 1e6 - 5e5), T.DOUBLE),
+    )
+    page = Page(blocks, ("g", "v", "x"), jnp.asarray(n - 7, jnp.int32))
+    v, x = col("v", T.BIGINT), col("x", T.DOUBLE)
+    aggs = {
+        "null_key": (
+            AggSpec("sum", v, "s", T.BIGINT),
+            AggSpec("count_star", None, "c", T.BIGINT),
+        ),
+        "float": (
+            AggSpec("sum", x, "sx", T.DOUBLE),
+            AggSpec("avg", x, "ax", T.DOUBLE),
+            AggSpec("count", v, "c", T.BIGINT),
+        ),
+        "min_max": (
+            AggSpec("min", v, "mn", T.BIGINT),
+            AggSpec("max", v, "mx", T.BIGINT),
+        ),
+        "all_filtered": (
+            AggSpec("sum", v, "s", T.BIGINT),
+            AggSpec("avg", v, "a", T.DOUBLE),
+        ),
+    }[name]
+    # a bigint bound no row passes empties the page; else about half pass
+    bound = 2 * 10**9 if name == "all_filtered" else 0
+    mask = ir.comparison("ge", v, ir.Literal(bound, T.BIGINT))
+    groups = {"null_key": 4, "all_filtered": 0}.get(name, 3)
+    return page, (col("g", T.VARCHAR),), ("g",), aggs, mask, groups
+
+
+@pytest.mark.parametrize(
+    "case", ["q1", "null_key", "float", "min_max", "all_filtered"]
+)
+def test_fused_program_matches_sort_strategy(case):
+    """The dense group-by as the executor launches it (ONE jitted program,
+    the mask's literal an operand) against `grouped_aggregate_sorted`:
+    exact for integers and decimals, f32-ulp close for float sums."""
+    from presto_tpu.connectors.memory import MemoryCatalog
+    from presto_tpu.exec.breaker import BREAKERS
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.ops.aggregate import grouped_aggregate_sorted
+    from presto_tpu.plan import nodes as N
+
+    page, group_exprs, group_names, aggs, mask, groups = _fused_case(case)
+    BREAKERS.reset()
+    node = N.Aggregate(None, group_exprs, group_names, tuple(aggs), mask)
+    out = Executor(MemoryCatalog({}))._try_pallas_groupby(node, page)
+    assert out is not None
+    assert BREAKERS.snapshot()["pallas_groupby"]["total_failures"] == 0
+    want = grouped_aggregate_sorted(
+        page, group_exprs, group_names, aggs, 64, mask
+    )
+    key = lambda row: str(row[: len(group_names)])  # noqa: E731
+    got, want = sorted(out.to_pylist(), key=key), sorted(
+        want.to_pylist(), key=key
+    )
+    assert len(got) == len(want) == groups
+    for g, w in zip(got, want):
+        assert g == pytest.approx(w, rel=1e-6) if case == "float" else g == w

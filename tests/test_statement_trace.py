@@ -185,6 +185,42 @@ def test_new_literal_books_compiles_on_its_operator(served):
     assert all("compiles" not in s.attrs for s in again.spans())
 
 
+def test_fresh_q1_delta_hits_the_fused_program(served):
+    """Q1's DELTA is an operand of ONE `grouped_aggregate_pallas` program,
+    not part of its key: a DELTA the process has not seen compiles
+    nothing, hits the kernel cache, and still answers for ITS date (a
+    stale literal is the bug this is here for). Counts, so they repeat."""
+    from presto_tpu.exec.qcache import KERNEL_CACHE
+
+    session = Session(
+        DeviceTpchCatalog(sf=SF), result_cache=False, pallas_groupby=True
+    )
+    server = CoordinatorServer(session, port=0).start()
+    try:
+        pallas = session, server, Client(server.uri, timeout=600.0)
+        answers = []
+        for i, delta in enumerate((90, 91, 117)):
+            before = KERNEL_CACHE.snapshot()
+            rows, trace, _ = serve(pallas, sql_of("q1", delta=delta))
+            after = KERNEL_CACHE.snapshot()
+            agg = span_named(trace, "Aggregate")
+            assert agg.attrs["strategy"] == "pallas"
+            assert agg.attrs["programs"] == 1
+            assert agg.attrs["bound_literals"] == 1
+            if i:
+                assert all("compiles" not in s.attrs for s in trace.spans())
+                assert after["misses"] == before["misses"]
+                assert after["hits"] > before["hits"]
+            else:
+                assert agg.attrs["compiles"] >= 1
+            # the XLA composition, pallas off, is the reference
+            assert rows == serve(served, sql_of("q1", delta=delta))[0]
+            answers.append(rows)
+    finally:
+        server.stop()
+    assert len({repr(rows) for rows in answers}) == 3
+
+
 # -- (d) program names -------------------------------------------------------
 
 

@@ -105,6 +105,39 @@ def test_pallas_groupby_partials_sf1(one_chip, as_tpu, groups, nch, dtype, kinds
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_fused_q1_groupby_sf1(one_chip, as_tpu):
+    """Q1's whole `Aggregate` as the executor launches it: the decimal
+    arithmetic, the limb split and the kernel in one program, DELTA an
+    operand. (At 2**26 rows, SF10's width, the same compile reports 2.95
+    GB of arguments and 5.10 GB of temporaries.)"""
+    from presto_tpu.benchmark.handcoded import (
+        Q1_GROUP_NAMES,
+        Q1_GROUPS,
+        Q1_PREDICATE,
+        lineitem_q1_page,
+        q1_aggs,
+    )
+    from presto_tpu.exec.qcache import lift_literals, rebind_plan
+    from presto_tpu.ops.pallas_groupby import maybe_grouped_aggregate
+
+    mask, operands = lift_literals(Q1_PREDICATE)
+    assert len(operands) == 1
+
+    def fn(page, ops):
+        return maybe_grouped_aggregate(
+            page, Q1_GROUPS, Q1_GROUP_NAMES, q1_aggs(),
+            rebind_plan(mask, ops),
+        )
+
+    page = jax.tree_util.tree_map(
+        lambda x: _spec((LINEITEM_SF1,) * x.ndim, x.dtype, one_chip),
+        lineitem_q1_page(0.001),
+    )
+    c = _compile(fn, page, (_spec((), operands[0].dtype, one_chip),))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 def test_matmul_agg_g4096_sf1(one_chip):
     from presto_tpu.ops.matmul_agg import grouped_matmul_partials
 
