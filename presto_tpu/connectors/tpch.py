@@ -23,9 +23,11 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from .. import types as T
+from ..obs import span as obs_span
 from ..page import Block, LazyDict, Page, intern_dictionary
 
 # ---------------------------------------------------------------------------
@@ -209,6 +211,14 @@ class Table:
     @property
     def num_rows(self) -> int:
         return len(next(iter(self.columns.values())).data)
+
+    @property
+    def nbytes(self) -> int:
+        """Bytes of the stored columns (dictionary codes, not text)."""
+        return sum(
+            c.data.nbytes + (0 if c.valid is None else c.valid.nbytes)
+            for c in self.columns.values()
+        )
 
     def to_page(self, start: int = 0, stop: Optional[int] = None, pad_to=None) -> Page:
         stop = self.num_rows if stop is None else min(stop, self.num_rows)
@@ -597,17 +607,30 @@ class TpchCatalog:
         plan channels). Cached: repeated queries reuse device arrays."""
         pg = self._pages.get(tname)
         if pg is None:
-            pg = self.host_table(tname).to_page()
+            tb = self.host_table(tname)
+            # the whole table, every column, host to device: a
+            # `table_load` span under the TableScan that asked, timed
+            # until the last column has arrived (docs/observability.md)
+            with obs_span.child(
+                "table_load", wall_as="upload_s", table=tname,
+                rows=tb.num_rows, columns=len(tb.columns), bytes=tb.nbytes,
+            ):
+                pg = jax.block_until_ready(tb.to_page())
             self._pages[tname] = pg
         return pg
 
     def host_table(self, tname: str) -> Table:
         """Host-resident (numpy) table, cached — the streaming scan source
         (reference ConnectorPageSource: data stays off-device until a split
-        batch is requested)."""
+        batch is requested). Generating it is a `table_load` span of its
+        own, under whatever asked first: as a rule the planner, for the
+        column statistics."""
         tb = self._tables.get(tname)
         if tb is None:
-            tb = table(tname, self.sf)
+            with obs_span.child(
+                "table_load", wall_as="generate_s", table=tname
+            ):
+                tb = table(tname, self.sf)
             self._tables[tname] = tb
         return tb
 

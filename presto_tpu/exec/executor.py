@@ -31,7 +31,7 @@ from ..ops.join import build, join_expand, join_n1, sorted_probe_layout
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
 from ..expr.compiler import project_page
 from ..obs.span import current as current_span
-from ..obs.span import host_read
+from ..obs.span import held, host_read
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
 
@@ -343,6 +343,15 @@ class Executor:
                 cur[0].leave(span, "error")
             raise
         if span is not None:
+            if isinstance(node, N.Join) and len(pages) == 2:
+                # rows in and out, where the host holds them already
+                # (a `_shrink` below or here read them): never a read
+                for name, page in zip(
+                    ("probe_rows", "build_rows", "out_rows"), (*pages, out)
+                ):
+                    n = held(page.count)
+                    if n is not None:
+                        span.attrs[name] = int(n)
             wall = cur[0].leave(span).wall_s
         else:
             wall = time.perf_counter() - t0

@@ -33,6 +33,7 @@ the cross-thread `statement` / `queued` spans use.
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import threading
 import time
@@ -369,6 +370,48 @@ def host_read(x):
         counters.get("host_read_wait_s", 0.0) + waited
     )
     return out
+
+
+def held(x):
+    """The host's copy of `x` where it holds one already, else None:
+    never a wait and never a counted read. A Python or numpy value is
+    held; a device array is once `host_read` (or anything else) has
+    fetched it, which on an accelerator leaves the copy on the array
+    (the CPU backend keeps none, so there this is None until the value
+    is a plain one)."""
+    if isinstance(x, jax.Array):
+        return getattr(x, "_npy_value", None)
+    return x
+
+
+@contextlib.contextmanager
+def child(name: str, wall_as: Optional[str] = None, **attrs):
+    """A span `name` under the calling thread's innermost open one, for
+    code below the executor (a connector) that has no trace in hand;
+    where the thread has no open span the body just runs. `wall_as`
+    names a counter that gets the body's seconds, folded upward as
+    `host_read_wait_s` is: `host_read`'s counterpart for what the host
+    MAKES or SENDS. A body that sends ends with `jax.block_until_ready`
+    on what it sent, so the transfer's wait is booked here, once, and
+    not in whichever read comes next."""
+    cur = getattr(_OPEN, "cur", None)
+    if cur is None:
+        yield
+        return
+    span = cur[0].enter(name, **attrs)
+    t0 = time.perf_counter()
+    status = "ok"
+    try:
+        yield
+    except BaseException:
+        status = "error"
+        raise
+    finally:
+        if wall_as is not None:
+            span.counters[wall_as] = (
+                span.counters.get(wall_as, 0.0) + time.perf_counter() - t0
+            )
+        cur[0].leave(span, status)
 
 
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
