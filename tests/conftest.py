@@ -8,6 +8,7 @@ multi-chip sharding logic on N virtual CPU devices in one process.
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
+import pathlib
 import re
 
 flags = os.environ.get("XLA_FLAGS", "")
@@ -118,6 +119,12 @@ _MODULE_TIMEOUTS = {
     "test_matview_chaos.py": 300,
     "test_feedback.py": 240,
 }
+# Every shard of the TPC-DS suite, however many there are (the limit is per
+# case: Q14, the longest, took 109 s in a whole run).
+_MODULE_TIMEOUTS.update(
+    (p.name, 300)
+    for p in (pathlib.Path(__file__).parent / "tpcds").glob("test_*.py")
+)
 
 _SLOW_CANDIDATE_S = 30.0
 _slow_candidates = []

@@ -2,11 +2,34 @@
 of presto-benchmark BenchmarkSuite.java:32) — every entry executes and
 reports sane rows/s on the test mesh backend."""
 
+import time
+
 from presto_tpu.benchmark.micro import DEVICE_BENCHES, run_suite
+
+_SMALL = {"sf": 0.005, "runs": 1}
+
+
+def _settle_soak(table):
+    """Run `mixed_soak_qps` again while it loses its own race: the bench
+    starts its writer thread and reads at once, and its 40 cached reads take
+    ~12 ms, so on a busy machine all of them can be served before the first
+    append lands; it then raises "zero patched reads" (2 of 12 runs under
+    `-n 6`). A broken patch verdict loses EVERY attempt, so poll to a
+    generous deadline, as conftest's spill guard does."""
+    deadline = time.monotonic() + 120.0
+    while (
+        "zero patched reads" in table["errors"].get("mixed_soak_qps", "")
+        and time.monotonic() < deadline
+    ):
+        again = run_suite(only=["mixed_soak_qps"], **_SMALL)
+        del table["errors"]["mixed_soak_qps"]
+        table["errors"].update(again["errors"])
+        table["results"].extend(again["results"])
 
 
 def test_suite_runs_every_operator():
-    table = run_suite(sf=0.005, runs=1)
+    table = run_suite(**_SMALL)
+    _settle_soak(table)
     assert table["backend"] == "cpu"
     names = {r["name"] for r in table["results"]}
     # every device bench + the host serde bench must produce a row;
@@ -31,5 +54,5 @@ def test_suite_runs_every_operator():
 
 
 def test_single_bench_selection():
-    table = run_suite(sf=0.005, runs=1, only=["filter_compact"])
+    table = run_suite(only=["filter_compact"], **_SMALL)
     assert [r["name"] for r in table["results"]] == ["filter_compact"]

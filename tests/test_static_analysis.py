@@ -2,6 +2,7 @@
 false-positive guards for every pass, suppression/baseline round-trips,
 and the tier-1 gate that keeps the REAL tree clean."""
 
+import gc
 import json
 import textwrap
 import time
@@ -1400,17 +1401,36 @@ def test_baseline_fingerprints_survive_line_drift(tmp_path):
 # -- the tier-1 gate --------------------------------------------------------
 
 
+def _cpu_seconds(fn):
+    """(CPU seconds of this process, result) of one call, garbage of
+    earlier tests collected first."""
+    gc.collect()
+    t0 = time.process_time()
+    out = fn()
+    return time.process_time() - t0, out
+
+
 def test_repo_is_clean_and_fast():
     """THE gate: zero un-baselined findings on the real tree, in well
     under the 10s budget. A new finding means: fix it, allow() it with a
-    reason, or (for pre-existing classes) re-baseline deliberately."""
-    t0 = time.monotonic()
-    result = run_check(REPO_ROOT)
-    dt = time.monotonic() - t0
+    reason, or (for pre-existing classes) re-baseline deliberately.
+    The budget is CPU seconds of the linter, which is single-threaded and
+    starts no process. A busy machine stretches those as it does wall
+    seconds (6.4 s idle, 10.5-11.1 s beside five tier-1 workers that keep
+    every hardware thread busy, 10.8-12.4 s alone on a slow day of the
+    host), so a check over 10 s is held to the cost of parsing the same
+    tree just then: it takes 6.5-7.5 parses on an idle and on a loaded
+    machine alike, and 11 parses is the headroom 10 s leaves over 6.4."""
+    dt, result = _cpu_seconds(lambda: run_check(REPO_ROOT))
     assert result.ok, "NEW prestolint findings:\n" + "\n".join(
         f.render() for f in result.new
     )
-    assert dt < 10.0, f"prestolint took {dt:.1f}s (budget 10s)"
+    if dt >= 10.0:
+        parse, _ = _cpu_seconds(lambda: load_project(REPO_ROOT))
+        assert dt < 11.0 * parse, (
+            f"prestolint took {dt:.1f}s of CPU (budget 10s) and "
+            f"{dt / parse:.1f} parses of the tree (budget 11)"
+        )
 
 
 def test_all_eight_passes_registered():

@@ -390,3 +390,32 @@ def test_lint_module_entrypoint_real_tree():
     )
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "clean" in proc.stdout
+
+
+# -- the TPC-DS suite's sharding (tests/tpcds/) -------------------------------
+
+
+def test_tpcds_shards_partition_the_queries():
+    """Every TPC-DS query is in exactly one shard, and the shard files on
+    disk are exactly k = 0..N_SHARDS-1, each asking for its own k: a file
+    lost in a merge would silently drop its share of the oracle suite."""
+    import importlib.util
+    from pathlib import Path
+
+    from presto_tpu.benchmark.tpcds_sql import QUERIES
+
+    here = Path(__file__).resolve().parent / "tpcds"
+    spec = importlib.util.spec_from_file_location(
+        "tpcds_cases", here / "cases.py"
+    )
+    cases = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(cases)
+
+    dealt = [q for k in range(cases.N_SHARDS) for q in cases.shard(k)]
+    assert sorted(dealt) == sorted(QUERIES)
+    assert {p.name for p in here.glob("test_*.py")} == {
+        f"test_tpcds_queries_{k}.py" for k in range(cases.N_SHARDS)
+    }
+    for k in range(cases.N_SHARDS):
+        text = (here / f"test_tpcds_queries_{k}.py").read_text()
+        assert f'parametrize("qid", shard({k}))' in text, k

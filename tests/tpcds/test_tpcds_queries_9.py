@@ -1,0 +1,10 @@
+"""Shard 9 of the TPC-DS oracle suite (cases.py says why it is sharded)."""
+
+import pytest
+
+from cases import check, shard
+
+
+@pytest.mark.parametrize("qid", shard(9))
+def test_tpcds_query(session, oracle, qid):
+    check(session, oracle, qid)
