@@ -324,16 +324,13 @@ def phase_served(sf: float, counter, reference, with_q18: bool) -> None:
 def _check_strategies(session, q1_sql, q3_sql) -> None:
     """EXPLAIN ANALYZE must name the paths the code declares default on
     tpu: the Pallas single-pass group-by for Q1, and for Q3 the
-    device-resident sorted-hash join with the bucket-directory probe —
-    never the hash-table path, whose kernels Mosaic refuses."""
+    device-resident sorted-hash join with the bucket-directory probe."""
     from presto_tpu.ops.join import sorted_probe_layout
-    from presto_tpu.ops.pallas_join import pallas_join_mode
 
     notes1 = re.findall(r"strategy=([^\],]*)", session.explain_analyze(q1_sql))
     _require("pallas" in notes1, f"Q1 strategies {notes1}: no Pallas group-by")
-    _require(pallas_join_mode() == "off", f"join mode {pallas_join_mode()}")
     notes3 = re.findall(r"strategy=([^\],]*)", session.explain_analyze(q3_sql))
-    joins = [n for n in notes3 if n.startswith(("sorted-hash", "hash-table"))]
+    joins = [n for n in notes3 if n.startswith("sorted-hash")]
     want = f"sorted-hash({sorted_probe_layout()})"
     _require(
         joins and all(j == want for j in joins),

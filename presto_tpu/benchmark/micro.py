@@ -40,10 +40,10 @@ class Bench:
     step: Callable  # (acc: int64, *args) -> int64  (jittable unless eager)
     args: tuple
     note: str = ""
-    # eager steps run UNJITTED — the hash-table join / hash group-by
-    # engine defaults route around jit (host scans need concrete
-    # operands; the ops/sort.py host-sort idiom), so their micros must
-    # measure the same eager dispatch the engine uses
+    # eager steps run UNJITTED — the hash group-by engine default
+    # routes around jit (host scans need concrete operands; the
+    # ops/sort.py host-sort idiom), so its micro must measure the same
+    # eager dispatch the engine uses
     eager: bool = False
 
 
@@ -336,131 +336,57 @@ def _orders_keys_page(sf: float):
 
 
 def bench_join_build(sf: float) -> Bench:
-    """Build-side index construction through the ENGINE-DEFAULT path
-    (ref: BenchmarkHashBuildAndJoinOperators build phase /
-    HashBuilderOperator.finish). Since PR 11 build() produces the
-    linear-probe hash table (ops/pallas_join.py) on cpu/tpu, eagerly —
-    the micro measures exactly what the executor dispatches; the sorted
-    fallback layout is measured by forcing PRESTO_TPU_PALLAS_JOIN=off."""
+    """Build-side index construction (ref:
+    BenchmarkHashBuildAndJoinOperators build phase /
+    HashBuilderOperator.finish): the sorted-hash layout with its bucket
+    directory, as the executor's join kernels build it."""
     from .. import types as T
     from ..expr.ir import col
-    from ..ops.join import build
-    from ..ops.pallas_join import JoinTable, pallas_join_mode
+    from ..ops.join import build_sorted
 
     page = _orders_keys_page(sf)
     keys = (col("o_orderkey", T.BIGINT),)
 
     def step(acc, p):
-        bs = build(_chained_page(p, acc), keys)
-        if isinstance(bs, JoinTable):
-            return _consume((bs.slot_tag, bs.slot_row, bs.count))
+        bs = build_sorted(_chained_page(p, acc), keys)
         return _consume((bs.sorted_hash, bs.order, bs.count))
 
-    return Bench(
-        "join_build", int(page.count), step, (page,),
-        note=f"mode={pallas_join_mode()}",
-        eager=pallas_join_mode() != "off",
-    )
+    return Bench("join_build", int(page.count), step, (page,))
 
 
 def bench_join_probe(sf: float) -> Bench:
-    """FK->PK probe through the ENGINE-DEFAULT path: lineitem x orders
-    (ref: join phase of BenchmarkHashBuildAndJoinOperators; rows/s counts
-    PROBE rows). The build side is prepared once (the executor's
-    _probe_stream shape); each run probes the full lineitem page."""
+    """FK->PK probe: lineitem x orders (ref: join phase of
+    BenchmarkHashBuildAndJoinOperators; rows/s counts PROBE rows). The
+    build side is prepared once (the executor's _probe_stream shape);
+    each run probes the full lineitem page."""
+    import dataclasses as dc
+
     from .. import types as T
     from ..expr.ir import col
-    from ..ops.join import build, join_n1
-    from ..ops.pallas_join import pallas_join_mode
+    from ..ops.join import build_sorted, join_n1
     from .handcoded import _table_page
 
     probe = _table_page("lineitem", sf, ("l_orderkey", "l_extendedprice"))
-    bs = build(_orders_keys_page(sf), (col("o_orderkey", T.BIGINT),))
+    bs = build_sorted(
+        _orders_keys_page(sf), (col("o_orderkey", T.BIGINT),)
+    )
     pkeys = (col("l_orderkey", T.BIGINT),)
     out_names = ("o_custkey", "o_totalprice")
 
-    if pallas_join_mode() == "off":
-        # sorted-layout mode runs JITTED: thread the build arrays as
-        # runtime args (a closure would bake them in as trace constants
-        # and let XLA fold build-side work — not comparable to the
-        # BENCH_r05 baseline this measures against)
-        import dataclasses as dc
-
-        def step(acc, p, sorted_hash, order, bpage, count):
-            b = dc.replace(bs, sorted_hash=sorted_hash, order=order,
-                           page=bpage, count=count)
-            return _consume(
-                join_n1(_chained_page(p, acc), b, pkeys, out_names,
-                        out_names)
-            )
-
-        return Bench(
-            "join_probe_n1", int(probe.count), step,
-            (probe, bs.sorted_hash, bs.order, bs.page, bs.count),
-            note="mode=off",
+    # thread the build arrays as runtime args (a closure would bake them
+    # in as trace constants and let XLA fold build-side work — not
+    # comparable to the BENCH_r05 baseline this measures against)
+    def step(acc, p, sorted_hash, order, bpage, count):
+        b = dc.replace(bs, sorted_hash=sorted_hash, order=order,
+                       page=bpage, count=count)
+        return _consume(
+            join_n1(_chained_page(p, acc), b, pkeys, out_names,
+                    out_names)
         )
-
-    def step(acc, p):
-        out = join_n1(
-            _chained_page(p, acc), bs, pkeys, out_names, out_names
-        )
-        return _consume(out)
 
     return Bench(
-        "join_probe_n1",
-        int(probe.count),
-        step,
-        (probe,),
-        note=f"mode={pallas_join_mode()}",
-        eager=True,
-    )
-
-
-def bench_pallas_join_build(sf: float) -> Bench:
-    """The hash-table build kernel in isolation (ops/pallas_join.py
-    build_table: parallel linear-probing insert + overflow handling) —
-    gated so the kernel path stays fast even if engine defaults move."""
-    from .. import types as T
-    from ..expr.ir import col
-    from ..ops.pallas_join import build_table
-
-    page = _orders_keys_page(sf)
-    keys = (col("o_orderkey", T.BIGINT),)
-
-    def step(acc, p):
-        jt = build_table(_chained_page(p, acc), keys)
-        if jt is None:
-            raise RuntimeError("hash-table build unexpectedly ineligible")
-        return _consume((jt.slot_tag, jt.slot_row))
-
-    return Bench("pallas_join_build", int(page.count), step, (page,),
-                 eager=True)
-
-
-def bench_pallas_join_probe(sf: float) -> Bench:
-    """The hash-table probe kernel in isolation (first-verified-match
-    scan + emit, ops/pallas_join.table_join_n1)."""
-    from .. import types as T
-    from ..expr.ir import col
-    from ..ops.pallas_join import build_table, table_join_n1
-    from .handcoded import _table_page
-
-    probe = _table_page("lineitem", sf, ("l_orderkey", "l_extendedprice"))
-    jt = build_table(_orders_keys_page(sf), (col("o_orderkey", T.BIGINT),))
-    if jt is None:
-        raise RuntimeError("hash-table build unexpectedly ineligible")
-    pkeys = (col("l_orderkey", T.BIGINT),)
-
-    def step(acc, p):
-        out = table_join_n1(
-            _chained_page(p, acc), jt, pkeys,
-            ("o_custkey", "o_totalprice"), ("o_custkey", "o_totalprice"),
-        )
-        return _consume(out)
-
-    return Bench(
-        "pallas_join_probe", int(probe.count), step, (probe,),
-        note=f"occ={int(jt.occupancy() * 100)}%", eager=True,
+        "join_probe_n1", int(probe.count), step,
+        (probe, bs.sorted_hash, bs.order, bs.page, bs.count),
     )
 
 
@@ -544,7 +470,7 @@ def bench_join_probe_filtered(sf: float) -> Bench:
     from ..exec.dynfilter import derive_filter
     from ..expr.ir import col
     from ..ops.filter import compact
-    from ..ops.join import build, join_n1
+    from ..ops.join import build_sorted, join_n1
     from ..page import Page, round_capacity
     from .handcoded import _table_page
 
@@ -556,7 +482,7 @@ def bench_join_probe_filtered(sf: float) -> Bench:
     okey = orders.block("o_orderkey")
     sel = (okey.data % 16 == 0) & (jnp.arange(orders.capacity) < orders.count)
     bpage = compact(orders, sel)
-    bs = build(bpage, (col("o_orderkey", T.BIGINT),))
+    bs = build_sorted(bpage, (col("o_orderkey", T.BIGINT),))
     df = derive_filter(okey, sel)
     if df is None:
         raise RuntimeError("derive_filter unexpectedly ineligible")
@@ -781,11 +707,11 @@ def bench_semi_join(sf: float) -> Bench:
     semi variant; rows/s counts probe rows)."""
     from .. import types as T
     from ..expr.ir import col
-    from ..ops.join import build, semi_match_mask
+    from ..ops.join import build_sorted, semi_match_mask
     from .handcoded import _table_page
 
     probe = _table_page("lineitem", sf, ("l_orderkey",))
-    bs = build(_orders_keys_page(sf), (col("o_orderkey", T.BIGINT),))
+    bs = build_sorted(_orders_keys_page(sf), (col("o_orderkey", T.BIGINT),))
     pkeys = (col("l_orderkey", T.BIGINT),)
 
     def step(acc, p):
@@ -959,8 +885,6 @@ DEVICE_BENCHES = {
     "agg_matmul_suppkey": bench_agg_matmul,
     "join_build": bench_join_build,
     "join_probe_n1": bench_join_probe,
-    "pallas_join_build": bench_pallas_join_build,
-    "pallas_join_probe": bench_pallas_join_probe,
     "pallas_groupby_hash": bench_pallas_groupby_hash,
     "join_probe_filtered": bench_join_probe_filtered,
     "bloom_build_query": bench_bloom_build_query,

@@ -52,9 +52,6 @@ def main(sf: float = 0.1, runs: int = 5):
     bpage = _orders_keys_page(sf)
     kexpr = (col("o_orderkey", T.BIGINT),)
     pkexpr = (col("l_orderkey", T.BIGINT),)
-    # this profiler decomposes the SORTED-hash layout's stages; the
-    # hash-table default (ops/pallas_join.py) has its own micros
-    # (pallas_join_build / pallas_join_probe in benchmark/micro.py)
     bs = J.build_sorted(bpage, kexpr)
     pkeys = [evaluate(e, probe) for e in pkexpr]
     h = hash_rows(pkeys)

@@ -37,7 +37,7 @@ from ..ops.aggregate import (
     grouped_aggregate_sorted,
 )
 from ..ops.filter import compact, filter_page
-from ..ops.join import build, join_expand, join_n1
+from ..ops.join import build_sorted, join_expand, join_n1
 from ..ops.sort import distinct_page, limit_page, top_n
 from ..expr.compiler import project_page
 from ..page import Block, Page, round_capacity
@@ -614,7 +614,7 @@ replicated subtrees delegate to the single-node Executor."""
                 )
                 out, overflow = join_expand(
                     lx,
-                    build(rx, node.right_keys),
+                    build_sorted(rx, node.right_keys),
                     node.left_keys,
                     lx.names,
                     [(nm, nm) for nm in right_names],
@@ -691,7 +691,7 @@ replicated subtrees delegate to the single-node Executor."""
         def make_n1(l: Page, r: Page) -> Page:
             return join_n1(
                 l,
-                build(r, node.right_keys),
+                build_sorted(r, node.right_keys),
                 node.left_keys,
                 right_names,
                 right_names,
@@ -718,7 +718,7 @@ replicated subtrees delegate to the single-node Executor."""
             def make_expand(l: Page, r: Page):
                 return join_expand(
                     l,
-                    build(r, node.right_keys),
+                    build_sorted(r, node.right_keys),
                     node.left_keys,
                     l.names,
                     [(nm, nm) for nm in right_names],
@@ -767,7 +767,7 @@ replicated subtrees delegate to the single-node Executor."""
         if node.residual is None:
 
             def local(p: Page, s: Page) -> Page:
-                bs = build(s, node.source_keys)
+                bs = build_sorted(s, node.source_keys)
                 return join_n1(
                     p,
                     bs,
@@ -796,7 +796,7 @@ replicated subtrees delegate to the single-node Executor."""
 
             def local(p: Page, s: Page):
                 p2 = self.local._with_row_id(p, rid)
-                bs = build(s, node.source_keys)
+                bs = build_sorted(s, node.source_keys)
                 probe_out = [rid] + [nm for nm in p.names if nm in needed]
                 build_out = [(nm, nm) for nm in s.names if nm in needed]
                 expanded, overflow = join_expand(
@@ -809,7 +809,7 @@ replicated subtrees delegate to the single-node Executor."""
                     kind="inner",
                 )
                 matched = filter_page(expanded, node.residual)
-                bs2 = build(matched, (ir.ColumnRef(rid, rid_t),))
+                bs2 = build_sorted(matched, (ir.ColumnRef(rid, rid_t),))
                 out = join_n1(
                     p2,
                     bs2,

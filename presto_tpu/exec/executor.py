@@ -27,7 +27,12 @@ from .. import types as T
 from ..expr import ir
 from ..ops.aggregate import global_aggregate, grouped_aggregate_sorted
 from ..ops.filter import compact, filter_page
-from ..ops.join import build, join_expand, join_n1, sorted_probe_layout
+from ..ops.join import (
+    build_sorted,
+    join_expand,
+    join_n1,
+    sorted_probe_layout,
+)
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
 from ..expr.compiler import project_page
 from ..obs.span import current as current_span
@@ -225,90 +230,6 @@ class Executor:
             for i, c in enumerate(node.children)
         ]
 
-    def _star_spec(self, node: N.PlanNode):
-        """The inner Join of a fusable star shape: two stacked inner n1
-        joins whose probe keys BOTH live on the shared fact side, no
-        residuals — the multiway-probe shape of arXiv:1905.13376 (one
-        pass over the fact resolves both dimensions; see
-        ops/pallas_join.table_multiway_n1)."""
-        from ..expr import ir as _ir
-        from ..ops.pallas_join import pallas_join_mode
-
-        if not isinstance(node, N.Join) or node.kind != "inner":
-            return None
-        if not node.unique_build or node.residual is not None:
-            return None
-        inner = node.left
-        if not isinstance(inner, N.Join) or inner.kind != "inner":
-            return None
-        if not inner.unique_build or inner.residual is not None:
-            return None
-        fact_names = {n for n, _ in inner.left.fields}
-        for k in node.left_keys:
-            if not isinstance(k, _ir.ColumnRef) or k.name not in fact_names:
-                return None
-        if pallas_join_mode() == "off":
-            return None
-        from .breaker import BREAKERS
-
-        if not (
-            BREAKERS.allow("pallas_join_build")
-            and BREAKERS.allow("pallas_join_probe")
-        ):
-            return None
-        return inner
-
-    def _run_star_join(self, node: N.Join, inner: N.Join, pos=None) -> Page:
-        """Fused multiway execution of a star pair; an ineligible side
-        degrades to plain nested execution on the pages already run
-        (materialized plan results — nothing is consume-once; the
-        fact's preprobe re-application inside _exec_join is an
-        idempotent re-filter)."""
-        from ..ops.pallas_join import table_multiway_n1
-
-        dim1 = self._run(inner.right, pos and pos + ".0.1")
-        if getattr(inner, "dynamic_filters", ()):
-            self._publish_dynamic_filters(inner, dim1)
-        dim2 = self._run(node.right, pos and pos + ".1")
-        if getattr(node, "dynamic_filters", ()):
-            self._publish_dynamic_filters(node, dim2)
-        fact = self._run(inner.left, pos and pos + ".0.0")
-        if getattr(inner, "dynamic_filters", ()):
-            fact = self._apply_preprobe(inner, fact)
-        if getattr(node, "dynamic_filters", ()):
-            fact = self._apply_preprobe(node, fact)
-        bs1 = self._build_table_guarded(dim1, inner.right_keys)
-        bs2 = self._build_table_guarded(dim2, node.right_keys)
-        if bs1 is None or bs2 is None:
-            mid = self._exec_join(inner, fact, dim1)
-            return self._exec_join(node, mid, dim2)
-        names1 = tuple(n for n, _ in inner.right.fields)
-        names2 = tuple(n for n, _ in node.right.fields)
-        try:
-            out = table_multiway_n1(
-                fact,
-                (
-                    (bs1, tuple(inner.left_keys), names1, names1),
-                    (bs2, tuple(node.left_keys), names2, names2),
-                ),
-            )
-        except Exception as exc:  # noqa: BLE001 — degrade, don't fail
-            from .breaker import BREAKERS
-
-            BREAKERS.record_failure("pallas_join_probe", repr(exc))
-            mid = self._exec_join(inner, fact, dim1)
-            return self._exec_join(node, mid, dim2)
-        from .breaker import BREAKERS
-
-        BREAKERS.record_success("pallas_join_probe")
-        self._strategy_note(inner, "multiway-fused")
-        self._strategy_note(
-            node,
-            f"multiway occ={int(bs1.occupancy() * 100)}%"
-            f"/{int(bs2.occupancy() * 100)}%",
-        )
-        return self._shrink(out, node)
-
     def _run(self, node: N.PlanNode, pos=None) -> Page:
         """One plan node: its inputs, then the node. Where a trace is
         open on this thread (obs/span.py; never under PRESTO_TPU_TRACE=0)
@@ -367,11 +288,6 @@ class Executor:
 
     def _run_node(self, node: N.PlanNode, pos):
         """(output, input pages, adaptive re-runs of this node)."""
-        inner = self._star_spec(node)
-        if inner is not None:
-            # fused execution: the outer node carries the pair's stats
-            # (child scans/builds record their own rows via self._run)
-            return self._run_star_join(node, inner, pos), [], 0
         pages = self._run_children(node, pos)
         retries_before = self._retries
         out = self.exec_node(node, *pages)
@@ -1172,81 +1088,6 @@ class Executor:
         return self._shrink(fn(page), node)
 
     # -- joins --
-    def _build_table_guarded(self, page: Page, key_exprs):
-        """build_table with build()'s breaker bookkeeping but WITHOUT
-        build()'s sorted fallback — an ineligible table here must cost
-        nothing (the jitted sorted path will build inside its own
-        kernel; an eager sorted build would be discarded)."""
-        from ..ops.pallas_join import build_table
-        from .breaker import BREAKERS
-
-        try:
-            jt = build_table(page, key_exprs)
-        except Exception as exc:  # noqa: BLE001 — degrade, don't fail
-            BREAKERS.record_failure("pallas_join_build", repr(exc))
-            return None
-        if jt is not None:
-            BREAKERS.record_success("pallas_join_build")
-        return jt
-
-    def _try_table_join(self, node: N.Join, left: Page, right: Page,
-                        right_names) -> Optional[Page]:
-        """EAGER hash-table join attempt (ops/pallas_join.py) — routed
-        AROUND jit like host-sort plans (the PR 9 idiom): the table path
-        needs concrete operands, and jitting its host scans would mean
-        pure_callback on the single-device CPU runtime. None = take the
-        jitted sorted-hash kernel path below. build()/join_n1()/
-        join_expand() own the breaker bookkeeping and the degrade to the
-        sorted layout on kernel faults."""
-        from ..ops.pallas_join import TABLE_MAX_BUILD, pallas_join_mode
-
-        if pallas_join_mode() == "off" or not node.right_keys:
-            return None
-        if right.capacity > TABLE_MAX_BUILD:
-            return None
-        from .breaker import BREAKERS
-
-        if not (
-            BREAKERS.allow("pallas_join_build")
-            and BREAKERS.allow("pallas_join_probe")
-        ):
-            return None
-        bs = self._build_table_guarded(right, node.right_keys)
-        if bs is None:
-            return None
-        self._strategy_note(
-            node,
-            f"hash-table({pallas_join_mode()}) "
-            f"occ={int(bs.occupancy() * 100)}%"
-            + (f" of={len(bs.of_tag)}" if len(bs.of_tag) else ""),
-        )
-        if node.unique_build:
-            out = join_n1(
-                left, bs, node.left_keys, right_names, right_names,
-                kind=node.kind,
-            )
-        else:
-            est = self._est_rows(node)
-            cap = round_capacity(
-                max(left.capacity, int(est) if est is not None else 1, 1)
-            )
-            while True:
-                out, overflow = join_expand(
-                    left, bs, node.left_keys, left.names,
-                    [(n, n) for n in right_names], out_capacity=cap,
-                    kind=node.kind,
-                )
-                over = int(host_read(overflow))
-                if over == 0:
-                    break
-                cap = round_capacity(cap + over)
-                self._retries += 1
-        if node.residual is not None:
-            if node.kind != "inner":
-                raise ExecutionError("residual on outer join not yet supported")
-            out = filter_page(out, node.residual)
-        return self._shrink(out, node)
-
     def _exec_join(self, node: N.Join, left: Page, right: Page) -> Page:
         if node.kind == "full" or (
             node.kind != "inner" and node.residual is not None
@@ -1255,9 +1096,6 @@ class Executor:
         if node.dynamic_filters:
             left = self._apply_preprobe(node, left)
         right_names = right.names
-        table_out = self._try_table_join(node, left, right, right_names)
-        if table_out is not None:
-            return table_out
         self._strategy_note(node, f"sorted-hash({sorted_probe_layout()})")
         if node.unique_build:
             out = self._kernel_guarded(
@@ -1266,7 +1104,7 @@ class Executor:
                 (node, "n1"),
                 lambda: lambda l, r: join_n1(
                     l,
-                    build(r, node.right_keys),
+                    build_sorted(r, node.right_keys),
                     node.left_keys,
                     right_names,
                     right_names,
@@ -1296,7 +1134,7 @@ class Executor:
                 (node, "expand", c),
                 lambda: lambda l, r: join_expand(
                     l,
-                    build(r, node.right_keys),
+                    build_sorted(r, node.right_keys),
                     node.left_keys,
                     l.names,
                     [(n, n) for n in right_names],
@@ -1336,7 +1174,7 @@ class Executor:
         right2 = self._with_row_id(right, rid_r)
         rid_t = T.BIGINT
 
-        bs = build(right2, node.right_keys)
+        bs = build_sorted(right2, node.right_keys)
         probe_out = list(left.names) + [rid_l]
         build_out = [(n, n) for n in right.names] + [(rid_r, rid_r)]
         est = self._est_rows(node)
@@ -1378,7 +1216,7 @@ class Executor:
         parts = [drop(matched, {rid_l, rid_r})]
 
         # probe rows with no surviving match -> null build columns
-        bs_l = build(matched, (ir.ColumnRef(rid_l, rid_t),))
+        bs_l = build_sorted(matched, (ir.ColumnRef(rid_l, rid_t),))
         left_un = join_n1(
             left2, bs_l, (ir.ColumnRef(rid_l, rid_t),), [], [], kind="anti"
         )
@@ -1391,7 +1229,7 @@ class Executor:
             )
         )
         if full:
-            bs_r = build(matched, (ir.ColumnRef(rid_r, rid_t),))
+            bs_r = build_sorted(matched, (ir.ColumnRef(rid_r, rid_t),))
             right_un = join_n1(
                 right2, bs_r, (ir.ColumnRef(rid_r, rid_t),), [], [], kind="anti"
             )
@@ -1417,12 +1255,12 @@ class Executor:
     def _exec_semijoin(self, node: N.SemiJoin, probe: Page, source: Page) -> Page:
         if node.dynamic_filters:
             probe = self._apply_preprobe(node, probe)
+        self._strategy_note(node, f"sorted-hash({sorted_probe_layout()})")
         if node.residual is None:
             from ..ops.join import semi_match_mask
-            from ..ops.pallas_join import pallas_join_mode
 
             def probe_fn(p, s):
-                bs = build(s, node.source_keys)
+                bs = build_sorted(s, node.source_keys)
                 if node.mark is not None:
                     return semi_match_mask(p, bs, node.probe_keys)
                 return join_n1(
@@ -1430,17 +1268,13 @@ class Executor:
                     kind="anti" if node.anti else "semi",
                 )
 
-            if pallas_join_mode() == "off":
-                # no host hash table on this backend: ONE jitted
-                # sorted-hash kernel, as _exec_join does. Run eagerly,
-                # the collision scan's while_loop re-traces and compiles
-                # on every execution of the statement.
-                out = self._kernel_guarded(
-                    "join_probe", "semi_join", (node, "semi"),
-                    lambda: probe_fn, probe, source,
-                )
-            else:
-                out = probe_fn(probe, source)
+            # ONE jitted sorted-hash kernel, as _exec_join does. Run
+            # eagerly, the collision scan's while_loop re-traces and
+            # compiles on every execution of the statement.
+            out = self._kernel_guarded(
+                "join_probe", "semi_join", (node, "semi"),
+                lambda: probe_fn, probe, source,
+            )
             if node.mark is not None:
                 return self._attach_mark(probe, out, node.mark)
             return self._shrink(out, node)
@@ -1448,7 +1282,7 @@ class Executor:
         # residual, then keep probe rows whose row-id survived
         rid = self._row_id_channel(probe)
         probe2 = self._with_row_id(probe, rid)
-        bs = build(source, node.source_keys)
+        bs = build_sorted(source, node.source_keys)
         needed = self._residual_channels(node.residual)
         probe_out = [rid] + [n for n in probe.names if n in needed]
         build_out = [(n, n) for n in source.names if n in needed]
@@ -1471,7 +1305,7 @@ class Executor:
         matched = filter_page(expanded, node.residual)
         matched = self._shrink(matched, node)
         rid_type = T.BIGINT
-        bs2 = build(matched, (ir.ColumnRef(rid, rid_type),))
+        bs2 = build_sorted(matched, (ir.ColumnRef(rid, rid_type),))
         if node.mark is not None:
             from ..ops.join import semi_match_mask
 

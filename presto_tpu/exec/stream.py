@@ -42,7 +42,7 @@ from ..ops.aggregate import (
     grouped_aggregate_sorted,
 )
 from ..ops.filter import filter_page
-from ..ops.join import build, join_expand, join_n1
+from ..ops.join import build_sorted, join_expand, join_n1
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
 from ..ops.union import concat_pages
 from ..page import Block, Page, round_capacity
@@ -1050,7 +1050,7 @@ class StreamingExecutor:
             mem_held = page_device_bytes(mem_page)
             self.pool.reserve(mem_held, "hybrid join resident build")
             try:
-                bs_mem = build(mem_page, node.right_keys)
+                bs_mem = build_sorted(mem_page, node.right_keys)
             except BaseException:
                 self.pool.free(mem_held)
                 raise
@@ -1162,7 +1162,7 @@ class StreamingExecutor:
             # and nothing downstream to feed either.
             empty = spilled.take_page(np.empty(0, np.int64))
             yield from self._probe_with(
-                node, build(empty, node.right_keys), right_names,
+                node, build_sorted(empty, node.right_keys), right_names,
                 iter([first_probe]),
             )
         self._record_hybrid_outcome(node, P, depth_before)
@@ -1209,7 +1209,7 @@ class StreamingExecutor:
             nb = page_device_bytes(page)
             self.pool.reserve(nb, "hybrid join partition build")
             try:
-                bs = build(page, node.right_keys)
+                bs = build_sorted(page, node.right_keys)
                 yield from self._probe_with(
                     node, bs, right_names,
                     self._spilled_pages(probe_sub, chunk_rows),
@@ -1251,7 +1251,7 @@ class StreamingExecutor:
             nb = page_device_bytes(page)
             self.pool.reserve(nb, "hybrid join build chunk")
             try:
-                bs = build(page, node.right_keys)
+                bs = build_sorted(page, node.right_keys)
                 yield from self._probe_with(
                     node, bs, right_names,
                     self._spilled_pages(probe_sub, chunk_rows),
@@ -1309,7 +1309,7 @@ class StreamingExecutor:
     def _probe_stream(
         self, node: N.Join, right_page: Page, right_names, probe=None
     ) -> Iterator[Page]:
-        bs = build(right_page, node.right_keys)
+        bs = build_sorted(right_page, node.right_keys)
         preprobe = getattr(node, "dynamic_filters", ()) and any(
             not consumed for _f, _i, consumed in node.dynamic_filters
         )
@@ -1404,7 +1404,7 @@ class StreamingExecutor:
         )
         held = self.pool.reserve(page_device_bytes(source), "semijoin source")
         try:
-            bs = build(source, node.source_keys)
+            bs = build_sorted(source, node.source_keys)
             for batch in self.stream(node.child):
                 if preprobe:
                     batch = self.local._apply_preprobe(node, batch)

@@ -6,8 +6,7 @@ from .aggregate import (  # noqa: F401
 )
 from .filter import compact, filter_page, filter_project_page  # noqa: F401
 from .hashing import hash_rows  # noqa: F401
-from .join import BuildSide, build, build_sorted, join_expand, join_n1  # noqa: F401
-from .pallas_join import JoinTable, build_table  # noqa: F401
+from .join import BuildSide, build_sorted, join_expand, join_n1  # noqa: F401
 from .sort import (  # noqa: F401
     SortKey,
     apply_permutation,

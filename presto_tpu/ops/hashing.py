@@ -87,17 +87,16 @@ def hash_rows(columns) -> jnp.ndarray:
 #
 # Dictionary codes are per-table: the same string can carry different codes
 # on the two sides of a join, so hashing codes (hash_column above) is only
-# safe within one table. For join partitioning / hash-table tags the two
+# safe within one table. For join partitioning / hash ordering the two
 # sides must agree for equal VALUES, so varchar columns rehash through a
 # per-dictionary value-hash lookup table: vh[code] = crc-seeded splitmix64
 # of the string bytes, computed ONCE per interned dictionary and cached.
 # 32-bit crc collisions only create false candidates — true key equality
 # (dictionary-unified code compare) always decides matches.
 #
-# Eager/host contexts only: the lookup table is a host array; embedding it
-# in a traced kernel would bake a per-dictionary constant into the
-# executable (one recompile per dictionary). Callers (ops/pallas_join.py,
-# exec/spill.hash_partition_indices) run eagerly by design.
+# The lookup table is a host array, so a traced kernel carries it as a
+# per-dictionary constant: a block's dict_id is static pytree data
+# (page.py), so a new dictionary is a new trace with or without it.
 
 _VALUE_HASH_BY_DICT: dict = {}
 
@@ -120,9 +119,10 @@ def value_hash_max_dict() -> int:
 
 # prestolint: host-function -- one-time host pass over an interned
 # dictionary; jnp only finishes the mix on the host-built array
-def dict_value_hashes(dict_id: int) -> np.ndarray:
+def dict_value_hashes(dict_id: int) -> jnp.ndarray:
     """(len(dictionary),) uint64 value hashes for an interned dictionary,
-    cached per dict_id (dictionaries are immutable once interned)."""
+    cached on the device per dict_id (dictionaries are immutable once
+    interned)."""
     vh = _VALUE_HASH_BY_DICT.get(dict_id)
     if vh is None:
         import zlib
@@ -136,9 +136,8 @@ def dict_value_hashes(dict_id: int) -> np.ndarray:
             raw[i] = np.uint64(zlib.crc32(b)) | (
                 np.uint64(len(b) & 0xFFFFFFFF) << np.uint64(32)
             )
-        vh = np.asarray(mix64(jnp.asarray(raw)))
-        if not len(entries):
-            vh = vh[:0]
+        with jax.ensure_compile_time_eval():  # the caller may be a trace
+            vh = mix64(jnp.asarray(raw))[: len(entries)]
         _VALUE_HASH_BY_DICT[dict_id] = vh
     return vh
 
@@ -155,88 +154,19 @@ def value_hashable(columns) -> bool:
     return True
 
 
-def _np_mix64(x: np.ndarray) -> np.ndarray:
-    """splitmix64 finalizer, numpy twin of mix64 (uint64 wraps mod 2^64;
-    numpy wraps silently for unsigned dtypes)."""
-    x = x.astype(np.uint64)
-    x = (x ^ (x >> np.uint64(30))) * _C1
-    x = (x ^ (x >> np.uint64(27))) * _C2
-    return x ^ (x >> np.uint64(31))
-
-
-def _np_hash_column(data: np.ndarray, valid) -> np.ndarray:
-    """hash_column's numpy twin — bit-identical results (the host join
-    path hashes probe batches every call; eager jnp dispatch overhead
-    was ~40% of the whole probe)."""
-    if data.ndim == 2:
-        hs = [_np_hash_column(data[:, i], None) for i in range(data.shape[1])]
-        h = np_combine_hashes(hs)
-        if valid is not None:
-            h = np.where(valid, h, _NULL_HASH)
-        return h
-    if np.issubdtype(data.dtype, np.floating):
-        data = np.where(data == 0, np.zeros_like(data), data)
-        data = np.where(np.isnan(data), np.full_like(data, np.nan), data)
-        idtype = {4: np.uint32, 8: np.uint64}[data.dtype.itemsize]
-        bits = data.view(idtype).astype(np.uint64)
-    else:
-        bits = data.astype(np.uint64)
-    h = _np_mix64(bits)
-    if valid is not None:
-        h = np.where(valid, h, _NULL_HASH)
-    return h
-
-
-def np_combine_hashes(hashes) -> np.ndarray:
-    out = np.zeros_like(hashes[0])
-    for h in hashes:
-        out = (out * np.uint64(31)) + h
-        out = _np_mix64(out + _GOLDEN)
-    return out
-
-
-# prestolint: host-function -- host twin of hash_rows_values for the
-# eager join/group-by kernels (np.asarray on CPU jax arrays is zero-copy)
-def np_hash_rows_values(columns) -> np.ndarray:
-    """hash_rows_values computed entirely in numpy — bit-identical to
-    the jnp version (both are splitmix64 over the same canonicalized
-    bits), for the host kernel paths where per-op jax dispatch dominates."""
-    hs = []
-    for c in columns:
-        valid = None if c.valid is None else np.asarray(c.valid)
-        if getattr(c, "dict_id", None) is not None:
-            vh = dict_value_hashes(c.dict_id)
-            codes = np.asarray(c.data).astype(np.int64)
-            np.clip(codes, 0, max(len(vh) - 1, 0), out=codes)
-            h = (
-                vh[codes]
-                if len(vh)
-                else np.full(codes.shape, _NULL_HASH)
-            )
-            if valid is not None:
-                h = np.where(valid, h, _NULL_HASH)
-        else:
-            h = _np_hash_column(np.asarray(c.data), valid)
-        hs.append(h)
-    return np_combine_hashes(hs) if len(hs) > 1 else hs[0]
-
-
-# prestolint: host-function -- eager-only by contract (module note):
-# gathers host value-hash tables by concrete dictionary codes
 def hash_rows_values(columns) -> jnp.ndarray:
     """hash_rows with table-independent varchar hashing: dictionary
     columns hash their VALUES via dict_value_hashes, so build and probe
-    sides of a join partition/tag identically for equal strings. Eager
-    contexts only (see module note); callers gate on value_hashable()."""
+    sides of a join partition/tag identically for equal strings.
+    Callers gate on value_hashable()."""
     hs = []
     for c in columns:
         if getattr(c, "dict_id", None) is not None:
             vh = dict_value_hashes(c.dict_id)
-            codes = np.asarray(c.data).astype(np.int64)
-            np.clip(codes, 0, max(len(vh) - 1, 0), out=codes)
-            h = jnp.asarray(
-                vh[codes] if len(vh) else np.full(codes.shape, _NULL_HASH)
-            )
+            if len(vh):
+                h = vh[jnp.clip(c.data.astype(jnp.int32), 0, len(vh) - 1)]
+            else:
+                h = jnp.full(c.data.shape, _NULL_HASH)
             if c.valid is not None:
                 h = jnp.where(c.valid, h, _NULL_HASH)
             hs.append(h)

@@ -21,16 +21,6 @@ Composite keys hash-combine then verify each part.
 
 Supported: inner, left (probe-outer), semi, anti — the shapes TPC-H needs.
 Right/full outer come with the planner's join-side swap in a later round.
-
-PR 11: the sorted-hash layout above is now the FALLBACK. `build()` first
-tries the linear-probe hash-table layout in ops/pallas_join.py (Pallas
-kernels on TPU, the numpy twin on the CPU engine default) behind the
-pallas_join_build / pallas_join_probe circuit breakers; join_n1 /
-join_expand / semi_match_mask dispatch on which layout `build()`
-produced, and a probe-side kernel fault degrades back to this file's
-composition (rebuilding the sorted layout from the table's retained
-build page). Traced callers (jitted executors, the shard_map mesh path)
-always get the sorted layout — the table path is eager by design.
 """
 
 from __future__ import annotations
@@ -55,17 +45,14 @@ from .hashing import (
 )
 
 
-def _want_value_hash(keys, count) -> bool:
-    """Eager build with varchar keys whose dictionaries admit the
-    one-time value pass -> hash by VALUE so cross-dictionary equi-joins
-    meet (see BuildSide.value_hashed)."""
-    if not any(getattr(k, "dict_id", None) is not None for k in keys):
-        return False
-    concrete = not any(
-        isinstance(a, jax.core.Tracer)
-        for a in [count] + [k.data for k in keys]
-    )
-    return concrete and value_hashable(keys)
+def _want_value_hash(keys) -> bool:
+    """Varchar keys whose dictionaries admit the one-time value pass ->
+    hash by VALUE so cross-dictionary equi-joins meet (see
+    BuildSide.value_hashed)."""
+    return any(
+        getattr(k, "dict_id", None) is not None for k in keys
+    ) and value_hashable(keys)
+
 
 # numpy scalar (not a device array) so importing this module does no device work
 MAX_HASH = np.uint64(0xFFFFFFFFFFFFFFFF)
@@ -87,23 +74,10 @@ class BuildSide:
     bucket_bits: int = 0  # static per build shape
     # True when varchar keys were hashed by dictionary VALUE
     # (hash_rows_values): probes MUST hash the same way or equal strings
-    # with different codes never meet (the pre-PR-11 cross-dictionary
-    # varchar equi-join wrong-result, now fixed for eager builds). Traced
-    # builds keep code hashing — both sides of a traced join share one
-    # trace, so they stay consistent (and reach only same-dictionary
-    # data in practice: the mesh shards one table's pages).
+    # with different codes never meet. Eager and traced builds alike:
+    # the two sides of a jitted join are two tables, each with its own
+    # dictionary (PR 31: a code-hashed traced join dropped those matches).
     value_hashed: bool = False
-
-
-# The PRESTO_TPU_JOIN_PROBE_HOST pure_callback searchsorted route that
-# lived here (PR 3's `_default_host_probe`, measured 4x slower than the
-# bucket-directory probe and default-off ever since) is DELETED, not just
-# still off: PR 11 re-measured it against the hash-table kernels and the
-# numpy linear-probe scan in ops/pallas_join.py beats it ~7x at the
-# join_probe_n1 shape (22ms vs ~150ms for 600k probes) while also beating
-# the directory probe — so the CPU host route is now the ENGINE DEFAULT
-# via build_table(), and the searchsorted callback (plus its
-# join_probe_cpu breaker) has no remaining niche.
 
 
 def _pick_bucket_bits(capacity: int) -> int:
@@ -111,31 +85,6 @@ def _pick_bucket_bits(capacity: int) -> int:
     so the unrolled 4-slot collision scan covers nearly every probe."""
     bits = max(1, int(np.ceil(np.log2(max(capacity, 1) * 2))))
     return min(bits, 22)  # cap the directory at 4M entries
-
-
-def build(page: Page, key_exprs):
-    """Prepare a build side for probing. First choice: the linear-probe
-    hash-table layout (ops/pallas_join.py — the numpy twin, the CPU
-    engine default; off on TPU), behind the pallas_join_build /
-    pallas_join_probe breakers. Otherwise — on TPU, for traced operands
-    and for cross joins — the sorted-hash layout of build_sorted."""
-    if key_exprs:
-        from ..exec.breaker import BREAKERS
-
-        if BREAKERS.allow("pallas_join_build") and BREAKERS.allow(
-            "pallas_join_probe"
-        ):
-            from .pallas_join import build_table
-
-            try:
-                jt = build_table(page, key_exprs)
-            except Exception as exc:  # noqa: BLE001 — degrade, don't fail
-                BREAKERS.record_failure("pallas_join_build", repr(exc))
-            else:
-                if jt is not None:
-                    BREAKERS.record_success("pallas_join_build")
-                    return jt
-    return build_sorted(page, key_exprs)
 
 
 def sorted_probe_layout() -> str:
@@ -166,7 +115,7 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     with jax.named_scope("hash"):
         keys = [evaluate(e, page) for e in key_exprs]
         live = page.live_mask()
-        value_hashed = _want_value_hash(keys, page.count)
+        value_hashed = _want_value_hash(keys)
         if not keys:
             h = jnp.zeros(page.capacity, jnp.uint64)
         elif value_hashed:
@@ -295,41 +244,9 @@ def _collision_scan(bs: BuildSide, probe_keys, lo, hi, max_scan: int = 4):
     return matched, build_row
 
 
-def _table_dispatch(bs, run_table, run_legacy):
-    """Route through the hash-table kernels when build() produced a
-    JoinTable; a probe-side kernel fault records on the pallas_join_probe
-    breaker and degrades to the sorted-hash composition by rebuilding
-    from the table's retained build page (rare: the breaker then opens
-    and subsequent build() calls skip the table outright)."""
-    from .pallas_join import JoinTable
-
-    if isinstance(bs, JoinTable):
-        from ..exec.breaker import BREAKERS
-
-        try:
-            out = run_table(bs)
-        except Exception as exc:  # noqa: BLE001 — degrade, don't fail
-            BREAKERS.record_failure("pallas_join_probe", repr(exc))
-            bs = build_sorted(bs.page, bs.key_exprs)
-            try:
-                return run_legacy(bs)
-            except Exception:
-                # the sorted fallback failed the same way: a semantic /
-                # data-shape error, not a kernel fault — neutralize the
-                # breaker hit so one bad join cannot degrade the kernel
-                # path for the whole process (same contract as
-                # Executor._kernel_guarded)
-                BREAKERS.record_success("pallas_join_probe")
-                raise
-        else:
-            BREAKERS.record_success("pallas_join_probe")
-            return out
-    return run_legacy(bs)
-
-
 def join_n1(
     probe: Page,
-    bs,
+    bs: BuildSide,
     probe_key_exprs,
     build_names: Sequence[str],
     out_build_names: Sequence[str],
@@ -340,27 +257,6 @@ def join_n1(
 
     Output capacity == probe capacity; probe columns pass through, build
     payload columns are gathered (null where unmatched, for `left`)."""
-    from .pallas_join import table_join_n1
-
-    return _table_dispatch(
-        bs,
-        lambda jt: table_join_n1(
-            probe, jt, probe_key_exprs, build_names, out_build_names, kind
-        ),
-        lambda b: _join_n1_sorted(
-            probe, b, probe_key_exprs, build_names, out_build_names, kind
-        ),
-    )
-
-
-def _join_n1_sorted(
-    probe: Page,
-    bs: BuildSide,
-    probe_key_exprs,
-    build_names: Sequence[str],
-    out_build_names: Sequence[str],
-    kind: str = "inner",
-) -> Page:
     probe_keys = [evaluate(e, probe) for e in probe_key_exprs]
     live = probe.live_mask()
     _, lo, hi = _probe_ranges(bs, probe_keys, probe.capacity)
@@ -393,21 +289,11 @@ def _join_n1_sorted(
     raise ValueError(f"unknown join kind {kind!r}")
 
 
-def semi_match_mask(probe: Page, bs, probe_key_exprs) -> jnp.ndarray:
-    """Boolean per-probe-row match membership (the mark-join kernel:
-    reference HashSemiJoinOperator's semiJoinOutput channel)."""
-    from .pallas_join import table_semi_mask
-
-    return _table_dispatch(
-        bs,
-        lambda jt: table_semi_mask(probe, jt, probe_key_exprs),
-        lambda b: _semi_match_mask_sorted(probe, b, probe_key_exprs),
-    )
-
-
-def _semi_match_mask_sorted(
+def semi_match_mask(
     probe: Page, bs: BuildSide, probe_key_exprs
 ) -> jnp.ndarray:
+    """Boolean per-probe-row match membership (the mark-join kernel:
+    reference HashSemiJoinOperator's semiJoinOutput channel)."""
     probe_keys = [evaluate(e, probe) for e in probe_key_exprs]
     live = probe.live_mask()
     _, lo, hi = _probe_ranges(bs, probe_keys, probe.capacity)
@@ -416,33 +302,6 @@ def _semi_match_mask_sorted(
 
 
 def join_expand(
-    probe: Page,
-    bs,
-    probe_key_exprs,
-    probe_out: Sequence[str],
-    build_out: Sequence[Tuple[str, str]],  # (build col, output name)
-    out_capacity: int,
-    kind: str = "inner",
-) -> Tuple[Page, jnp.ndarray]:
-    """General 1:N join dispatcher — see _join_expand_sorted for the
-    contract; the table path emits VERIFIED pairs so its overflow is
-    exact rather than a candidate bound."""
-    from .pallas_join import table_join_expand
-
-    return _table_dispatch(
-        bs,
-        lambda jt: table_join_expand(
-            probe, jt, probe_key_exprs, probe_out, build_out,
-            out_capacity, kind,
-        ),
-        lambda b: _join_expand_sorted(
-            probe, b, probe_key_exprs, probe_out, build_out,
-            out_capacity, kind,
-        ),
-    )
-
-
-def _join_expand_sorted(
     probe: Page,
     bs: BuildSide,
     probe_key_exprs,

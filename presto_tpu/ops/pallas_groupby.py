@@ -550,7 +550,7 @@ def pallas_available() -> bool:
 # boolean column (mixed-radix gid over the domain product, G <= 64). The
 # hash-slot path below lifts that ceiling: ARBITRARY-valued keys (int64
 # order keys, composite keys, floats, NULLs) map to dense group ids
-# through the same linear-probe slot machinery as ops/pallas_join.py —
+# through a linear-probe slot table —
 # a distinct-insert pass assigns each row the slot of its key's first
 # occurrence (true key equality verified against the slot's
 # representative row, so 32-bit tag collisions re-probe instead of

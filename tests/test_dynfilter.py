@@ -409,17 +409,19 @@ def test_faulting_derivation_degrades_not_fails(tpch, monkeypatch):
     assert BREAKERS.get("dynamic_filter").total_failures > 0
 
 
-def test_table_join_matches_sorted_probe(tpch, monkeypatch):
-    # PR 11 deleted the PRESTO_TPU_JOIN_PROBE_HOST searchsorted callback
-    # route (re-measured ~7x slower than the hash-table host scan that is
-    # now the engine default); this pin replaces its oracle: the
-    # hash-table default must agree with the sorted-layout fallback
-    monkeypatch.setenv("PRESTO_TPU_PALLAS_JOIN", "off")
-    off = Session(tpch, dynamic_filtering=False)
-    want = sorted(map(repr, off.query(Q3).rows()))
-    monkeypatch.delenv("PRESTO_TPU_PALLAS_JOIN")
-    table = Session(tpch, dynamic_filtering=False)
-    assert sorted(map(repr, table.query(Q3).rows())) == want
+def test_directory_probe_matches_searchsorted_probe(tpch):
+    # the join's default probe layout (bucket directory) must agree with
+    # the layout an open join_probe breaker degrades it to, on every
+    # join of Q3 (join_n1 and join_expand)
+    want = sorted(
+        map(repr, Session(tpch, dynamic_filtering=False).query(Q3).rows())
+    )
+    br = BREAKERS.get("join_probe")
+    for _ in range(br.failure_threshold):
+        br.record_failure("injected")
+    degraded = Session(tpch, dynamic_filtering=False, result_cache=False)
+    assert sorted(map(repr, degraded.query(Q3).rows())) == want
+    assert "sorted-hash(searchsorted)" in degraded.explain_analyze(Q3)
 
 
 # ---------------------------------------------------------------------------

@@ -1,9 +1,7 @@
-"""Pallas-native hash join + group-by kernels (PR 11): the linear-probe
-JoinTable layout (ops/pallas_join.py) against the sorted-hash fallback
-and a pure-python oracle, the hash-slot group-by against the sort
-composition, the ragged paged partition layout (ops/ragged.py), breaker
-degradation, and the engine wiring (executor strategy notes, multiway
-star fusion, EXPLAIN ANALYZE occupancy)."""
+"""The sorted-hash join (ops/join.py) against a pure-python oracle, the
+hash-slot group-by against the sort composition, the ragged paged
+partition layout (ops/ragged.py), and the engine wiring (executor
+strategy notes, a star query against the SQLite oracle)."""
 
 import numpy as np
 import pytest
@@ -15,12 +13,11 @@ from presto_tpu.connectors.memory import MemoryCatalog
 from presto_tpu.exec.breaker import BREAKERS
 from presto_tpu.expr.ir import col
 from presto_tpu.ops import ragged
-from presto_tpu.ops.join import build, build_sorted, join_expand, join_n1, semi_match_mask
-from presto_tpu.ops.pallas_join import (
-    JoinTable,
-    build_table,
-    table_join_n1,
-    table_multiway_n1,
+from presto_tpu.ops.join import (
+    build_sorted,
+    join_expand,
+    join_n1,
+    semi_match_mask,
 )
 from presto_tpu.page import Block, Page, round_capacity
 from presto_tpu.session import Session
@@ -70,7 +67,7 @@ def _rows(out, names):
 
 
 # ---------------------------------------------------------------------------
-# property suite: table == sorted == oracle across dtypes x NULLs x skew x
+# property suite: sorted-hash join == oracle across dtypes x NULLs x skew x
 # empty x build-larger-than-probe
 # ---------------------------------------------------------------------------
 
@@ -111,8 +108,6 @@ def test_join_property_suite(dtype, nulls, skew):
               count=np_ - 5)
     keys = (col("k", kt),)
 
-    jt = build(b, keys)
-    assert isinstance(jt, JoinTable)
     bs = build_sorted(b, keys)
 
     # oracle pair multiset over live, non-null rows
@@ -126,15 +121,14 @@ def test_join_property_suite(dtype, nulls, skew):
     from collections import Counter
 
     for kind in ("inner", "left"):
-        def run(bs_):
-            cap = 1 << 13
-            while True:
-                out, ov = join_expand(
-                    p, bs_, keys, ("w",), [("v", "bv")], cap, kind=kind
-                )
-                if int(ov) == 0:
-                    return out
-                cap = round_capacity(cap + int(ov))
+        cap = 1 << 13
+        while True:
+            out, ov = join_expand(
+                p, bs, keys, ("w",), [("v", "bv")], cap, kind=kind
+            )
+            if int(ov) == 0:
+                break
+            cap = round_capacity(cap + int(ov))
 
         want = []
         for i in range(np_ - 5):
@@ -145,24 +139,18 @@ def test_join_property_suite(dtype, nulls, skew):
                 want.append((i, None))
         want_pairs = Counter(want)
 
-        for out in (run(jt), run(bs)):
-            got = Counter(
-                (w, bv)
-                for w, bv in _rows(out, ("w", "bv"))
-            )
-            want_c = Counter(
-                (w, None if m is None else m) for w, m in want_pairs.elements()
-            )
-            assert got == want_c, (kind, dtype, nulls, skew)
+        got = Counter(_rows(out, ("w", "bv")))
+        assert got == want_pairs, (kind, dtype, nulls, skew)
 
     # -- semi / anti / mark --
     want_semi = sorted(i for i in plive if pk[i].item() in by_key)
-    got_t = _rows(join_n1(p, build(b, keys), keys, (), (), kind="semi"), ("w",))
     got_s = _rows(join_n1(p, bs, keys, (), (), kind="semi"), ("w",))
-    assert got_t == got_s == sorted([(i,) for i in want_semi])
-    mask_t = np.asarray(semi_match_mask(p, build(b, keys), keys))
+    assert got_s == [(i,) for i in want_semi]
+    got_a = _rows(join_n1(p, bs, keys, (), (), kind="anti"), ("w",))
+    semi = set(want_semi)
+    assert got_a == [(i,) for i in range(np_ - 5) if i not in semi]
     mask_s = np.asarray(semi_match_mask(p, bs, keys))
-    assert (mask_t == mask_s).all()
+    assert np.flatnonzero(mask_s).tolist() == want_semi
 
 
 def test_empty_build_and_empty_probe():
@@ -171,21 +159,21 @@ def test_empty_build_and_empty_probe():
                "v": (np.arange(8), T.BIGINT, None)}, count=0)
     p = _page({"k": (np.arange(64, dtype=np.int64), T.BIGINT, None),
                "w": (np.arange(64), T.BIGINT, None)})
-    jt = build(b, keys)
-    out = join_n1(p, jt, keys, ("v",), ("bv",))
+    bs = build_sorted(b, keys)
+    out = join_n1(p, bs, keys, ("v",), ("bv",))
     assert int(out.count) == 0
-    out = join_n1(p, jt, keys, ("v",), ("bv",), kind="anti")
+    out = join_n1(p, bs, keys, ("v",), ("bv",), kind="anti")
     assert int(out.count) == 64
     # empty probe partition
     p0 = _page({"k": (np.arange(16, dtype=np.int64), T.BIGINT, None),
                 "w": (np.arange(16), T.BIGINT, None)}, count=0)
-    out = join_n1(p0, build(b, keys), keys, ("v",), ("bv",))
+    out = join_n1(p0, bs, keys, ("v",), ("bv",))
     assert int(out.count) == 0
 
 
-def test_varchar_cross_dictionary_table_join():
+def test_varchar_cross_dictionary_join():
     """Different dictionaries on the two sides: value hashing + unified
-    code verification must agree with the sorted path."""
+    code verification must find every match."""
     b = Page.from_dict(
         {"k": [f"s{i:03d}" for i in range(200)],
          "v": np.arange(200, dtype=np.int64)}
@@ -196,12 +184,11 @@ def test_varchar_cross_dictionary_table_join():
     kt = b.block("k").type
     keys = (col("k", kt),)
     assert b.block("k").dict_id != p.block("k").dict_id
-    jt = build(b, keys)
-    assert isinstance(jt, JoinTable)
-    got = _rows(join_n1(p, jt, keys, ("v",), ("bv",)), ("w", "bv"))
-    # python oracle over VALUES: the pre-PR-11 code-hash join dropped
-    # cross-dictionary matches; both the table path and the (eager,
-    # now value-hashed) sorted fallback must find every one
+    bs = build_sorted(b, keys)
+    assert bs.value_hashed
+    got = _rows(join_n1(p, bs, keys, ("v",), ("bv",)), ("w", "bv"))
+    # python oracle over VALUES: a code-hash join drops cross-dictionary
+    # matches; the eager, value-hashed build must find every one
     from presto_tpu.page import dictionary_by_id
 
     bd = dictionary_by_id(b.block("k").dict_id)
@@ -215,92 +202,41 @@ def test_varchar_cross_dictionary_table_join():
         if pd_[int(c)] in by_val
     )
     assert got == oracle and len(got) > 0
-    want = _rows(join_n1(p, build_sorted(b, keys), keys, ("v",), ("bv",)),
-                 ("w", "bv"))
-    assert want == oracle
 
 
-def test_interp_mode_pallas_kernels(monkeypatch):
-    """The Pallas build + probe kernels themselves (interpret mode) must
-    agree with the host twin, including the deep-scan continuation."""
-    monkeypatch.setenv("PRESTO_TPU_PALLAS_JOIN", "interp")
-    rng = np.random.default_rng(7)
-    nb, np_ = 500, 1200
-    bk = rng.integers(0, 200, nb).astype(np.int64)  # dups -> long scans
-    pk = rng.integers(0, 260, np_).astype(np.int64)
-    b = _page({"k": (bk, T.BIGINT, None), "v": (np.arange(nb), T.BIGINT, None)})
-    p = _page({"k": (pk, T.BIGINT, None), "w": (np.arange(np_), T.BIGINT, None)})
-    keys = (col("k", T.BIGINT),)
-    jt = build_table(b, keys)
-    got = _rows(table_join_n1(p, jt, keys, ("v",), ("bv",), kind="semi"), ("w",))
-    monkeypatch.delenv("PRESTO_TPU_PALLAS_JOIN")
-    want = _rows(join_n1(p, build_sorted(b, keys), keys, (), (), kind="semi"),
-                 ("w",))
-    assert got == want
+@pytest.mark.parametrize("shape", ["n1_jit", "join_sql", "semi_sql"])
+def test_varchar_cross_dictionary_traced_join(shape):
+    """The same under a trace, where the executor runs its joins: the
+    two sides are two tables with a dictionary each, so a traced build
+    must hash values too (PR 31: code-hashed, it met 87 of 541 pairs)."""
+    import jax
 
-
-def test_value_hash_np_twin_bit_identical():
-    from presto_tpu.ops.hashing import hash_rows_values, np_hash_rows_values
-
-    rng = np.random.default_rng(3)
-    n = 4096
-    cols = [
-        Block(jnp.asarray(rng.integers(-(2**50), 2**50, n)), T.BIGINT,
-              jnp.asarray(rng.random(n) > 0.1)),
-        Block(jnp.asarray(np.where(rng.random(n) < 0.05, np.nan,
-                                   rng.normal(size=n))), T.DOUBLE, None),
-    ]
-    a = np.asarray(hash_rows_values(cols))
-    bvals = np_hash_rows_values(cols)
-    assert (a == bvals).all()
-    # varchar via the per-dictionary value-hash table
-    pg = Page.from_dict({"s": [f"x{i%37}" for i in range(256)]})
-    c = [pg.block("s")]
-    assert (np.asarray(hash_rows_values(c)) == np_hash_rows_values(c)).all()
-
-
-# ---------------------------------------------------------------------------
-# breaker degradation
-# ---------------------------------------------------------------------------
-
-
-def test_build_breaker_routes_to_sorted():
-    b = _page({"k": (np.arange(100, dtype=np.int64), T.BIGINT, None),
-               "v": (np.arange(100), T.BIGINT, None)})
-    keys = (col("k", T.BIGINT),)
-    assert isinstance(build(b, keys), JoinTable)
-    br = BREAKERS.get("pallas_join_build")
-    for _ in range(br.failure_threshold):
-        br.record_failure("injected")
-    assert not isinstance(build(b, keys), JoinTable)
-
-
-def test_probe_fault_degrades_and_records(monkeypatch):
-    import presto_tpu.ops.pallas_join as pj
-
-    b = _page({"k": (np.arange(300, dtype=np.int64), T.BIGINT, None),
-               "v": (np.arange(300), T.BIGINT, None)})
-    p = _page({"k": (np.arange(0, 600, 2, dtype=np.int64), T.BIGINT, None),
-               "w": (np.arange(300), T.BIGINT, None)})
-    keys = (col("k", T.BIGINT),)
-    jt = build(b, keys)
-    assert isinstance(jt, JoinTable)
-    want = _rows(join_n1(p, build_sorted(b, keys), keys, ("v",), ("bv",)),
-                 ("w", "bv"))
-
-    def boom(*a, **k):
-        raise RuntimeError("injected probe kernel fault")
-
-    monkeypatch.setattr(pj, "table_join_n1", boom)
-    got = _rows(join_n1(p, jt, keys, ("v",), ("bv",)), ("w", "bv"))
-    assert got == want  # degraded mid-call by rebuilding the sorted layout
-    snap = BREAKERS.get("pallas_join_probe").snapshot()
-    assert snap["total_failures"] >= 1
-    monkeypatch.undo()
-    # breaker opened: next build() skips the table outright, restoring
-    # the pre-PR behavior end to end
-    assert not BREAKERS.allow("pallas_join_probe")
-    assert not isinstance(build(b, keys), JoinTable)
+    b = Page.from_dict(
+        {"k": [f"s{i:03d}" for i in range(200)],
+         "v": np.arange(200, dtype=np.int64)}
+    )
+    rng = np.random.default_rng(11)
+    pk = [f"s{i:03d}" for i in rng.integers(0, 260, 700)]
+    p = Page.from_dict({"pk": pk, "w": np.arange(700, dtype=np.int64)})
+    assert b.block("k").dict_id != p.block("pk").dict_id
+    want = sorted((w, int(x[1:])) for w, x in enumerate(pk) if int(x[1:]) < 200)
+    if shape == "n1_jit":
+        kt = b.block("k").type
+        fn = jax.jit(
+            lambda p_, b_: join_n1(
+                p_, build_sorted(b_, (col("k", kt),)), (col("pk", kt),),
+                ("v",), ("bv",),
+            )
+        )
+        assert _rows(fn(p, b), ("w", "bv")) == want
+        return
+    s = Session(MemoryCatalog({"b": b, "p": p}))
+    if shape == "join_sql":
+        got = s.query("select w, v from p join b on pk = k").rows()
+        assert sorted(got) == want
+    else:
+        got = s.query("select w from p where pk in (select k from b)").rows()
+        assert sorted(got) == [(w,) for w, _ in want]
 
 
 # ---------------------------------------------------------------------------
@@ -500,7 +436,7 @@ def test_hybrid_join_ragged_recursion_tiny_budget(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# engine wiring: strategy notes + multiway star fusion
+# engine wiring: strategy notes, a star query
 # ---------------------------------------------------------------------------
 
 
@@ -511,58 +447,29 @@ def test_explain_analyze_join_strategy_note():
     txt = s.explain_analyze(
         "select count(*) from lineitem, orders where l_orderkey = o_orderkey"
     )
-    assert "hash-table(" in txt and "occ=" in txt, txt
+    assert "strategy=sorted-hash(directory)" in txt, txt
 
 
 def test_multiway_star_fusion_oracle():
-    """Two stacked n1 joins with both keys on the fact side fuse into one
-    multiway probe pass (the planner must know the build keys are unique,
-    so TPC-H PK joins are the shape); results must match the plain nested
-    execution. result_cache=False keeps the two configurations from
-    serving each other's pages."""
-    import os
-
+    """Two stacked n1 joins with both keys on the fact side (the planner
+    knows the build keys are unique, so TPC-H PK joins are the shape)
+    run as two plain joins, one after the other, on every backend: the
+    answer is the SQLite oracle's and no node is fused."""
     from presto_tpu.connectors.tpch import TpchCatalog
+    from presto_tpu.testing.oracle import SqliteOracle, assert_same_results
 
-    cat = TpchCatalog(sf=0.01)
     sql = (
         "select count(*) c, "
         "sum(l_extendedprice + o_totalprice + s_acctbal) v from lineitem "
         "join orders on l_orderkey = o_orderkey "
         "join supplier on l_suppkey = s_suppkey"
     )
-    os.environ["PRESTO_TPU_PALLAS_JOIN"] = "off"
-    try:
-        want = Session(cat, result_cache=False).query(sql).rows()
-    finally:
-        del os.environ["PRESTO_TPU_PALLAS_JOIN"]
-    s = Session(cat, result_cache=False)
-    assert s.query(sql).rows() == want
-    txt = s.explain_analyze(sql)
-    assert "multiway" in txt and "multiway-fused" in txt, txt
-
-
-def test_multiway_op_matches_sequential():
-    rng = np.random.default_rng(23)
-    nf = 2000
-    fact = _page({
-        "k1": (rng.integers(0, 100, nf).astype(np.int64), T.BIGINT, None),
-        "k2": (rng.integers(-5, 60, nf).astype(np.int64), T.BIGINT, None),
-        "m": (np.arange(nf), T.BIGINT, None),
-    })
-    d1 = _page({"a": (np.arange(100, dtype=np.int64), T.BIGINT, None),
-                "av": (np.arange(100) * 2, T.BIGINT, None)})
-    d2 = _page({"b": (np.arange(60, dtype=np.int64), T.BIGINT, None),
-                "bv": (np.arange(60) * 3, T.BIGINT, None)})
-    jt1 = build_table(d1, (col("a", T.BIGINT),))
-    jt2 = build_table(d2, (col("b", T.BIGINT),))
-    fused = table_multiway_n1(
-        fact,
-        (
-            (jt1, (col("k1", T.BIGINT),), ("av",), ("av",)),
-            (jt2, (col("k2", T.BIGINT),), ("bv",), ("bv",)),
-        ),
+    s = Session(TpchCatalog(sf=0.01), result_cache=False)
+    result = s.query(sql)
+    assert_same_results(
+        result.rows(), SqliteOracle(sf=0.01).query(sql),
+        [b.type for b in result.page.blocks], ordered=False,
     )
-    step1 = join_n1(fact, jt1, (col("k1", T.BIGINT),), ("av",), ("av",))
-    step2 = join_n1(step1, jt2, (col("k2", T.BIGINT),), ("bv",), ("bv",))
-    assert _rows(fused, ("m", "av", "bv")) == _rows(step2, ("m", "av", "bv"))
+    txt = s.explain_analyze(sql)
+    assert "multiway" not in txt, txt
+    assert txt.count("strategy=sorted-hash(directory)") == 2, txt

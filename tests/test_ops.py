@@ -9,7 +9,7 @@ from presto_tpu.expr import col, lit, comparison, binary
 from presto_tpu.ops import (
     AggSpec,
     SortKey,
-    build,
+    build_sorted,
     compact,
     distinct_page,
     filter_page,
@@ -169,7 +169,7 @@ def test_join_n1_inner_left_semi_anti():
         {"k": np.array([3, 1, 4, 1, 5], np.int64), "v": np.array([30, 10, 40, 11, 50], np.int64)},
         pad_to=8,
     )
-    bs = build(build_page, [col("k", T.BIGINT)])
+    bs = build_sorted(build_page, [col("k", T.BIGINT)])
 
     out = join_n1(probe, bs, [col("k", T.BIGINT)], ["name"], ["name"], kind="inner")
     assert out.to_pylist() == [
@@ -203,7 +203,7 @@ def test_join_n1_null_keys_never_match():
         np.array([1, 2, 3], np.int64), T.BIGINT, valid=np.array([True, False, True])
     )
     probe = Page.from_blocks([pk], ["k"])
-    bs = build(build_page, [col("k", T.BIGINT)])
+    bs = build_sorted(build_page, [col("k", T.BIGINT)])
     out = join_n1(probe, bs, [col("k", T.BIGINT)], [], [], kind="semi")
     assert out.to_pylist() == [(1,)]
 
@@ -217,7 +217,7 @@ def test_join_expand_1n():
         {"k": np.array([3, 1, 9], np.int64), "v": np.array([300, 100, 900], np.int64)},
         pad_to=4,
     )
-    bs = build(build_page, [col("k", T.BIGINT)])
+    bs = build_sorted(build_page, [col("k", T.BIGINT)])
     out, overflow = join_expand(
         probe,
         bs,
@@ -305,8 +305,6 @@ def test_join_bucket_directory_stress():
 
     if os.environ.get("PRESTO_TPU_JOIN_PROBE", "directory") != "directory":
         pytest.skip("directory probe gated off via PRESTO_TPU_JOIN_PROBE")
-    from presto_tpu.ops.join import build_sorted
-
     rng = np.random.default_rng(7)
     nb, npr = 5000, 20000
     bk = rng.integers(0, 3000, nb)  # duplicates guaranteed
@@ -317,8 +315,7 @@ def test_join_bucket_directory_stress():
     )
     pk = rng.integers(0, 4000, npr)  # some keys miss entirely
     probe = Page.from_dict({"k": pk.astype(np.int64)}, pad_to=1 << 15)
-    # this test pins the SORTED layout's bucket directory (the table
-    # path has its own suite in tests/test_pallas_join.py)
+    # this test pins the sorted layout's bucket directory
     bs = build_sorted(build_page, [col("k", T.BIGINT)])
     assert bs.bucket_start is not None and bs.bucket_bits > 0
 

@@ -47,13 +47,11 @@ GATED = (
     # path misses either cache, so the gate catches a broken fast path
     # as well as a slow one
     "plan_cache_hit",
-    # hash-relational kernels (PR 11): join_build/join_probe_n1 measure
-    # the engine-default hash-table path (floors raised ~3x over the
-    # BENCH_r05 sorted-layout rates); the pallas_* rows pin the kernel
-    # family in isolation (build insert, first-match probe, hash-slot
-    # group-by) so a default-path change can't silently shelve them
-    "join_build", "join_probe_n1",
-    "pallas_join_build", "pallas_join_probe", "pallas_groupby_hash",
+    # hash-relational kernels: join_build/join_probe_n1 measure the
+    # sorted-hash join (the one join engine, floors at the BENCH_r05
+    # rates); pallas_groupby_hash pins the hash-slot group-by (PR 11)
+    # so a default-path change can't silently shelve it
+    "join_build", "join_probe_n1", "pallas_groupby_hash",
     # streaming ingest + incremental matviews (PR 14): delta refresh
     # must scale with the delta, not the base (the micro RAISES when
     # the refresh falls off the delta path, and its speedup_vs_full
