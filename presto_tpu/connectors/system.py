@@ -400,6 +400,7 @@ class SystemCatalog(Connector):
         # set explicitly (not via late getattr) so __getattr__ never
         # delegates the name to the wrapped catalog
         self.matview_manager = None
+        self._column_stats_cache = {}  # Connector.column_stats' own
 
     @property
     def name(self):
@@ -450,6 +451,19 @@ class SystemCatalog(Connector):
         if table in self._SYSTEM_TABLES:
             return []
         return self.wrapped.unique_columns(table)
+
+    def column_stats(self, table: str, column: str):
+        """The wrapped catalog's own statistics for its tables. Defined
+        here because `Connector.column_stats` would otherwise answer
+        before `__getattr__` can delegate, and the served planner would
+        read the SPI's 2^18-row sample of a catalog that knows better.
+        system.runtime.* tables, and a wrapped catalog with no
+        statistics, keep that sample (through `self.scan`)."""
+        if table not in self._SYSTEM_TABLES:
+            fn = getattr(self.wrapped, "column_stats", None)
+            if fn is not None:
+                return fn(table, column)
+        return Connector.column_stats(self, table, column)
 
     def table_version(self, table: str):
         # system.runtime.* are live views of server state: NEVER cacheable

@@ -22,6 +22,7 @@ upload is a few hundred bytes.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Optional, Sequence
 
 import numpy as np
@@ -84,10 +85,21 @@ class DeviceTpchCatalog(TpchCatalog):
             self._tables[tname] = tb
         return tb
 
+    # a table over this many rows is sampled for its statistics, in
+    # ranges spread over the whole table (as Connector's sample is)
+    STATS_SAMPLE_ROWS = 2_000_000
+    STATS_SAMPLE_RANGES = 8
+
     def column_stats(self, tname: str, column: str):
-        """CBO statistics from the numpy twin; very large tables are
-        sampled by prefix (the generators are row-wise stationary, so a
-        prefix is representative) to bound host memory at high SF."""
+        """CBO statistics from the numpy twin. A table over
+        STATS_SAMPLE_ROWS is sampled, to bound host memory at high SF, in
+        ranges spread from its first row to its last: the keys are
+        stored sorted, so only such ranges see the table's min and max.
+        Where the ranges share no value (a clustered key: every range
+        brings values of its own, however often each repeats inside it)
+        the distinct count grows with the table and is scaled by the
+        table's size over the sample's; `stats_from_column` scales only
+        a sample that is over half distinct."""
         from ..plan.stats import stats_from_column
 
         cache = getattr(self, "_stats_cache", None)
@@ -96,20 +108,36 @@ class DeviceTpchCatalog(TpchCatalog):
         key = (tname, column)
         if key not in cache:
             n = self.row_count(tname)
-            cap = 2_000_000
+            cap = self.STATS_SAMPLE_ROWS
+            pieces = None
             if tname in _HOST_SMALL or n <= cap:
                 col = self.host_table(tname).columns[column]
                 data, dic = col.data, col.dictionary
                 valid = getattr(col, "valid", None)
             else:
-                typ, pool = benchgen.SCHEMAS[tname][column]
-                data = benchgen.numpy_columns_range(
-                    tname, self.sf, (column,), 0, cap
-                )[column].astype(typ.storage_dtype)
-                dic, valid = pool, None
-            cache[key] = stats_from_column(
+                typ, dic = benchgen.SCHEMAS[tname][column]
+                span = cap // self.STATS_SAMPLE_RANGES
+                pieces = [
+                    benchgen.numpy_columns_range(
+                        tname, self.sf, (column,), int(start), span
+                    )[column].astype(typ.storage_dtype)
+                    for start in np.linspace(
+                        0, n - span, self.STATS_SAMPLE_RANGES
+                    ).astype(np.int64)
+                ]
+                data, valid = np.concatenate(pieces), None
+            stats = stats_from_column(
                 data, valid, self.schema(tname)[column], dic, n
             )
+            if pieces is not None and dic is None and (
+                stats.ndv <= 0.5 * len(data)  # not scaled already
+            ):
+                apart = sum(len(np.unique(p)) for p in pieces)
+                if stats.ndv >= 0.9 * apart:
+                    stats = dataclasses.replace(
+                        stats, ndv=stats.ndv * (n / len(data))
+                    )
+            cache[key] = stats
         return cache[key]
 
     def page(self, tname: str) -> Page:

@@ -273,6 +273,8 @@ class Executor:
                     n = held(page.count)
                     if n is not None:
                         span.attrs[name] = int(n)
+            if retries:
+                span.attrs["retries"] = retries
             wall = cur[0].leave(span).wall_s
         else:
             wall = time.perf_counter() - t0
@@ -690,6 +692,12 @@ class Executor:
         import re
 
         self.dyn_ctx.note_pruned(lead_fid, pruned, where)
+        cur = current_span()
+        if cur is not None:
+            # summed: the streaming driver applies the mask per batch
+            attrs = cur[1].attrs
+            attrs["dyn_pruned"] = attrs.get("dyn_pruned", 0) + pruned
+            attrs["dyn_strategy"] = descs
         if self.collector is None:
             return
         book = self.dyn_ctx.scan_pruned if where == "scan" else (
@@ -871,11 +879,18 @@ class Executor:
         decision and should be observable, not guessed): on the node's
         span, which is this thread's innermost while the node executes,
         and in the collector's stats."""
-        cur = current_span()
-        if cur is not None:
-            cur[1].attrs["strategy"] = name
+        self._span_note(strategy=name)
         if self.collector is not None:
             self.collector.stats_for(node).detail = f"strategy={name}"
+
+    @staticmethod
+    def _span_note(**attrs) -> None:
+        """Attributes for the executing node's span (this thread's
+        innermost while the node runs): values the host already holds,
+        never a read."""
+        cur = current_span()
+        if cur is not None:
+            cur[1].attrs.update(attrs)
 
     # -- aggregation --
     def _exec_aggregate(self, node: N.Aggregate, page: Page) -> Page:
@@ -1148,11 +1163,20 @@ class Executor:
                 break
             cap = round_capacity(cap + over)
             self._retries += 1
+        self._note_expand(est, cap)
         if node.residual is not None:
             if node.kind != "inner":
                 raise ExecutionError("residual on outer join not yet supported")
             out = filter_page(out, node.residual)
         return self._shrink(out, node)
+
+    def _note_expand(self, est, cap: int) -> None:
+        """On the join's span: the CBO's output estimate and the capacity
+        `join_expand` last ran at (its cost follows the slots, not the
+        rows: a capacity far over `out_rows` is an estimate far out)."""
+        self._span_note(
+            est_rows=None if est is None else int(est), out_capacity=cap
+        )
 
     def _exec_outer_join(self, node: N.Join, left: Page, right: Page) -> Page:
         """LEFT join with a residual ON filter, and FULL OUTER join.
@@ -1196,6 +1220,7 @@ class Executor:
                 break
             cap = round_capacity(cap + over)
             self._retries += 1
+        self._note_expand(est, cap)
         matched = (
             filter_page(expanded, node.residual)
             if node.residual is not None

@@ -127,6 +127,27 @@ def test_metrics_fold_lifecycle_phases(served):
         assert f"presto_query_phase_{phase}_seconds_count" in text
 
 
+def test_join_and_filter_spans_say_what_the_estimate_did(served):
+    """Q3 through the coordinator's SystemCatalog: the expand join's
+    span carries the CBO's estimate and the capacity `join_expand` ran
+    at, the span that applied a dynamic filter what it pruned."""
+    _rows, trace, _ = serve(served, sql_of("q3"))
+    spans = trace.spans()
+    # lineitem x (orders x customer); orders x customer is n:1
+    (expand,) = [
+        s for s in spans if s.name == "Join" and "out_capacity" in s.attrs
+    ]
+    attrs = expand.attrs
+    cap = attrs["out_capacity"]
+    assert cap & (cap - 1) == 0 and cap >= attrs["est_rows"] > 0
+    assert "retries" not in attrs  # the estimate held: no overflow, no re-run
+    pruned = [s for s in spans if "dyn_pruned" in s.attrs]
+    assert any(s.name == "Filter" for s in pruned)
+    assert {s.name for s in pruned} <= {"Filter", "TableScan", "Join"}
+    for s in pruned:
+        assert s.attrs["dyn_pruned"] >= 0 and ":" in s.attrs["dyn_strategy"]
+
+
 # -- (b) host reads ----------------------------------------------------------
 
 
