@@ -97,6 +97,11 @@ class Executor:
         # masks never repeat across batches/workers (ops/filter.py)
         self._sample_pos: Dict[int, int] = {}
         self.sample_salt = 0
+        # hash-sort group-bys that outgrew their first guess: plan node
+        # -> the capacity its groups needed (_exec_aggregate)
+        from .qcache import LRUCache
+
+        self._agg_groups = LRUCache(max_entries=4096, name="agg_groups")
         self._inputs_wall_s = 0.0  # _run: walls of the inputs run so far
 
     def _kernel(self, name, key, make_fn):
@@ -264,7 +269,7 @@ class Executor:
                 cur[0].leave(span, "error")
             raise
         if span is not None:
-            if isinstance(node, N.Join) and len(pages) == 2:
+            if isinstance(node, (N.Join, N.SemiJoin)) and len(pages) == 2:
                 # rows in and out, where the host holds them already
                 # (a `_shrink` below or here read them): never a read
                 for name, page in zip(
@@ -324,14 +329,8 @@ class Executor:
         device idles until the next dispatch. So the sync is only paid
         when shrinking can plausibly win: the page is big AND the CBO
         expects the live count to be well under capacity."""
-        if not self.shrink:
+        if not self._shrink_reads(page, node):
             return page
-        if page.capacity <= (1 << 14):
-            return page  # too small for shrinking to pay for a sync
-        if node is not None:
-            est = self._est_rows(node)
-            if est is not None and est >= 0.5 * page.capacity:
-                return page  # expected near-full: skip the sync
         n = int(host_read(page.count))
         cap = round_capacity(max(n, 1))
         if cap >= page.capacity:
@@ -339,6 +338,18 @@ class Executor:
         idx = slice(0, cap)
         blocks = [b.take_rows(idx) for b in page.blocks]
         return Page(tuple(blocks), page.names, page.count)
+
+    def _shrink_reads(self, page: Page, node: "N.PlanNode" = None) -> bool:
+        """Whether `_shrink` pays its sync for this page (see there)."""
+        if not self.shrink:
+            return False
+        if page.capacity <= (1 << 14):
+            return False  # too small for shrinking to pay for a sync
+        if node is not None:
+            est = self._est_rows(node)
+            if est is not None and est >= 0.5 * page.capacity:
+                return False  # expected near-full: skip the sync
+        return True
 
     def _node_plan_stats(self, node):
         """Memoized full CBO PlanStats for a node (column min/max/NDV —
@@ -539,7 +550,24 @@ class Executor:
             and BREAKERS.allow("dynamic_filter")
         )
 
-    def _dyn_worthwhile(self, node) -> bool:
+    def _est_join_rows(self, node, build: Optional[Page]):
+        """The CBO's estimate of a join's output, held against the build
+        side that arrived: a page of `capacity` slots holds at most that
+        many rows, and where that is under the build's own estimate (a
+        runtime filter below it pruned what the CBO cannot see: Q18's
+        orders, cut to the semi-join's few hundred keys) the output is
+        taken to shrink with it. A build at or over its estimate, or one
+        that is no page (spilled, sharded), leaves the figure as it is.
+        None where there are no statistics."""
+        est = self._est_rows(node)
+        if est is None or build is None:
+            return est
+        build_est = self._est_rows(node.children[1])
+        if not build_est or build.capacity >= build_est:
+            return est
+        return est * build.capacity / build_est
+
+    def _dyn_worthwhile(self, node, build: Optional[Page] = None) -> bool:
         """CBO benefit gate: deriving costs a build-side pass plus a probe
         mask, so skip when the join barely filters (est output close to
         the probe input — e.g. an unfiltered FK->PK join keeps every
@@ -551,7 +579,7 @@ class Executor:
         max_sel = float(
             os.environ.get("PRESTO_TPU_DYNFILTER_MAX_SEL", "0.7")
         )
-        out_est = self._est_rows(node)
+        out_est = self._est_join_rows(node, build)
         probe_est = self._est_rows(node.children[0])
         if out_est is None or probe_est is None or probe_est <= 0:
             return True
@@ -568,7 +596,9 @@ class Executor:
         from ..expr.compiler import evaluate
         from .dynfilter import derive_filter
 
-        if not self._dyn_enabled() or not self._dyn_worthwhile(node):
+        if not self._dyn_enabled() or not self._dyn_worthwhile(
+            node, build_page
+        ):
             return
         keys = (
             node.right_keys
@@ -616,7 +646,7 @@ class Executor:
         Returns (page, survivor count)."""
         import numpy as np
 
-        from ..ops.filter import compact
+        from ..ops.filter import LARGE_PAGE_ROWS, compact, compact_few
 
         keep = keep & page.live_mask()
         if jax.default_backend() == "cpu":
@@ -634,6 +664,14 @@ class Executor:
                 ),
                 n,
             )
+        if page.capacity >= LARGE_PAGE_ROWS:
+            # count first: a mask that keeps a sixteenth or less is
+            # compacted by `cap` binary searches, not a full-capacity sort
+            # and gather (one read, as below)
+            n = int(host_read(jnp.sum(keep.astype(jnp.int32))))
+            cap = round_capacity(max(n, 1))
+            if cap * 16 <= page.capacity:
+                return compact_few(page, keep, cap=cap), n
         out = compact(page, keep)
         n = int(host_read(out.count))
         cap = round_capacity(max(n, 1))
@@ -814,6 +852,20 @@ class Executor:
             for fid, _ch in node.dynamic_filters
         ):
             return self._exec_filter_dyn(node, page)
+        from ..ops.filter import LARGE_PAGE_ROWS, compact_few, keep_mask
+
+        if page.capacity >= LARGE_PAGE_ROWS and self._shrink_reads(page, node):
+            # the count `_shrink` would read, read BEFORE the compaction:
+            # a predicate that keeps a sixteenth or less of a large page
+            # (Q18's HAVING: ~100 of 2^24 slots) gathers only what it keeps
+            mask = self._kernel(
+                "filter_mask", ("filter_mask", node),
+                lambda: lambda p: keep_mask(p, node.predicate),
+            )
+            keep, count = mask(page)
+            cap = round_capacity(max(int(host_read(count)), 1))
+            if cap * 16 <= page.capacity:
+                return compact_few(page, keep, cap=cap)
         fn = self._kernel(
             "filter", node, lambda: lambda p: filter_page(p, node.predicate)
         )
@@ -867,7 +919,11 @@ class Executor:
             "project", node,
             lambda: lambda p: project_page(p, node.exprs, node.names),
         )
-        return fn(page)
+        out = fn(page)
+        # the same live count by construction: keep the input's count
+        # object, whose host copy (where a `_shrink` below read it) the
+        # jitted program's output lacks (`obs.span.held`)
+        return Page(out.blocks, out.names, page.count)
 
     def _exec_output(self, node: N.Output, page: Page) -> Page:
         blocks = tuple(page.block(c) for c in node.channels)
@@ -904,11 +960,19 @@ class Executor:
             import jax
 
             self.pallas_groupby = jax.default_backend() == "tpu"
-        if self.pallas_groupby:
+        # the slots this node's groups needed the last time it ran the
+        # sort strategy: past the hash-slot cap that attempt would read
+        # the keys and inputs to the host (1.5 GB for Q18's subquery at
+        # SF10) only to give up again
+        from ..ops.pallas_groupby import HASH_MAX_GROUPS_HOST
+
+        learned = self._agg_groups.get(node, count=False)
+        small = learned is None or learned <= HASH_MAX_GROUPS_HOST
+        if self.pallas_groupby and small:
             out = self._try_pallas_groupby(node, page)
             if out is not None:
                 return out
-        out = self._try_hash_groupby(node, page)
+        out = self._try_hash_groupby(node, page) if small else None
         if out is not None:
             return out
         if self.matmul_groupby is None:
@@ -935,23 +999,37 @@ class Executor:
         # of a blocking count sync; page.capacity bounds it above.
         est = self._est_rows(node)
         guess = int(est) if est is not None else page.capacity
+        # a large page takes the run-sum form, whose cost does not grow
+        # with its slots: there the estimate stands, and Q18's 15M groups
+        # run once, not as a 65,536-slot program first that is thrown away
+        from ..ops.aggregate import RUNS_MIN_ROWS
+
+        runs = page.capacity >= RUNS_MIN_ROWS
+        first_cap = page.capacity if runs else 1 << 16
         max_groups = round_capacity(
-            min(max(guess, 1), page.capacity, 1 << 16)
+            min(max(guess, 1), page.capacity, first_cap)
         )
+        # the capacity this node outgrew its guess to the last time it
+        # ran (a repeated statement's plan is the same object): start
+        # there, or every execution runs the whole program twice and
+        # throws the first away (Q18: 60M rows into 15M groups)
+        if learned is not None:
+            max_groups = min(learned, round_capacity(page.capacity))
         max_elems = 128  # collection-aggregate width (adaptive, like mg)
         while True:
             mg, me = max_groups, max_elems
             fn = self._kernel(
-                "grouped_aggregate_sorted", (node, mg, me),
+                "grouped_aggregate_sorted", (node, mg, me, runs),
                 lambda: lambda p: grouped_aggregate_sorted(
                     p, node.group_exprs, node.group_names, node.aggs, mg,
-                    node.mask, max_elems=me,
+                    node.mask, max_elems=me, runs=runs,
                 ),
             )
             out = fn(page)
             true_groups = int(host_read(out.count))
             if true_groups > max_groups:
                 max_groups = round_capacity(true_groups)
+                self._agg_groups.put(node, max_groups)
                 self._retries += 1
                 continue
             if "$collect_need" in out.names:
@@ -971,6 +1049,12 @@ class Executor:
                     out.count,
                 )
             break
+        # the count the loop read anyway, and the slots it last ran with
+        self._span_note(groups=true_groups, max_groups=max_groups)
+        if true_groups > HASH_MAX_GROUPS_HOST and learned is None:
+            # no retry taught it (the first guess held): still past the
+            # hash-slot cap, so the next execution skips that attempt
+            self._agg_groups.put(node, max_groups)
         return self._shrink(out, node)
 
     def _try_pallas_groupby(self, node: N.Aggregate, page: Page) -> Optional[Page]:
@@ -1135,9 +1219,9 @@ class Executor:
                 out = filter_page(out, node.residual)
             return self._shrink(out, node)
         # general 1:N expansion with adaptive capacity retry; initial
-        # guess = probe capacity vs CBO join-output estimate (no
-        # blocking count sync)
-        est = self._est_rows(node)
+        # guess = probe capacity vs CBO join-output estimate, held
+        # against the build that arrived (no blocking count sync)
+        est = self._est_join_rows(node, right)
         cap = round_capacity(
             max(left.capacity, int(est) if est is not None else 1, 1)
         )

@@ -645,6 +645,189 @@ def grouped_aggregate_direct(
 # ---------------------------------------------------------------------------
 
 
+# `grouped_aggregate_sorted` takes the run-sum form below from this many
+# rows up. Under it the scatter form stays: its programs are the ones every
+# smaller page already has compiled, and a multi-operand sort compiles
+# slowly (hashing.argsort_hashes). On the v5e a 60M-row single-operand
+# sort is tens of milliseconds and a gather or scatter ~15-30 ns an
+# element (PERF.md section 5, PR 33), so at size a sort that carries its
+# payload replaces every `x[order]` and every `segment_sum`.
+RUNS_MIN_ROWS = 1 << 23
+
+_RUN_FUNCS = ("sum", "avg", "count", "count_star")
+
+
+def _runs_eligible(keys, ins, aggs) -> bool:
+    """Integer keys and integer sum / avg / count: what a prefix sum over
+    rows in key order reproduces bit for bit (a float sum would round
+    differently, a float key has -0.0 and NaNs to canonicalize, min / max
+    have no inverse to subtract)."""
+
+    def int1d(v):
+        return v.data.ndim == 1 and jnp.issubdtype(v.data.dtype, jnp.integer)
+
+    return (
+        all(int1d(v) for v in keys)
+        and all(a.func in _RUN_FUNCS for a in aggs)
+        and all(v is None or int1d(v) for v in ins)
+    )
+
+
+def _exclusive_diff(c):
+    """c[g] - c[g-1], with c[-1] = 0."""
+    return c - jnp.concatenate([jnp.zeros((1,), c.dtype), c[:-1]])
+
+
+def _grouped_aggregate_runs(
+    page: Page, live, keys, ins, group_names, aggs, max_groups: int
+) -> Page:
+    """`grouped_aggregate_sorted` with no gather and no scatter: ONE sort
+    by the key columns themselves carries each aggregate's contribution,
+    prefix sums run over the rows in key order, and a second sort moves
+    each run's LAST row to the front, where a group's sum is the
+    difference of two neighbouring prefix sums (exact: int64 wraps as
+    `segment_sum` does, and decimal sums go as 32-bit halves in int64
+    prefix sums, good for 2^31 rows, into the same two lanes)."""
+    from . import decimal128 as d128
+
+    cap = page.capacity
+    idx_t = jnp.int32 if cap < (1 << 30) else jnp.int64
+    # sort keys: dead rows last, then per key (NULL flag, value)
+    operands = [(~live).astype(jnp.int32)]
+    for v in keys:
+        if v.valid is not None:
+            operands.append((~v.valid).astype(jnp.int32))
+            operands.append(jnp.where(v.valid, v.data, jnp.zeros_like(v.data)))
+        else:
+            operands.append(v.data)
+    num_keys = len(operands)
+    # payload: per aggregate its zeroed contribution, and the flag of
+    # what contributes where a NULL input makes that differ from `live`
+    slot_of = {}
+
+    def carry(arr, tag):
+        if tag not in slot_of:
+            slot_of[tag] = len(operands)
+            operands.append(arr)
+        return slot_of[tag]
+
+    plan = []
+    for spec, v in zip(aggs, ins):
+        flag = None
+        if v is not None and v.valid is not None:
+            flag = carry(
+                (live & v.valid).astype(jnp.int32), ("c", id(v.valid))
+            )
+        val = None
+        if spec.func in ("sum", "avg"):
+            contributes = live if v.valid is None else (live & v.valid)
+            val = carry(
+                jnp.where(contributes, v.data, jnp.zeros_like(v.data)),
+                ("x", id(v.data), id(v.valid)),
+            )
+        plan.append((flag, val))
+    srt = jax.lax.sort(tuple(operands), num_keys=num_keys, is_stable=False)
+
+    with jax.named_scope("group.runs"):
+        live_s = srt[0] == 0
+        boundary = jnp.zeros(cap, jnp.bool_).at[0].set(True)
+        for k in srt[1:num_keys]:
+            boundary = boundary | jnp.concatenate(
+                [jnp.ones((1,), jnp.bool_), k[1:] != k[:-1]]
+            )
+        boundary = boundary & live_s
+        num_live_groups = jnp.sum(boundary.astype(jnp.int32))
+        # a run's last row: live, and the next row starts a run or is dead
+        continues = jnp.concatenate(
+            [live_s[1:] & ~boundary[1:], jnp.zeros((1,), jnp.bool_)]
+        )
+        is_last = live_s & ~continues
+        idx = jnp.arange(cap, dtype=idx_t)
+        ends_key = jnp.where(is_last, idx, idx + cap)
+
+    with jax.named_scope("group.prefix"):
+        prefix = {}  # operand slot -> tuple of inclusive prefix sums
+        for flag, val in plan:
+            if flag is not None and flag not in prefix:
+                prefix[flag] = (jnp.cumsum(srt[flag]),)
+        for (flag, val), spec, v in zip(plan, aggs, ins):
+            if val is None or val in prefix:
+                continue
+            x = srt[val]
+            if _wide_for(spec, v):
+                prefix[val] = (
+                    jnp.cumsum(x >> d128.RADIX_BITS),
+                    jnp.cumsum(x & d128.MASK32),
+                )
+            else:
+                prefix[val] = (jnp.cumsum(x),)
+
+    with jax.named_scope("group.ends"):
+        moved = list(srt[1:num_keys])
+        where = {}
+        for slot, arrs in prefix.items():
+            where[slot] = (len(moved), len(arrs))
+            moved.extend(arrs)
+        out = jax.lax.sort(
+            (ends_key, *moved), num_keys=1, is_stable=False
+        )
+
+        def fit(a):
+            # the first `max_groups` rows (max_groups may pass capacity)
+            if max_groups <= cap:
+                return a[:max_groups]
+            return jnp.pad(a, (0, max_groups - cap))
+
+        in_range = jnp.arange(max_groups, dtype=jnp.int32) < num_live_groups
+        ends = fit(out[0]).astype(jnp.int64)
+        # rows of a run that are live: its end position less the last one's
+        live_count = jnp.where(
+            in_range,
+            ends - jnp.concatenate([jnp.full((1,), -1, jnp.int64), ends[:-1]]),
+            0,
+        )
+        gkeys = [fit(a) for a in out[1:num_keys]]
+        sums = {
+            slot: tuple(
+                jnp.where(in_range, _exclusive_diff(fit(out[1 + at + j])), 0)
+                for j in range(n)
+            )
+            for slot, (at, n) in where.items()
+        }
+
+    blocks, names = [], []
+    ki = 0
+    for v, name in zip(keys, group_names):
+        kvalid = None
+        if v.valid is not None:
+            kvalid = gkeys[ki] == 0
+            ki += 1
+        blocks.append(
+            Block(gkeys[ki].astype(v.data.dtype), v.type, kvalid, v.dict_id)
+        )
+        ki += 1
+        names.append(name)
+    for (flag, val), spec, v in zip(plan, aggs, ins):
+        masked_count = (
+            live_count if flag is None else sums[flag][0].astype(jnp.int64)
+        )
+        if spec.func in ("count", "count_star"):
+            blocks.append(_finalize(spec, masked_count, None, None))
+            names.append(spec.name)
+            continue
+        parts = sums[val]
+        if len(parts) == 2:
+            s = jnp.stack(d128.dnorm(parts[0], parts[1]), axis=-1)
+        else:
+            s = parts[0]
+        raw = s if spec.func == "sum" else (s, masked_count)
+        blocks.append(
+            _finalize(spec, raw, masked_count > 0, v.type, v.dict_id)
+        )
+        names.append(spec.name)
+    return Page.from_blocks(blocks, names, count=num_live_groups)
+
+
 def grouped_aggregate_sorted(
     page: Page,
     group_exprs,
@@ -653,13 +836,22 @@ def grouped_aggregate_sorted(
     max_groups: int,
     pre_mask=None,
     max_elems: int = 128,
+    runs: Optional[bool] = None,
 ) -> Page:
     """General grouped aggregation via hash-sort + run detection.
 
     max_groups is the static output capacity (planner-chosen; overflow beyond
-    it is a query error the host checks via the returned count)."""
+    it is a query error the host checks via the returned count). `runs`
+    picks the run-sum form (`_grouped_aggregate_runs`) where the shape is
+    eligible; None = by the page's capacity (RUNS_MIN_ROWS)."""
     live = _masked_live(page, pre_mask)
     keys, ins = _eval_inputs(page, group_exprs, aggs)
+    if runs is None:
+        runs = page.capacity >= RUNS_MIN_ROWS
+    if runs and page.capacity > 1 and _runs_eligible(keys, ins, aggs):
+        return _grouped_aggregate_runs(
+            page, live, keys, ins, group_names, aggs, max_groups
+        )
 
     with jax.named_scope("group.hash_sort"):
         h = hash_rows(keys)

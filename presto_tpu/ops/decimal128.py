@@ -231,8 +231,13 @@ def segment_sum_wide(x_lanes, segment_ids, num_segments):
     canonical < 2^32, so their int64 partial sums cannot overflow)."""
     import jax
 
-    sums = jax.ops.segment_sum(x_lanes, segment_ids, num_segments)
-    hi, lo = dnorm(sums[..., 0], sums[..., 1])
+    # a scatter per lane: one scatter of (rows, 2) updates makes the TPU
+    # compiler hold them as u32[rows, 2] tiled (8, 128), 512 B a row
+    # (30.7 GB for 60M rows: RESOURCE_EXHAUSTED, PR 33)
+    hi, lo = dnorm(
+        jax.ops.segment_sum(x_lanes[..., 0], segment_ids, num_segments),
+        jax.ops.segment_sum(x_lanes[..., 1], segment_ids, num_segments),
+    )
     return jnp.stack([hi, lo], axis=-1)
 
 

@@ -10,6 +10,7 @@ cumsum + scatter, the XLA answer to dynamic row counts under static shapes."""
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -50,6 +51,42 @@ def compact(page: Page, keep: jnp.ndarray) -> Page:
     perm = kept_first_permutation(keep)
     blocks = [b.take_rows(perm) for b in page.blocks]
     return Page(tuple(blocks), page.names, count)
+
+
+# From this many rows up a dynamic filter's mask and compaction take the
+# forms that need no full-capacity gather (`compact_few` here,
+# exec/dynfilter._inlist_mask); under it they stay the programs every
+# smaller page has compiled. The v5e gathers at ~15-30 ns an element, so
+# `compact` of a 60M-row page costs seconds whatever it keeps (PR 33).
+LARGE_PAGE_ROWS = 1 << 23
+
+
+@functools.partial(jax.jit, static_argnames=("cap",))
+def compact_few(page: Page, keep: jnp.ndarray, cap: int) -> Page:
+    """`compact` then a slice to `cap` rows, for a mask known to keep at
+    most `cap` of them: the j-th kept row is where the running count of
+    kept rows first reaches j + 1, found by `cap` binary searches, so
+    only `cap` rows of each column are gathered. Rows past the count
+    repeat the last row (they are dead either way)."""
+    keep = keep & page.live_mask()
+    running = jnp.cumsum(keep.astype(jnp.int32))
+    idx = jnp.searchsorted(
+        running, jnp.arange(1, cap + 1, dtype=jnp.int32), side="left"
+    )
+    idx = jnp.minimum(idx, page.capacity - 1)
+    blocks = [b.take_rows(idx) for b in page.blocks]
+    return Page(tuple(blocks), page.names, running[-1].astype(jnp.int32))
+
+
+def keep_mask(page: Page, predicate):
+    """(live rows the predicate selects, how many): `filter_page`'s mask,
+    for a caller that reads the count before it compacts."""
+    v = evaluate(predicate, page)
+    keep = v.data
+    if v.valid is not None:
+        keep = keep & v.valid  # NULL predicate == not selected
+    keep = keep & page.live_mask()
+    return keep, jnp.sum(keep.astype(jnp.int32)).astype(jnp.int32)
 
 
 def filter_page(page: Page, predicate) -> Page:

@@ -162,6 +162,74 @@ def test_compaction_sort_sf1(one_chip):
     )
 
 
+def test_wide_segment_sum_sf10(one_chip):
+    """A decimal sum per group over SF10's lineitem (Q18's subquery at
+    the sort strategy's first guess of 65,536 groups): as ONE scatter of
+    (rows, 2) lane pairs the compiler held the updates as u32[rows, 2]
+    tiled (8, 128), 30.7 GB, and the statement died with
+    RESOURCE_EXHAUSTED (chip run, PR 33); a scatter per lane fits."""
+    from presto_tpu.ops import decimal128 as d128
+
+    rows, groups = 59_994_841, (1 << 16) + 1
+    compiled = _compile(
+        lambda x, gid: d128.segment_sum_wide(
+            d128.from_int64(x), gid, groups
+        ),
+        _spec((rows,), jnp.int64, one_chip),
+        _spec((rows,), jnp.int32, one_chip),
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+LINEITEM_SF10_FULL = 59_994_841  # TpchCatalog(sf=10)'s lineitem
+
+
+def test_selective_compaction_sf10(one_chip):
+    """What a dynamic filter runs over SF10's lineitem (PR 33): the
+    compare-all IN-list mask and `compact_few`, neither with a
+    full-capacity gather or a 60M-row sort to compile."""
+    from presto_tpu import types as T
+    from presto_tpu.exec.dynfilter import _inlist_mask
+    from presto_tpu.ops.filter import compact_few
+
+    page = _page_specs(
+        [(jnp.int64, T.BIGINT), (jnp.int64, T.DecimalType(12, 2))],
+        LINEITEM_SF10_FULL, one_chip,
+    )
+    keep = _spec((LINEITEM_SF10_FULL,), jnp.bool_, one_chip)
+    c = compact_few.lower(page, keep, cap=1024).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+    c = _inlist_mask.lower(
+        _spec((128,), jnp.int64, one_chip),
+        _spec((LINEITEM_SF10_FULL,), jnp.int64, one_chip),
+    ).compile()
+    assert c.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+@pytest.mark.slow
+def test_run_sum_group_by_sf10(one_chip):
+    """Q18's subquery at SF10 in the run-sum form: two payload-carrying
+    60M-row sorts (150 s to compile in this sandbox), 2.6 GB of
+    temporaries beside the 6.6 GB resident."""
+    from presto_tpu import types as T
+    from presto_tpu.expr.ir import col
+    from presto_tpu.ops.aggregate import AggSpec, grouped_aggregate_sorted
+
+    dec = T.DecimalType(12, 2)
+    page = _page_specs(
+        [(jnp.int64, T.BIGINT), (jnp.int64, dec)], LINEITEM_SF10_FULL, one_chip
+    )
+    compiled = _compile(
+        lambda p: grouped_aggregate_sorted(
+            p, [col("c0", T.BIGINT)], ["k"],
+            [AggSpec("sum", col("c1", dec), "s", T.DecimalType(38, 2))],
+            1 << 24, runs=True,
+        ),
+        page,
+    )
+    assert compiled.memory_analysis().temp_size_in_bytes < 3 << 30
+
+
 def _page_specs(cols, capacity, sharding):
     """A Page of ShapeDtypeStructs (jit lowers pytrees of them)."""
     from presto_tpu.page import Block, Page
