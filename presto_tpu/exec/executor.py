@@ -635,18 +635,22 @@ class Executor:
 
     def _dyn_compact(self, page: Page, keep) -> Tuple[Page, int]:
         """Compact + shrink for dynamic-filter masks, which are typically
-        VERY selective. The generic `compact` (argsort on the drop flag)
-        pays a full-capacity sort and then `_shrink`'s CBO gate — which
-        knows nothing about runtime filters — skips the slice. Here the
-        exact survivor count is known (pruned-row accounting syncs it
-        anyway), so the output is always sliced to the count's bucket; on
-        the CPU backend the whole compaction routes through ONE host
-        `np.flatnonzero` pass + a small gather instead of XLA's
-        comparison sort (the keypack host-sort pattern, ops/keypack.py).
-        Returns (page, survivor count)."""
+        VERY selective, and whose survivors `_shrink`'s CBO gate (it knows
+        nothing about runtime filters) would leave at page capacity. The
+        survivor count is read FIRST (pruned-row accounting needs it
+        anyway; the one read made here), so no column is gathered at page
+        capacity: a gather costs the TPU ~7.5 ns an index whatever it
+        gathers from, a 6M-row sort 8 ms. A mask that keeps a sixteenth of
+        the page or less runs `compact_few` (no sort at all); any other
+        sorts the kept rows first as `compact` does and gathers the
+        count's bucket of that permutation. The node's span says which
+        (`compact`, `compact_capacity`). On the CPU backend the whole
+        compaction routes through ONE host `np.flatnonzero` pass + a small
+        gather instead of XLA's comparison sort (the keypack host-sort
+        pattern, ops/keypack.py). Returns (page, survivor count)."""
         import numpy as np
 
-        from ..ops.filter import LARGE_PAGE_ROWS, compact, compact_few
+        from ..ops.filter import compact_few, kept_first_permutation
 
         keep = keep & page.live_mask()
         if jax.default_backend() == "cpu":
@@ -655,34 +659,28 @@ class Executor:
             cap = round_capacity(max(n, 1))
             idx = np.zeros(cap, np.int64)
             idx[:n] = nz
-            idxd = jnp.asarray(idx)
-            blocks = [b.take_rows(idxd) for b in page.blocks]
-            return (
-                Page(
-                    tuple(blocks), page.names,
-                    jnp.asarray(n, dtype=jnp.int32),
-                ),
-                n,
-            )
-        if page.capacity >= LARGE_PAGE_ROWS:
-            # count first: a mask that keeps a sixteenth or less is
-            # compacted by `cap` binary searches, not a full-capacity sort
-            # and gather (one read, as below)
-            n = int(host_read(jnp.sum(keep.astype(jnp.int32))))
-            cap = round_capacity(max(n, 1))
+            form, perm = "host", jnp.asarray(idx)
+            count = jnp.asarray(n, dtype=jnp.int32)
+        else:
+            # the page leaves with THIS count object: the host's copy
+            # stays on it (`obs.span.held`), so nothing above reads the
+            # count again
+            count = jnp.sum(keep, dtype=jnp.int32)
+            n = int(host_read(count))
+            cap = min(round_capacity(max(n, 1)), page.capacity)
+            # a sixteenth: the searches gather cap * log2(rows) indices
+            # where the sort costs what the page's rows do (on a 6M-row
+            # page the two cross near 1/128: PERF.md section 6, PR 34)
             if cap * 16 <= page.capacity:
-                return compact_few(page, keep, cap=cap), n
-        out = compact(page, keep)
-        n = int(host_read(out.count))
-        cap = round_capacity(max(n, 1))
-        if cap < out.capacity:
-            idx = slice(0, cap)
-            out = Page(
-                tuple(b.take_rows(idx) for b in out.blocks),
-                out.names,
-                out.count,
-            )
-        return out, n
+                form, perm = "few", None
+            else:
+                form, perm = "sort", kept_first_permutation(keep)[:cap]
+        self._span_note(compact=form, compact_capacity=cap)
+        if perm is None:
+            blocks = compact_few(page, keep, cap=cap).blocks
+        else:
+            blocks = tuple(b.take_rows(perm) for b in page.blocks)
+        return Page(blocks, page.names, count), n
 
     def _dyn_mask_page(self, node, page: Page, entries, where: str) -> Page:
         """AND every available dynamic-filter mask over `page` and compact.

@@ -5,8 +5,18 @@ ScanFilterAndProjectOperator (presto-main/.../operator/
 ScanFilterAndProjectOperator.java:55) with codegen'd PageProcessors. On TPU a
 filter has two parts: evaluating the predicate (fused elementwise — see
 expr/compiler.py) and *compaction* — moving surviving rows to the front so the
-page keeps its "live rows in [0, count)" invariant. Compaction is an O(n)
-cumsum + scatter, the XLA answer to dynamic row counts under static shapes."""
+page keeps its "live rows in [0, count)" invariant, the XLA answer to
+dynamic row counts under static shapes. Two programs do it, both keeping
+the survivors in their original order: `compact` (one single-operand sort
+of the row ids, kept rows first, then every column gathered at page
+capacity; a scatter serializes on the TPU) for a caller that does not know
+how many rows survive, and `compact_few` (a running count of kept rows,
+`cap` binary searches over it, `cap` rows of each column gathered; no
+sort) for one that has read the count and found it small. A gather costs
+the v5e ~7.5 ns an index whatever it gathers from and a 6M-row sort 8 ms
+(chip runs, PR 34), so what a compaction costs is what it gathers: a
+caller that holds the count (`Executor._dyn_compact`) never gathers a
+column at page capacity."""
 
 from __future__ import annotations
 
@@ -53,11 +63,15 @@ def compact(page: Page, keep: jnp.ndarray) -> Page:
     return Page(tuple(blocks), page.names, count)
 
 
-# From this many rows up a dynamic filter's mask and compaction take the
-# forms that need no full-capacity gather (`compact_few` here,
-# exec/dynfilter._inlist_mask); under it they stay the programs every
-# smaller page has compiled. The v5e gathers at ~15-30 ns an element, so
-# `compact` of a 60M-row page costs seconds whatever it keeps (PR 33).
+# From this many rows up two call sites take the forms that need no
+# full-capacity gather; under it they stay the programs every smaller page
+# has compiled (PR 33 had to hold the accepted cells' programs still). The
+# two left: the plain `Filter` (`Executor._exec_filter`: `compact_few`
+# behind a count read first, where `_shrink` would have read it) and a
+# small IN-list's compare-all mask (exec/dynfilter._inlist_mask). Neither
+# choice depends on a page's size; each gate goes with a measurement of
+# its own on the chip, as the dynamic filter's compaction did in PR 34
+# (`Executor._dyn_compact` picks by the kept share alone, at any size).
 LARGE_PAGE_ROWS = 1 << 23
 
 

@@ -35,6 +35,36 @@ def rng():
     return np.random.default_rng(42)
 
 
+class _JaxAs:
+    """`jax` for ONE module, answering `default_backend()` with a name
+    of the test's and everything else as `jax` does."""
+
+    def __init__(self, backend):
+        self._backend = backend
+
+    def default_backend(self):
+        return self._backend
+
+    def __getattr__(self, name):
+        return getattr(jax, name)
+
+
+@pytest.fixture()
+def device_branch(monkeypatch):
+    """`Executor._dyn_compact` takes `np.flatnonzero` on the CPU backend;
+    here the executor module (and no other) sees an accelerator, so its
+    device branch runs. Returns the list its host reads are added to."""
+    from presto_tpu.exec import executor
+
+    reads = []
+    real = executor.host_read
+    monkeypatch.setattr(executor, "jax", _JaxAs("accelerator"))
+    monkeypatch.setattr(
+        executor, "host_read", lambda x: reads.append(1) or real(x)
+    )
+    return reads
+
+
 # -- memory/spill accounting guard (every test) ------------------------------
 #
 # After EVERY test: no spill file may be left on disk and no spill bytes

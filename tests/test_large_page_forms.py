@@ -6,6 +6,11 @@ orders; PR 33), each against the form it replaces there, at small sizes:
   gather, no scatter): the same groups, sums, counts and NULLs, row for row;
 - `compact_few` (ops/filter.py): `compact` for a mask that keeps few rows;
 - `_inlist_mask` (exec/dynfilter.py): IN-list membership by comparison.
+
+`compact_few` is no longer a large page's alone (PR 34): behind a
+dynamic filter's mask `Executor._dyn_compact` takes it at any capacity
+once the survivors fit a sixteenth of the page; its device branch is run
+here on the CPU backend.
 """
 
 import jax
@@ -153,12 +158,10 @@ def test_size_gate():
 
 # -- compact_few and the compare-all IN-list mask --
 
-@pytest.mark.parametrize("kept,cap", [(0, 16), (1, 16), (40, 64), (64, 64)])
-def test_compact_few_equals_compact(kept, cap):
-    from presto_tpu.ops.filter import compact, compact_few
-
-    rng = np.random.default_rng(kept)
-    n, capacity = 5000, 8192
+def _masked_page(n, capacity, kept):
+    """A page of `n` live rows in `capacity` slots and a mask that keeps
+    `kept` of them, and says True for every dead slot."""
+    rng = np.random.default_rng(capacity + kept)
     page = Page.from_dict(
         {
             "a": rng.integers(0, 1000, n).astype(np.int64),
@@ -169,10 +172,66 @@ def test_compact_few_equals_compact(kept, cap):
     keep = np.zeros(capacity, np.bool_)
     keep[rng.choice(n, kept, replace=False)] = True
     keep[n:] = True  # dead rows stay dead whatever the mask says
-    want = compact(page, jax.numpy.asarray(keep))
-    got = compact_few(page, jax.numpy.asarray(keep), cap=cap)
+    return page, jax.numpy.asarray(keep)
+
+
+@pytest.mark.parametrize(
+    "kept,cap,n,capacity",
+    [
+        (0, 16, 5000, 8192), (1, 16, 5000, 8192), (40, 64, 5000, 8192),
+        (64, 64, 5000, 8192),
+        (51_000, 65_536, 1_000_000, 1 << 20),  # Q3's bucket
+    ],
+)
+def test_compact_few_equals_compact(kept, cap, n, capacity):
+    from presto_tpu.ops.filter import compact, compact_few
+
+    page, keep = _masked_page(n, capacity, kept)
+    want = compact(page, keep)
+    got = compact_few(page, keep, cap=cap)
     assert got.capacity == cap and int(got.count) == int(want.count) == kept
     assert got.to_pylist() == want.to_pylist()
+
+
+# kept rows at the edges of both rules: a bucket's (`round_capacity`)
+# and the sixteenth's (1,024 of 16,384 slots; 375 of 6,000)
+DYN_COMPACT_CASES = [
+    (1 << 14, kept) for kept in (0, 1, 1023, 1024, 1025, 1 << 13)
+] + [(6000, kept) for kept in (0, 1, 256, 257, 374, 375, 376, 3000)]
+
+
+@pytest.mark.parametrize("capacity,kept", DYN_COMPACT_CASES)
+def test_dyn_compact_counts_first_at_any_capacity(
+    capacity, kept, device_branch, monkeypatch
+):
+    """Under 2^23 rows too: one read (the count), `compact_few` exactly
+    when the count's bucket fits a sixteenth of the page, `compact`'s
+    sort and the bucket's rows of its permutation gathered otherwise;
+    `compact`'s rows in `compact`'s order either way."""
+    from presto_tpu.exec.executor import Executor
+    from presto_tpu.ops import filter as filter_ops
+    from presto_tpu.page import round_capacity
+
+    page, keep = _masked_page(capacity - 100, capacity, kept)
+    want = filter_ops.compact(page, keep)
+    taken = []
+    for name in ("kept_first_permutation", "compact_few"):
+        real = getattr(filter_ops, name)
+        monkeypatch.setattr(
+            filter_ops, name,
+            lambda *a, _real=real, _name=name, **k: (
+                taken.append(_name) or _real(*a, **k)
+            ),
+        )
+    out, count = Executor(None)._dyn_compact(page, keep)
+    cap = round_capacity(max(kept, 1))
+    assert count == int(out.count) == kept
+    assert out.capacity == cap < capacity
+    assert out.to_pylist() == want.to_pylist()
+    assert taken == [
+        "compact_few" if cap * 16 <= capacity else "kept_first_permutation"
+    ]
+    assert len(device_branch) == 1
 
 
 @pytest.mark.parametrize("k", [1, 7, 8, 111, 256])

@@ -160,6 +160,51 @@ def test_one_altered_digit_is_not_correct(answers, case):
     assert checks["mismatched_cells"]["value"] == 1
 
 
+def filter_spans(trace):
+    """{"df0" | "df1": attrs} of the `Filter` spans that applied Q3's
+    runtime filters: `df0` prunes orders, `df1` lineitem."""
+    return {
+        s.attrs["dyn_strategy"].split(":")[0]: s.attrs
+        for s in trace.spans()
+        if s.name == "Filter" and "dyn_strategy" in s.attrs
+    }
+
+
+@pytest.mark.parametrize("case", ["q3_full-BUILDING", "q3_full-MACHINERY"])
+def test_q3s_dynamic_filters_say_which_compaction_ran(served, case, request):
+    """Q3's lineitem `Filter` keeps under a hundredth of its page behind
+    the join's runtime filter and gathers the survivors' capacity
+    (`compact: few`); orders' keeps a tenth and runs `compact` (`sort`).
+    Served once as tier-1 compacts (on the host), once with the
+    executor's device branch forced: the same rows and `dyn_pruned`."""
+    qid, params = STATEMENTS[case]
+    with open(os.path.join(BENCH, "sql", qid + ".sql")) as f:
+        sql = f.read().format(**params)
+    runs = []
+    for fixture in (None, "device_branch"):
+        if fixture:
+            request.getfixturevalue(fixture)
+        TRACES.reset()
+        cols, rows = served.execute(sql)
+        (trace,) = TRACES.recent()
+        runs.append((compare.canonical(cols, rows), filter_spans(trace)))
+    (want, on_host), (got, on_device) = runs
+    assert got == want and len(got) == 10
+    assert {f["compact"] for f in on_host.values()} == {"host"}
+    orders, lineitem = on_device["df0"], on_device["df1"]
+    n_lineitem = tpch.table("lineitem", SF).num_rows
+    assert lineitem["compact"] == "few"
+    assert lineitem["compact_capacity"] * 16 <= n_lineitem
+    assert 0 < lineitem["dyn_pruned"] == on_host["df1"]["dyn_pruned"]
+    assert orders["compact"] == "sort"
+    assert orders["compact_capacity"] * 16 > tpch.table("orders", SF).num_rows
+    assert 0 < orders["dyn_pruned"] == on_host["df0"]["dyn_pruned"]
+    for f in ("df0", "df1"):
+        assert (
+            on_device[f]["compact_capacity"] == on_host[f]["compact_capacity"]
+        )
+
+
 # -- (c) the load's spans and the metric that reads them --
 
 def loads_of(trace):

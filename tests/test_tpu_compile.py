@@ -206,6 +206,32 @@ def test_selective_compaction_sf10(one_chip):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+LINEITEM_SF1_FULL = 6_000_859  # TpchCatalog(sf=1)'s lineitem, as stored
+
+
+def test_selective_compaction_q3_sf1(one_chip):
+    """What Q3's lineitem `Filter` runs behind its runtime filter since
+    PR 34: `compact_few` over the four columns it carries, ~51,000 rows
+    kept (the 65,536 bucket), in place of a 6M-row sort and four 6M-row
+    gathers. No `lax.sort` in it (one at this size took 15-50 s on the
+    chip machine): 8-9 s to compile in this sandbox (PR 34)."""
+    from presto_tpu import types as T
+    from presto_tpu.ops.filter import compact_few
+
+    dec = T.DecimalType(12, 2)
+    page = _page_specs(
+        [
+            (jnp.int64, T.BIGINT), (jnp.int64, dec), (jnp.int64, dec),
+            (jnp.int32, T.DATE),
+        ],
+        LINEITEM_SF1_FULL, one_chip,
+    )
+    keep = _spec((LINEITEM_SF1_FULL,), jnp.bool_, one_chip)
+    c = compact_few.lower(page, keep, cap=1 << 16).compile()
+    assert " sort(" not in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 @pytest.mark.slow
 def test_run_sum_group_by_sf10(one_chip):
     """Q18's subquery at SF10 in the run-sum form: two payload-carrying
