@@ -954,10 +954,6 @@ class Executor:
                 lambda: lambda p: global_aggregate(p, node.aggs, node.mask),
             )
             return fn(page)
-        if self.pallas_groupby is None:
-            import jax
-
-            self.pallas_groupby = jax.default_backend() == "tpu"
         # the slots this node's groups needed the last time it ran the
         # sort strategy: past the hash-slot cap that attempt would read
         # the keys and inputs to the host (1.5 GB for Q18's subquery at
@@ -966,7 +962,7 @@ class Executor:
 
         learned = self._agg_groups.get(node, count=False)
         small = learned is None or learned <= HASH_MAX_GROUPS_HOST
-        if self.pallas_groupby and small:
+        if small and self._pallas_groupby_on():
             out = self._try_pallas_groupby(node, page)
             if out is not None:
                 return out
@@ -1054,6 +1050,15 @@ class Executor:
             # hash-slot cap, so the next execution skips that attempt
             self._agg_groups.put(node, max_groups)
         return self._shrink(out, node)
+
+    def _pallas_groupby_on(self) -> bool:
+        """The `pallas_groupby` knob, resolved at first use (None = auto:
+        on for the TPU backend)."""
+        if self.pallas_groupby is None:
+            import jax
+
+            self.pallas_groupby = jax.default_backend() == "tpu"
+        return self.pallas_groupby
 
     def _try_pallas_groupby(self, node: N.Aggregate, page: Page) -> Optional[Page]:
         """Dense small-G group-by (ops/pallas_groupby.py) as ONE program

@@ -28,6 +28,7 @@ need whole inputs (windows, full-outer composition, sorts beyond budget).
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax.numpy as jnp
@@ -44,6 +45,8 @@ from ..ops.aggregate import (
 from ..ops.filter import filter_page
 from ..ops.join import build_sorted, join_expand, join_n1
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
+from ..obs import span as obs_span
+from ..obs.span import host_read
 from ..ops.union import concat_pages
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
@@ -69,7 +72,7 @@ def coalesce_pages(
     held: List[Page] = []
     held_rows = 0
     for page in pages:
-        n = int(page.count)
+        n = int(host_read(page.count))
         if n >= target_rows and not held:
             yield page
             continue
@@ -136,11 +139,11 @@ class HostTable:
             vparts = []
             any_valid = any(b.valid is not None for b in blocks)
             for p, b in zip(pages, blocks):
-                n = int(p.count)
-                parts.append(np.asarray(b.data[:n]))
+                n = int(host_read(p.count))
+                parts.append(host_read(b.data[:n]))
                 if any_valid:
                     vparts.append(
-                        np.asarray(b.valid[:n])
+                        host_read(b.valid[:n])
                         if b.valid is not None
                         else np.ones((n,), np.bool_)
                     )
@@ -301,6 +304,25 @@ def _pushdown_hints(predicate, scan_node: N.TableScan):
     return hints or None
 
 
+# nodes `_run` executes whole, through a budget-aware sink
+_SINKS = (N.Aggregate, N.Distinct, N.TopN, N.Limit, N.Sort)
+_END = object()  # a stream has no batch left
+
+
+def _plan_positions(root: N.PlanNode) -> Dict[int, str]:
+    """{id(node): position} over a plan: `0` the root, `0.1.0` child
+    indices from it, as `Executor._run` numbers its spans."""
+    positions: Dict[int, str] = {}
+    todo = [(root, "0")]
+    while todo:
+        node, pos = todo.pop()
+        positions.setdefault(id(node), pos)
+        todo.extend(
+            (c, f"{pos}.{i}") for i, c in enumerate(node.children)
+        )
+    return positions
+
+
 class StreamingExecutor:
     """Host driver loop over device page batches (reference Driver +
     TaskExecutor collapsed: one Python loop, kernels stay on device)."""
@@ -353,6 +375,7 @@ class StreamingExecutor:
         }
         self._spill_space = spill_space
         self._owns_spill = spill_space is None
+        self._pos: Dict[int, str] = {}  # span positions of the running plan
 
     def _spill(self):
         """Lazily opened spill space (exec/spillspace.py): disk-tier
@@ -385,7 +408,7 @@ class StreamingExecutor:
         for b in self.stream(child):
             if first is None:
                 first = b  # schema carrier for the all-empty case
-            if int(b.count) == 0:
+            if int(host_read(b.count)) == 0:
                 continue
             nb = page_device_bytes(b)
             if spilled is None and self.pool.can_accumulate(held + nb):
@@ -410,6 +433,9 @@ class StreamingExecutor:
 
     def run(self, node: N.PlanNode) -> Page:
         self.dyn_ctx.reset()  # filters are per-query state
+        self._pos = (
+            _plan_positions(node) if obs_span.current() is not None else {}
+        )
         try:
             return self._run(node)
         finally:
@@ -432,6 +458,28 @@ class StreamingExecutor:
     # -- top-level dispatch: sinks consume streams --
 
     def _run(self, node: N.PlanNode) -> Page:
+        """One sink node, whole. Where a trace is open on this thread
+        (obs/span.py) it runs inside a span named by its class with its
+        position in the plan, as `Executor._run` opens one: the sink's
+        self time is its span minus its children's."""
+        cur = obs_span.current()
+        if cur is None:
+            return self._run_sink(node)
+        span = cur[0].enter(type(node).__name__, pos=self._node_pos(node))
+        try:
+            out = self._run_sink(node)
+        except BaseException:
+            cur[0].leave(span, "error")
+            raise
+        cur[0].leave(span)
+        return out
+
+    def _node_pos(self, node: N.PlanNode) -> str:
+        """`0.1.0`: child indices from the plan's root (`run` maps the
+        plan it was given; `?` for a node that is not of it)."""
+        return self._pos.get(id(node), "?")
+
+    def _run_sink(self, node: N.PlanNode) -> Page:
         if isinstance(node, N.Output):
             return self.local.exec_node(node, self._run(node.child))
         if isinstance(node, N.Aggregate):
@@ -453,7 +501,7 @@ class StreamingExecutor:
         for p in self.stream(node):
             if first is None:
                 first = p  # schema carrier for the all-empty case
-            if int(p.count) > 0:
+            if int(host_read(p.count)) > 0:
                 pages.append(p)
         if not pages:
             return first
@@ -464,6 +512,42 @@ class StreamingExecutor:
     # -- streaming core: generator of batches per node -----------------------
 
     def stream(self, node: N.PlanNode) -> Iterator[Page]:
+        """A node's batches; a sink reached mid-tree has `_run`'s span,
+        every other node `_spanned`'s."""
+        if isinstance(node, _SINKS):
+            return self._stream_node(node)
+        return self._spanned(node, self._stream_node(node))
+
+    def _spanned(
+        self, node: N.PlanNode, batches: Iterator[Page]
+    ) -> Iterator[Page]:
+        """`batches`, the stream of `node`, under ONE span for all of
+        them where a trace is open on this thread (`obs.span.Pulled`:
+        the thread's innermost while the node's own code runs, closed
+        at the sum of those pieces), not one a batch."""
+        pulled = obs_span.Pulled.open(
+            type(node).__name__, pos=self._node_pos(node)
+        )
+        if pulled is None:
+            yield from batches
+            return
+        status = "error"
+        try:
+            while True:
+                with pulled:
+                    batch = next(batches, _END)
+                if batch is _END:
+                    break
+                yield batch
+            status = "ok"
+        except GeneratorExit:  # the consumer stopped pulling (LIMIT)
+            status = "ok"
+            raise
+        finally:
+            batches.close()
+            pulled.close(status)
+
+    def _stream_node(self, node: N.PlanNode) -> Iterator[Page]:
         if isinstance(node, N.TableScan):
             yield from self._stream_scan(node)
         elif isinstance(node, N.Filter) and isinstance(node.child, N.TableScan):
@@ -471,7 +555,9 @@ class StreamingExecutor:
             # partitions at the connector (reference TupleDomain pushdown);
             # the real filter kernel still runs on every delivered batch
             hints = _pushdown_hints(node.predicate, node.child)
-            for batch in self._stream_scan(node.child, predicate=hints):
+            for batch in self._spanned(
+                node.child, self._stream_scan(node.child, predicate=hints)
+            ):
                 yield self.local.exec_node(node, batch)
         elif isinstance(node, (N.Filter, N.Project, N.Unnest, N.Sample)):
             # all row-local and stateless: apply per batch (Unnest expands
@@ -497,7 +583,7 @@ class StreamingExecutor:
                     yield Page(batch.blocks, first_names, batch.count)
         elif isinstance(node, N.Window) and node.partition_exprs:
             yield from self._stream_window(node)
-        elif isinstance(node, (N.Aggregate, N.Distinct, N.TopN, N.Limit, N.Sort)):
+        elif isinstance(node, _SINKS):
             # sink nodes reached mid-tree (e.g. Sort under the Project that
             # drops a hidden order channel) still go through their
             # budget-aware sinks, not the materializing fallback
@@ -569,12 +655,14 @@ class StreamingExecutor:
             # drop the (optional) hint rather than risk dropped rows
             predicate = None
         start = 0
-        read_total = skipped_total = 0
+        read_total = skipped_total = rows_total = 0
         while True:
+            t0 = time.perf_counter()
             src = scan(
                 node.table, start, start + B, pad_to=B,
                 columns=cols, predicate=predicate,
             )
+            scan_s = time.perf_counter() - t0
             # connector pruning counters are per scan CALL; take the max
             # across batches — exact for partition pruning (every call sees
             # the full file set) and a per-batch high-water for stripe
@@ -587,7 +675,20 @@ class StreamingExecutor:
                 read_total,
                 getattr(self.catalog, "last_scan_files_read", 0) or 0,
             )
-            n = int(src.count)
+            n = int(host_read(src.count))
+            # the scan's per-batch work, folded into the node's ONE span
+            # (docs/observability.md): `scan_s` is the wall inside the
+            # connector (slice, pad, hand the columns to the runtime);
+            # `upload_bytes` the live rows' share of the padded page
+            # (stored widths: dictionary codes, a mask a byte a row)
+            rows_total += n
+            obs_span.count(
+                batches=1, scan_s=scan_s,
+                upload_bytes=(
+                    page_device_bytes(src) // max(src.capacity, 1) * n
+                ),
+            )
+            self.local._span_note(rows=rows_total)
             if n > 0 or start == 0:
                 yield self._scan_out(node, self._rename_scan(node, src))
             start += B
@@ -628,7 +729,7 @@ class StreamingExecutor:
         for b in self.stream(node):
             if first is None:
                 first = b
-            if int(b.count) == 0:
+            if int(host_read(b.count)) == 0:
                 continue
             nb = page_device_bytes(b)
             if spilled is None and self.pool.can_accumulate(nb + held):
@@ -736,7 +837,7 @@ class StreamingExecutor:
             build_batches = [
                 p
                 for p in self._stream_side_bucket(rscan, rwrap, b)
-                if int(p.count) > 0
+                if int(host_read(p.count)) > 0
             ]
             if not build_batches:
                 continue  # inner join: an empty build bucket matches nothing
@@ -806,10 +907,10 @@ class StreamingExecutor:
         cols = [col for _, col, _ in scan.columns]
         for batch in self.stream(node.left):
             blk = batch.block(probe_ch)
-            m = int(batch.count)
-            keys = np.asarray(blk.data[:m])
+            m = int(host_read(batch.count))
+            keys = host_read(blk.data[:m])
             if blk.valid is not None:
-                keys = keys[np.asarray(blk.valid[:m])]
+                keys = keys[host_read(blk.valid[:m])]
             keys = np.unique(keys)
             rows = self.catalog.index_lookup(
                 scan.table, index_col, keys.tolist(), cols
@@ -1124,7 +1225,7 @@ class StreamingExecutor:
                 live = batch.live_mask()
                 if bs_mem is not None:
                     mem_batch = compact(batch, res_lut[part] & live)
-                    if int(mem_batch.count) > 0:
+                    if int(host_read(mem_batch.count)) > 0:
                         for out in self._probe_with(
                             node, bs_mem, right_names, iter([mem_batch])
                         ):
@@ -1132,7 +1233,7 @@ class StreamingExecutor:
                             yield out
                 if probe_spill is not None:
                     d_batch = compact(batch, (~res_lut[part]) & live)
-                    if int(d_batch.count) > 0:
+                    if int(host_read(d_batch.count)) > 0:
                         probe_spill.append(to_host_page(d_batch))
         finally:
             if mem_held:
@@ -1337,7 +1438,7 @@ class StreamingExecutor:
                     kind=node.kind,
                 )
             else:
-                cap = round_capacity(max(int(batch.count), 1))
+                cap = round_capacity(max(int(host_read(batch.count)), 1))
                 while True:
                     out, overflow = join_expand(
                         batch,
@@ -1348,9 +1449,9 @@ class StreamingExecutor:
                         out_capacity=cap,
                         kind=node.kind,
                     )
-                    if int(overflow) == 0:
+                    if int(host_read(overflow)) == 0:
                         break
-                    cap = round_capacity(cap + int(overflow))
+                    cap = round_capacity(cap + int(host_read(overflow)))
             if node.residual is not None:
                 out = filter_page(out, node.residual)
             yield self.local._shrink(out)
@@ -1449,13 +1550,29 @@ class StreamingExecutor:
             self.spill_stats["agg_hash_batches"] += 1
         return out
 
+    def _pallas_agg_attempt(
+        self, partial_node: N.Aggregate, batch: Page
+    ) -> Optional[Page]:
+        """A batch's partial aggregation as ONE program: the dense
+        small-G Pallas group-by, the resident path's first strategy
+        (`Executor._try_pallas_groupby`: behind the pallas_groupby
+        breaker, default on for the TPU, the mask's literals operands of
+        the program). None where it is off or the shape is ineligible;
+        the hash-slot attempt and the sort composition follow. Without
+        it a streamed Q1 at SF10 read 73 MB of every 2^20-row batch back
+        to the host for the hash-slot path and took 24-27 s (PERF.md
+        section 6, PR 35)."""
+        if not self.local._pallas_groupby_on():
+            return None
+        return self.local._try_pallas_groupby(partial_node, batch)
+
     def _agg_input_stream(self, node: N.Aggregate) -> Iterator[Page]:
         """Child batches for a (possibly filter-fused) aggregation; a fused
         mask over a direct table scan still pushes pruning hints down."""
         if node.mask is not None and isinstance(node.child, N.TableScan):
-            return self._stream_scan(
+            return self._spanned(node.child, self._stream_scan(
                 node.child, predicate=_pushdown_hints(node.mask, node.child)
-            )
+            ))
         return self.stream(node.child)
 
     def _sink_aggregate(self, node: N.Aggregate) -> Page:
@@ -1471,6 +1588,10 @@ class StreamingExecutor:
                 partials.append(global_aggregate(batch, partial, node.mask))
             acc = concat_pages(partials)
             out = global_aggregate(acc, final)
+            self.local._span_note(
+                partial_strategy="global", merges=1,
+                pool_peak_bytes=self.pool.peak,
+            )
             return apply_avg_post(out, node.aggs, post)
 
         group_refs = tuple(
@@ -1483,8 +1604,16 @@ class StreamingExecutor:
         pending: List[Page] = []
         pending_rows = 0
         spilled = None  # SpilledRows of partial-state pages
+        # the node as the per-batch partial aggregation runs it
+        partial_node = dataclasses.replace(node, aggs=tuple(partial))
+        # what the sink did, for its span: host-held values only
+        hash_before = self.spill_stats["agg_hash_batches"]
+        strategies = set()
+        merges = 0
 
         def merge(parts: List[Page], bound: int) -> Page:
+            nonlocal merges
+            merges += 1
             acc = parts[0] if len(parts) == 1 else concat_pages(parts)
             out = self._hash_agg_attempt(
                 acc, group_refs, node.group_names, final, None
@@ -1496,7 +1625,7 @@ class StreamingExecutor:
                 out = grouped_aggregate_sorted(
                     acc, group_refs, node.group_names, final, mg
                 )
-                true_groups = int(out.count)
+                true_groups = int(host_read(out.count))
                 if true_groups <= mg:
                     break
                 mg = round_capacity(true_groups)
@@ -1513,7 +1642,7 @@ class StreamingExecutor:
                 self.spill_events.append("aggregate")
                 spilled = SpilledRows(space=self._spill(), tag="aggregate")
             for p in pages:
-                if int(p.count) > 0 or spilled.num_rows == 0:
+                if int(host_read(p.count)) > 0 or spilled.num_rows == 0:
                     spilled.append(p)
 
         # state_held rotates through the loop; the finally releases
@@ -1525,35 +1654,40 @@ class StreamingExecutor:
         # no-op for them.
         try:
             for batch in self._agg_input_stream(node):
-                part = self._hash_agg_attempt(
-                    batch, node.group_exprs, node.group_names, partial,
-                    node.mask,
-                )
+                part = self._pallas_agg_attempt(partial_node, batch)
+                if part is not None:
+                    strategies.add("pallas")
+                else:
+                    part = self._hash_agg_attempt(
+                        batch, node.group_exprs, node.group_names, partial,
+                        node.mask,
+                    )
+                    strategies.add("sort" if part is None else "hash")
                 if part is None:
                     mg = round_capacity(
-                        min(max(int(batch.count), 1), 1 << 16)
+                        min(max(int(host_read(batch.count)), 1), 1 << 16)
                     )
                     while True:
                         part = grouped_aggregate_sorted(
                             batch, node.group_exprs, node.group_names,
                             partial, mg, node.mask,
                         )
-                        if int(part.count) <= mg:
+                        if int(host_read(part.count)) <= mg:
                             break
-                        mg = round_capacity(int(part.count))
+                        mg = round_capacity(int(host_read(part.count)))
                 part = self.local._shrink(part)
                 if spilled is not None:
                     spill_all([part])
                     continue
                 pending.append(part)
-                pending_rows += int(part.count)
+                pending_rows += int(host_read(part.count))
                 pending_bytes = sum(page_device_bytes(p) for p in pending)
                 self.pool.accumulated = pending_bytes
                 if pending_rows >= merge_rows or not self.pool.can_accumulate(
                     pending_bytes
                 ):
                     parts = ([state] if state is not None else []) + pending
-                    new_state = merge(parts, pending_rows + int(state.count if state is not None else 0))
+                    new_state = merge(parts, pending_rows + (int(host_read(state.count)) if state is not None else 0))
                     self.pool.free(state_held)
                     state_held = 0
                     nb = page_device_bytes(new_state)
@@ -1588,11 +1722,19 @@ class StreamingExecutor:
                 return self._finalize_spilled_agg(
                     node, spilled, group_refs, final, post
                 )
-            out = merge(parts, pending_rows + int(state.count if state is not None else 0))
+            out = merge(parts, pending_rows + (int(host_read(state.count)) if state is not None else 0))
             self.pool.free(state_held)
             state_held = 0
             return apply_avg_post(out, node.aggs, post)
         finally:
+            self.local._span_note(
+                partial_strategy="+".join(sorted(strategies)),
+                agg_hash_batches=(
+                    self.spill_stats["agg_hash_batches"] - hash_before
+                ),
+                merges=merges, spilled=spilled is not None,
+                pool_peak_bytes=self.pool.peak,
+            )
             if state_held:
                 self.pool.free(state_held)
             # pending partials are dropped with the exception — without
@@ -1633,14 +1775,14 @@ class StreamingExecutor:
             nb = page_device_bytes(page)
             self.pool.reserve(nb, "final aggregation partition")
             try:
-                mg = round_capacity(max(int(page.count), 1))
+                mg = round_capacity(max(int(host_read(page.count)), 1))
                 while True:
                     out = grouped_aggregate_sorted(
                         page, group_refs, node.group_names, final, mg
                     )
-                    if int(out.count) <= mg:
+                    if int(host_read(out.count)) <= mg:
                         break
-                    mg = round_capacity(int(out.count))
+                    mg = round_capacity(int(host_read(out.count)))
                 out = apply_avg_post(out, node.aggs, post)
                 outs.append(to_host_page(out))
             finally:
@@ -1676,7 +1818,7 @@ class StreamingExecutor:
         rows = 0
         for batch in self.stream(node.child):
             got.append(batch)
-            rows += int(batch.count)
+            rows += int(host_read(batch.count))
             if rows >= node.count:
                 break  # short-circuit: stop pulling the scan
         if not got:

@@ -414,6 +414,68 @@ def child(name: str, wall_as: Optional[str] = None, **attrs):
         cur[0].leave(span, status)
 
 
+def count(**amounts) -> None:
+    """Add to counters of the calling thread's innermost open span
+    (folded upward when it closes, as `host_reads` is): for values the
+    host holds already, never a read. Nothing on a thread with no open
+    span."""
+    cur = getattr(_OPEN, "cur", None)
+    if cur is not None:
+        counters = cur[1].counters
+        for name, amount in amounts.items():
+            counters[name] = counters.get(name, 0) + amount
+
+
+class Pulled:
+    """A span for a body that runs in pieces on one thread: a generator
+    that yields batches to a consumer between them (exec/stream.py's
+    per-node streams). `with pulled:` around each piece makes the span
+    the thread's innermost for as long, so what the piece books
+    (`host_read`, `count`, compiles) and the spans it opens land under
+    it, and the consumer's work between pieces does not. `close` ends
+    the span at its start plus the seconds spent INSIDE the pieces: its
+    wall is the body's own time, children's included, as an `enter`ed
+    span's is, so `Trace.exclusive_walls` still gives every span its
+    self time; only its right edge on the timeline is not where the last
+    piece ended. It holds no profiler annotation (one a piece would be
+    one a batch). `open` returns None on a thread with no open span."""
+
+    __slots__ = ("trace", "span", "parent", "inside_s", "_outer", "_t0")
+
+    @classmethod
+    def open(cls, name: str, **attrs) -> Optional["Pulled"]:
+        cur = getattr(_OPEN, "cur", None)
+        if cur is None:
+            return None
+        self = cls()
+        self.trace, self.parent = cur
+        self.span = self.trace.begin(name, parent=self.parent, **attrs)
+        self.inside_s = 0.0
+        return self
+
+    def __enter__(self):
+        self._outer = getattr(_OPEN, "cur", None)
+        _OPEN.cur = (self.trace, self.span)
+        self._t0 = time.perf_counter()
+        return self.span
+
+    def __exit__(self, *_exc):
+        self.inside_s += time.perf_counter() - self._t0
+        _OPEN.cur = self._outer
+        return False
+
+    def close(self, status: str = "ok") -> Span:
+        span = self.span
+        span.end = span.start + self.inside_s
+        self.trace.finish(span, status)
+        if span.counters:  # folded upward, as `Trace.leave` folds
+            with self.trace._lock:
+                into = self.parent.counters
+                for k, v in span.counters.items():
+                    into[k] = into.get(k, 0) + v
+        return span
+
+
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _listening = False
 _listening_lock = threading.Lock()

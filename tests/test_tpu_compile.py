@@ -138,6 +138,40 @@ def test_fused_q1_groupby_sf1(one_chip, as_tpu):
     assert c.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
+def test_streamed_q1_partial_groupby_batch(one_chip, as_tpu):
+    """The streaming sink's per-batch partial aggregation of Q1
+    (`StreamingExecutor._pallas_agg_attempt`, the cell `sf10s.scan_agg`):
+    `decompose_partial`'s sums and counts over one 2**20-row batch as
+    ONE program, DELTA an operand."""
+    from presto_tpu.benchmark.handcoded import (
+        Q1_GROUP_NAMES,
+        Q1_GROUPS,
+        Q1_PREDICATE,
+        lineitem_q1_page,
+        q1_aggs,
+    )
+    from presto_tpu.exec.qcache import lift_literals, rebind_plan
+    from presto_tpu.ops.aggregate import decompose_partial
+    from presto_tpu.ops.pallas_groupby import maybe_grouped_aggregate
+
+    partial, _final, _post = decompose_partial(q1_aggs())
+    mask, operands = lift_literals(Q1_PREDICATE)
+
+    def fn(page, ops):
+        return maybe_grouped_aggregate(
+            page, Q1_GROUPS, Q1_GROUP_NAMES, tuple(partial),
+            rebind_plan(mask, ops),
+        )
+
+    page = jax.tree_util.tree_map(
+        lambda x: _spec((1 << 20,) * x.ndim, x.dtype, one_chip),
+        lineitem_q1_page(0.001),
+    )
+    c = _compile(fn, page, (_spec((), operands[0].dtype, one_chip),))
+    assert "tpu_custom_call" in c.as_text()
+    assert c.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 def test_matmul_agg_g4096_sf1(one_chip):
     from presto_tpu.ops.matmul_agg import grouped_matmul_partials
 
