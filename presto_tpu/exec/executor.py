@@ -40,6 +40,11 @@ from ..obs.span import held, host_read
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
 
+# a page of at most this many slots is never read back for its count: a
+# blocking read cannot pay there (`_shrink_reads`; the streaming sink's
+# partial pages, exec/stream.py)
+SMALL_PAGE_ROWS = 1 << 14
+
 
 class ExecutionError(RuntimeError):
     pass
@@ -343,7 +348,7 @@ class Executor:
         """Whether `_shrink` pays its sync for this page (see there)."""
         if not self.shrink:
             return False
-        if page.capacity <= (1 << 14):
+        if page.capacity <= SMALL_PAGE_ROWS:
             return False  # too small for shrinking to pay for a sync
         if node is not None:
             est = self._est_rows(node)

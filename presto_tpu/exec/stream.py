@@ -50,7 +50,7 @@ from ..obs.span import host_read
 from ..ops.union import concat_pages
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
-from .executor import ExecutionError, Executor
+from .executor import SMALL_PAGE_ROWS, ExecutionError, Executor
 from .memory import MemoryExceededError, MemoryPool
 from .stats import page_device_bytes
 
@@ -1582,17 +1582,37 @@ class StreamingExecutor:
             # non-decomposable (min_by/max_by): aggregate the materialized
             # input in one pass (same choice the fragmenter makes)
             return self._exec_fallback(node)
+        # the node as the per-batch partial aggregation runs it
+        partial_node = dataclasses.replace(node, aggs=tuple(partial))
+        final_aggs, posts = tuple(final), tuple(post)
         if not node.group_exprs:
-            partials: List[Page] = []
-            for batch in self._agg_input_stream(node):
-                partials.append(global_aggregate(batch, partial, node.mask))
-            acc = concat_pages(partials)
-            out = global_aggregate(acc, final)
-            self.local._span_note(
-                partial_strategy="global", merges=1,
-                pool_peak_bytes=self.pool.peak,
+            # a batch's step is ONE cached program, enqueued, and no
+            # read: the resident path's `jit_global_aggregate`, one shape
+            # for every batch (the scan pads the last). Called unjitted
+            # this was ~60 eager launches a batch, and a streamed Q6 at
+            # SF10 was slower than the Q1 that moves 1.6x its bytes
+            # (PERF.md section 6, PR 36). The scan's count read keeps the
+            # host one batch ahead of its uploads at most: a sink that
+            # loses that read has to bound the run-ahead itself
+            partials = [
+                self.local._exec_aggregate(partial_node, batch)
+                for batch in self._agg_input_stream(node)
+            ]
+            finish = self.local._kernel(
+                "global_aggregate_final",
+                ("global_aggregate_final", final_aggs, node.aggs, posts),
+                lambda: lambda parts: apply_avg_post(
+                    global_aggregate(concat_pages(parts), final_aggs),
+                    node.aggs, posts,
+                ),
             )
-            return apply_avg_post(out, node.aggs, post)
+            out = finish(partials)
+            self.local._span_note(
+                partial_strategy="global", merges=1, partial_reads=0,
+                pool_peak_bytes=self.pool.peak,
+                **({"programs": 2} if self.local.jit else {}),
+            )
+            return out
 
         group_refs = tuple(
             ir.ColumnRef(nm, e.type)
@@ -1602,34 +1622,62 @@ class StreamingExecutor:
         state_held = 0
         merge_rows = max(self.batch_rows // 2, 1 << 14)
         pending: List[Page] = []
-        pending_rows = 0
+        pending_rows = pending_bytes = 0
         spilled = None  # SpilledRows of partial-state pages
-        # the node as the per-batch partial aggregation runs it
-        partial_node = dataclasses.replace(node, aggs=tuple(partial))
         # what the sink did, for its span: host-held values only
         hash_before = self.spill_stats["agg_hash_batches"]
         strategies = set()
         merges = 0
+        partial_reads = 0  # blocking reads of a partial page's count
+        programs = set()  # the cached programs the sink dispatched
 
-        def merge(parts: List[Page], bound: int) -> Page:
+        def merge(parts: List[Page], last: bool = False) -> Page:
+            """`final` over the accumulated partial pages; the answer's
+            columns (`apply_avg_post`) too where it is the `last`."""
             nonlocal merges
             merges += 1
+            slots = sum(p.capacity for p in parts)
+            if slots <= SMALL_PAGE_ROWS:
+                # few slots (the 58 partials of a streamed Q1 at SF10
+                # hold 348): ONE cached program, at slots its groups
+                # cannot outgrow, so no retry and no read. Dispatched
+                # step by step this was ~1,700 launches of that Q1's 1,775
+                mg = round_capacity(slots)
+
+                def merged(pages: List[Page]) -> Page:
+                    out = grouped_aggregate_sorted(
+                        concat_pages(pages), group_refs, node.group_names,
+                        final_aggs, mg,
+                    )
+                    return apply_avg_post(out, node.aggs, posts) if last else out
+
+                fn = self.local._kernel(
+                    "merge_partials",
+                    ("merge_partials", group_refs, node.group_names,
+                     final_aggs, mg, (node.aggs, posts) if last else None),
+                    lambda: merged,
+                )
+                programs.add(("merge_partials", last))
+                return fn(parts)
             acc = parts[0] if len(parts) == 1 else concat_pages(parts)
             out = self._hash_agg_attempt(
                 acc, group_refs, node.group_names, final, None
             )
-            if out is not None:
-                return self.local._shrink(out)
-            mg = round_capacity(min(max(bound, 1), 1 << 22))
-            while True:
-                out = grouped_aggregate_sorted(
-                    acc, group_refs, node.group_names, final, mg
+            if out is None:
+                bound = pending_rows + (
+                    int(host_read(state.count)) if state is not None else 0
                 )
-                true_groups = int(host_read(out.count))
-                if true_groups <= mg:
-                    break
-                mg = round_capacity(true_groups)
-            return self.local._shrink(out)
+                mg = round_capacity(min(max(bound, 1), 1 << 22))
+                while True:
+                    out = grouped_aggregate_sorted(
+                        acc, group_refs, node.group_names, final, mg
+                    )
+                    true_groups = int(host_read(out.count))
+                    if true_groups <= mg:
+                        break
+                    mg = round_capacity(true_groups)
+            out = self.local._shrink(out)
+            return apply_avg_post(out, node.aggs, post) if last else out
 
         def spill_all(pages: List[Page]) -> None:
             """Move partial-state pages to the spill store (re-finalizable:
@@ -1657,12 +1705,16 @@ class StreamingExecutor:
                 part = self._pallas_agg_attempt(partial_node, batch)
                 if part is not None:
                     strategies.add("pallas")
+                    programs.add("grouped_aggregate_pallas")
                 else:
                     part = self._hash_agg_attempt(
                         batch, node.group_exprs, node.group_names, partial,
                         node.mask,
                     )
                     strategies.add("sort" if part is None else "hash")
+                # the partial's rows as far as the host holds them: the
+                # count, where the sort strategy's retry loop has read it
+                part_rows = None
                 if part is None:
                     mg = round_capacity(
                         min(max(int(host_read(batch.count)), 1), 1 << 16)
@@ -1672,22 +1724,35 @@ class StreamingExecutor:
                             batch, node.group_exprs, node.group_names,
                             partial, mg, node.mask,
                         )
-                        if int(host_read(part.count)) <= mg:
+                        part_rows = int(host_read(part.count))
+                        partial_reads += 1
+                        if part_rows <= mg:
                             break
-                        mg = round_capacity(int(host_read(part.count)))
+                        mg = round_capacity(part_rows)
                 part = self.local._shrink(part)
                 if spilled is not None:
                     spill_all([part])
                     continue
                 pending.append(part)
-                pending_rows += int(host_read(part.count))
-                pending_bytes = sum(page_device_bytes(p) for p in pending)
+                if part_rows is None and part.capacity > SMALL_PAGE_ROWS:
+                    part_rows = int(host_read(part.count))
+                    partial_reads += 1
+                elif part_rows is None:
+                    # `pending_rows` only says WHEN to merge and bounds
+                    # the merge's slots, and a page's capacity bounds its
+                    # count: no read for a page `_shrink` would not read
+                    # either. The dense kernel's partial has at most 64
+                    # slots, and its read held the host until the batch
+                    # had crossed the link and the kernel had run
+                    part_rows = part.capacity
+                pending_rows += part_rows
+                pending_bytes += page_device_bytes(part)
                 self.pool.accumulated = pending_bytes
                 if pending_rows >= merge_rows or not self.pool.can_accumulate(
                     pending_bytes
                 ):
                     parts = ([state] if state is not None else []) + pending
-                    new_state = merge(parts, pending_rows + (int(host_read(state.count)) if state is not None else 0))
+                    new_state = merge(parts)
                     self.pool.free(state_held)
                     state_held = 0
                     nb = page_device_bytes(new_state)
@@ -1702,7 +1767,7 @@ class StreamingExecutor:
                         self.pool.note_revoked(nb)
                         state = None
                     pending = []
-                    pending_rows = 0
+                    pending_rows = pending_bytes = 0
                     self.pool.accumulated = 0
             self.pool.accumulated = 0
             if spilled is not None:
@@ -1722,10 +1787,10 @@ class StreamingExecutor:
                 return self._finalize_spilled_agg(
                     node, spilled, group_refs, final, post
                 )
-            out = merge(parts, pending_rows + (int(host_read(state.count)) if state is not None else 0))
+            out = merge(parts, last=True)
             self.pool.free(state_held)
             state_held = 0
-            return apply_avg_post(out, node.aggs, post)
+            return out
         finally:
             self.local._span_note(
                 partial_strategy="+".join(sorted(strategies)),
@@ -1733,7 +1798,12 @@ class StreamingExecutor:
                     self.spill_stats["agg_hash_batches"] - hash_before
                 ),
                 merges=merges, spilled=spilled is not None,
+                partial_reads=partial_reads,
                 pool_peak_bytes=self.pool.peak,
+                **(
+                    {"programs": len(programs)}
+                    if programs and self.local.jit else {}
+                ),
             )
             if state_held:
                 self.pool.free(state_held)

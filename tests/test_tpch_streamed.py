@@ -72,10 +72,13 @@ def bench_json(*parts):
         return json.load(f)
 
 
-def sql_of(case):
-    qid, params = STATEMENTS[case]
+def sql_for(qid, params):
     with open(os.path.join(BENCH, "sql", qid + ".sql")) as f:
         return f.read().format(**params)
+
+
+def sql_of(case):
+    return sql_for(*STATEMENTS[case])
 
 
 def n_lineitem():
@@ -134,6 +137,12 @@ def answers(streamed):
 
 def spans_named(trace, name):
     return [s for s in trace.spans() if s.name == name]
+
+
+def own_host_reads(trace, name):
+    """`host_reads` booked on the span itself, without its children's."""
+    (own,) = [n for s, n in trace.exclusive("host_reads") if s.name == name]
+    return own
 
 
 # -- (a) streamed answers: the references', and the resident session's --
@@ -200,13 +209,113 @@ def test_streamed_q1_through_the_pallas_sink_equals_the_reference(
     (agg,) = spans_named(trace, "Aggregate")
     (scan,) = spans_named(trace, "TableScan")
     assert agg.attrs["partial_strategy"] == "pallas"
-    assert agg.attrs["strategy"] == "pallas" and agg.attrs["programs"] == 1
+    # the batches' program and the end of the stream's (`merge_partials`)
+    assert agg.attrs["strategy"] == "pallas" and agg.attrs["programs"] == 2
+    assert agg.attrs["merges"] == 1
     assert agg.attrs["bound_literals"] == 1
     assert agg.attrs["agg_hash_batches"] == 0
     assert scan.attrs["batches"] == n_batches()
-    # a count a scanned batch, a count a partial page, the merge's
-    assert agg.attrs["host_reads"] <= 2 * n_batches() + 4
+    # a count a scanned batch: no read of a partial page, whose 64 slots
+    # bound its count, and none at the merge, whose slots hold them all
+    assert agg.attrs["host_reads"] <= n_batches() + 4
+    assert agg.attrs["partial_reads"] == 0
+    assert own_host_reads(trace, "Aggregate") == 0
     assert "lineitem" not in streamed_pallas.catalog._pages
+
+
+# -- the sink's step for a batch: one cached program and no read --
+
+@pytest.fixture(scope="module")
+def streamed_wide():
+    """Four batches of 16,384 rows, the last one short."""
+    s = Served(streaming=True, batch_rows=1 << 14, memory_budget=BUDGET)
+    try:
+        yield s
+    finally:
+        s.server.stop()
+
+
+@pytest.mark.parametrize("batch_rows", [BATCH_ROWS, 1 << 14])
+def test_streamed_q6_is_one_program_a_batch_and_no_read(
+    streamed, streamed_wide, resident, batch_rows
+):
+    """No GROUP BY: a batch's partial is the resident path's
+    `jit_global_aggregate`, the end of the stream one program over the
+    partial pages, and the sink reads nothing, however many batches."""
+    served = streamed if batch_rows == BATCH_ROWS else streamed_wide
+    batches = math.ceil(n_lineitem() / batch_rows)
+    assert n_lineitem() % batch_rows, "the last batch has to be short"
+    case = "q6_full-1994"
+    got, trace = served.trace_of(sql_of(case))
+    qid, params = STATEMENTS[case]
+    ref = load("reference", qid)
+    correct, checks = compare.verdict(
+        [(case, got)], {case: (ref.answer({}, params), ref.ORDER_BY)}, 0
+    )
+    assert correct, checks
+    assert got == resident.trace_of(sql_of(case))[0]
+    (agg,) = spans_named(trace, "Aggregate")
+    (scan,) = spans_named(trace, "TableScan")
+    assert scan.attrs["batches"] == batches
+    assert agg.attrs["partial_strategy"] == "global"
+    assert agg.attrs["programs"] == 2
+    assert agg.attrs["partial_reads"] == 0
+    assert scan.attrs["host_reads"] >= batches
+    assert own_host_reads(trace, "Aggregate") == 0
+    assert "lineitem" not in served.catalog._pages
+
+
+def test_second_streamed_q6_compiles_nothing_and_caches_two_programs(
+    streamed
+):
+    """The sink's programs live in `KERNEL_CACHE` like every resident
+    kernel's: a set of literals the process has not seen adds the
+    batches' program (the node holds its literals) and nothing a batch;
+    the end of the stream's program has no literal and is there already;
+    the same statement again compiles nothing."""
+    from presto_tpu.exec.qcache import KERNEL_CACHE
+
+    streamed.trace_of(sql_of("q6_full-1994"))  # the final program's shape
+    sql = sql_for(
+        "q6_full", {"year": 1997, "discount": 3, "quantity": 24, "sf": SF}
+    )
+    before = KERNEL_CACHE.snapshot()
+    _got, first = streamed.trace_of(sql)
+    after = KERNEL_CACHE.snapshot()
+    (agg,) = spans_named(first, "Aggregate")
+    assert agg.attrs["compiles"] >= 1
+    assert 1 <= after["misses"] - before["misses"] <= 2
+    assert after["hits"] - before["hits"] >= n_batches()
+    _got, again = streamed.trace_of(sql)
+    assert all("compiles" not in s.attrs for s in again.spans())
+    assert KERNEL_CACHE.snapshot()["misses"] == after["misses"]
+
+
+def test_large_sort_partials_keep_their_read_and_merge_at_merge_rows(
+    monkeypatch,
+):
+    """A partial page of more than 2^14 slots (the sort strategy over a
+    high-NDV key) is read for its count as before, one read a batch, and
+    the sink merges whenever half a batch of groups is pending."""
+    monkeypatch.setenv("PRESTO_TPU_PALLAS_GROUPBY_HASH", "off")
+    batch_rows = 1 << 15
+    batches = math.ceil(n_lineitem() / batch_rows)
+    sql = (
+        "select l_orderkey, l_linenumber, sum(l_quantity) as q "
+        "from lineitem group by l_orderkey, l_linenumber"
+    )
+    s = Served(streaming=True, batch_rows=batch_rows, memory_budget=BUDGET)
+    try:
+        got, trace = s.trace_of(sql)
+    finally:
+        s.server.stop()
+    assert len(got) == n_lineitem()  # a group a row
+    (agg,) = spans_named(trace, "Aggregate")
+    assert agg.attrs["partial_strategy"] == "sort"
+    assert agg.attrs["partial_reads"] == batches == 2
+    # every batch brings more than merge_rows (2^14) groups: a merge a
+    # batch, and the end of the stream's
+    assert agg.attrs["merges"] == batches + 1
 
 
 # -- (b) residency --
