@@ -36,7 +36,7 @@ from ..ops.join import (
 from ..ops.sort import distinct_page, limit_page, sort_page, top_n
 from ..expr.compiler import project_page
 from ..obs.span import current as current_span
-from ..obs.span import held, host_read
+from ..obs.span import held, host_read, sent
 from ..page import Block, Page, round_capacity
 from ..plan import nodes as N
 
@@ -48,6 +48,18 @@ SMALL_PAGE_ROWS = 1 << 14
 
 class ExecutionError(RuntimeError):
     pass
+
+
+def _last_written(page: Page) -> list:
+    """What to wait for to know a node's output is whole, never the
+    whole page: its count and the last array of its last column. One
+    program writes them all; the eager forms (`_dyn_compact`, `_shrink`)
+    count FIRST and gather the columns in order, and the device runs
+    what it is sent in order."""
+    arrays = [page.count]
+    if page.blocks:
+        arrays.append(jax.tree_util.tree_leaves(page.blocks[-1])[-1])
+    return arrays
 
 
 class Executor:
@@ -150,21 +162,13 @@ class Executor:
         return fn
 
     def _build_kernel(self, name, make_fn):
-        """Cache-fill: name the function for its call site, jit
-        (compilation itself is lazy, paid at the first
-        call) and, when the observability plane is on, wrap in the
-        compile-vs-execute profiler. The wrapper is stored in the cache
-        so "first call" stays attached to the entry's lifetime; it is
-        exception-transparent (the breaker protocol in _kernel_guarded
-        classifies faults by the escaping exception)."""
-        from ..obs.kernelprof import KERNEL_PROFILE, profiling_enabled
-
+        """Cache-fill: name the function for its call site and jit
+        (compilation itself is lazy, paid at the first call, and booked
+        by obs/span.py's compile listener on the span open then)."""
         fn = make_fn()
         fn.__name__ = fn.__qualname__ = name
         if self.jit:
             fn = jax.jit(fn)
-        if profiling_enabled():
-            fn = KERNEL_PROFILE.wrap(fn)
         return fn
 
     def _kernel_guarded(self, breaker_name, name, key, make_fn, *args):
@@ -285,6 +289,9 @@ class Executor:
                         span.attrs[name] = int(n)
             if retries:
                 span.attrs["retries"] = retries
+            # when the node's output is READY goes onto its span later,
+            # from a watcher thread: `Trace.device_spans`
+            sent(_last_written(out), "device")
             wall = cur[0].leave(span).wall_s
         else:
             wall = time.perf_counter() - t0

@@ -13,7 +13,9 @@ Wall time per node includes host sync (`block_until_ready` on the output
 count), so the first call also includes XLA compile time; `calls` lets the
 reader separate warm-up from steady state, and `retries` counts adaptive
 capacity re-executions (the static-shape analog of the reference's page
-growth, which its stats never see).
+growth, which its stats never see). The wall is the HOST's time in the
+node; `device_s` is the node's stretch of the device's queue, from the
+ready stamps of its spans (obs/span.py).
 """
 
 from __future__ import annotations
@@ -37,6 +39,10 @@ class NodeStats:
     out_bytes_total: int = 0  # cumulative across all dispatches
     out_bytes_peak: int = 0  # largest single dispatch
     detail: str = ""  # connector-provided annotation (e.g. file pruning)
+    # the node's stretch of the device's queue, from its spans' ready
+    # stamps (obs/span.py `Trace.device_spans`: its programs and the
+    # gaps between them); None where the run left no stamp
+    device_s: Optional[float] = None
 
     def line(self) -> str:
         ms = self.wall_s * 1e3
@@ -46,6 +52,8 @@ class NodeStats:
             f"out {self.rows_out:,} rows",
             f"{_fmt_bytes(self.out_bytes)}",
         ]
+        if self.device_s is not None:
+            parts.append(f"device-side {self.device_s * 1e3:,.1f}ms")
         if self.calls != 1:
             parts.append(f"{self.calls} calls")
             if self.out_bytes_total != self.out_bytes:

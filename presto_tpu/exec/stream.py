@@ -309,20 +309,6 @@ _SINKS = (N.Aggregate, N.Distinct, N.TopN, N.Limit, N.Sort)
 _END = object()  # a stream has no batch left
 
 
-def _plan_positions(root: N.PlanNode) -> Dict[int, str]:
-    """{id(node): position} over a plan: `0` the root, `0.1.0` child
-    indices from it, as `Executor._run` numbers its spans."""
-    positions: Dict[int, str] = {}
-    todo = [(root, "0")]
-    while todo:
-        node, pos = todo.pop()
-        positions.setdefault(id(node), pos)
-        todo.extend(
-            (c, f"{pos}.{i}") for i, c in enumerate(node.children)
-        )
-    return positions
-
-
 class StreamingExecutor:
     """Host driver loop over device page batches (reference Driver +
     TaskExecutor collapsed: one Python loop, kernels stay on device)."""
@@ -434,7 +420,7 @@ class StreamingExecutor:
     def run(self, node: N.PlanNode) -> Page:
         self.dyn_ctx.reset()  # filters are per-query state
         self._pos = (
-            _plan_positions(node) if obs_span.current() is not None else {}
+            N.plan_positions(node) if obs_span.current() is not None else {}
         )
         try:
             return self._run(node)
@@ -663,6 +649,11 @@ class StreamingExecutor:
                 columns=cols, predicate=predicate,
             )
             scan_s = time.perf_counter() - t0
+            # every column is with the runtime: when the link has copied
+            # them goes onto the scan's span from a watcher thread
+            # (`upload_s`, `link_idle_s`, `inflight_peak_bytes`)
+            src_bytes = page_device_bytes(src)
+            obs_span.sent(src, "link", src_bytes)
             # connector pruning counters are per scan CALL; take the max
             # across batches — exact for partition pruning (every call sees
             # the full file set) and a per-batch high-water for stripe
@@ -684,9 +675,7 @@ class StreamingExecutor:
             rows_total += n
             obs_span.count(
                 batches=1, scan_s=scan_s,
-                upload_bytes=(
-                    page_device_bytes(src) // max(src.capacity, 1) * n
-                ),
+                upload_bytes=src_bytes // max(src.capacity, 1) * n,
             )
             self.local._span_note(rows=rows_total)
             if n > 0 or start == 0:

@@ -1,13 +1,11 @@
-"""Observability plane: span trees + metrics registry + kernel profile.
+"""Observability plane: span trees + metrics registry.
 
 One subsystem unifying the engine's stats silos (see
 docs/observability.md): `Trace`/`TRACES` for per-query span trees that
 survive retry and merge across the fleet, `METRICS` for the
-process-wide Prometheus-rendered registry, `KERNEL_PROFILE` for the
-compile-vs-execute split of KERNEL_CACHE entries.
+process-wide Prometheus-rendered registry.
 """
 
-from .kernelprof import KERNEL_PROFILE, KernelProfile
 from .metrics import METRICS, MetricsRegistry
 from .span import (
     TRACES,
@@ -18,8 +16,6 @@ from .span import (
 )
 
 __all__ = [
-    "KERNEL_PROFILE",
-    "KernelProfile",
     "METRICS",
     "MetricsRegistry",
     "TRACES",

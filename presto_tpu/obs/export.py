@@ -2,7 +2,7 @@
 
 Every `*Stats` surface the engine already maintains (NodeStats,
 ExchangeStats, SchedulerStats, WireStats, GroupStats, CacheStats via
-qcache snapshots, breaker stats, the kernel profile) exports here —
+qcache snapshots, breaker stats, the spans' compile totals) exports here —
 prestolint's `stats-not-exported` rule enforces that a surfaced Stats
 class also reaches this module, so a new silo can't silently stay
 invisible to `/v1/metrics`.
@@ -84,7 +84,7 @@ def ensure_default_exports() -> None:
     )
     METRICS.register_producer("qcache", _metrics_qcache_producer)
     METRICS.register_producer("breakers", _metrics_breaker_producer)
-    METRICS.register_producer("kernel_profile", _metrics_kernel_producer)
+    METRICS.register_producer("compiles", _metrics_kernel_producer)
     METRICS.register_producer("feedback", _metrics_feedback_producer)
 
 
@@ -178,18 +178,14 @@ def _metrics_breaker_producer() -> List[Sample]:
 
 
 def _metrics_kernel_producer() -> List[Sample]:
-    from .kernelprof import KERNEL_PROFILE
+    """Backend compiles of this process, as the spans' listener counts
+    them (obs/span.py: `compiles` / `compile_s` on the span that paid)."""
+    from .span import compile_totals
 
-    snap = KERNEL_PROFILE.snapshot()
+    compiles, seconds = compile_totals()
     return [
-        ("presto_kernel_compiles_total", "counter", (),
-         float(snap["compiles"])),
-        ("presto_kernel_compile_seconds_total", "counter", (),
-         snap["compile_s"]),
-        ("presto_kernel_executions_total", "counter", (),
-         float(snap["executions"])),
-        ("presto_kernel_execute_seconds_total", "counter", (),
-         snap["execute_s"]),
+        ("presto_kernel_compiles_total", "counter", (), float(compiles)),
+        ("presto_kernel_compile_seconds_total", "counter", (), seconds),
     ]
 
 

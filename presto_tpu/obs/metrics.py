@@ -11,9 +11,9 @@ queryable as `system.runtime.metrics`.
 Two export styles, matching how the silos already work:
 
 * **push**: hot paths fold deltas with `counter()` / `observe()`
-  (exchange folds at task end, query completions, kernel profile);
+  (exchange folds at task end, query completions);
 * **pull**: process-global snapshot owners (qcache, BREAKERS, the
-  kernel profile) register a *producer* callback evaluated at scrape
+  spans' compile totals) register a *producer* callback evaluated at scrape
   time, so serving paths never pay for gauge upkeep.
 
 Histograms use fixed log2 buckets (0.25ms .. ~2min) so two processes'

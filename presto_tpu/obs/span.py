@@ -29,12 +29,22 @@ span, `leave` folds a span's counters into its parent, so every span
 carries its subtree's totals.
 `begin` / `finish` stay the thread-free primitives the cluster path and
 the cross-thread `statement` / `queued` spans use.
+
+A span's two clock readings say when the host finished ASKING. When what
+it asked for was DONE is a ready stamp (`sent`): the thread that has
+just enqueued work hands the arrays it will write to a watcher thread,
+which waits for them and writes the time onto the span, so the served
+thread never blocks for a stamp. `Trace.device_spans` turns the stamps
+into each operator's stretch of the device's queue; the streamed scan's
+batches (queue `link`) leave the link's busy and idle time on the
+`TableScan` span.
 """
 
 from __future__ import annotations
 
 import contextlib
 import itertools
+import queue as queue_mod
 import threading
 import time
 import uuid
@@ -246,6 +256,52 @@ class Trace:
             for s in spans
         ]
 
+    def device_spans(self) -> List[Tuple[Span, float]]:
+        """(span, seconds) for every span that carries a ready stamp of
+        the `device` queue (`sent`; `Executor._run` hands over each
+        node's output): its DEVICE-SIDE span, `ready_at` minus the later
+        of `ready_after` (when the entry before it on the queue was
+        ready: the device was not this node's before that) and the
+        moment the node's own work began (its last child's leave, or its
+        start). A node that works BETWEEN its children (a join publishes
+        its dynamic filter after the build side) also gets the stretch
+        from the one child's `ready_at` to the next child's first start.
+        That is the part of the device's queue that belongs to the node:
+        its programs AND the gaps between them (the host's reads and
+        dispatch inside the node), so it is the node's device time only
+        where the device is busy throughout. A stamp is never earlier
+        than its hand-over, so a node's host work after its last program
+        counts too. The stretches of one statement do not overlap."""
+        spans = self.spans()
+        children_left: Dict[str, float] = {}
+        for s in spans:
+            if s.parent_id is not None and s.end is not None:
+                children_left[s.parent_id] = max(
+                    children_left.get(s.parent_id, 0.0), s.end
+                )
+        stamped = sorted(
+            (
+                s for s in spans
+                if s.attrs.get("ready_queue") == "device"
+                and "ready_at" in s.attrs
+            ),
+            key=lambda s: (s.attrs["ready_at"], s.attrs["handed_at"]),
+        )  # the order handed over
+        own = {s.span_id: 0.0 for s in stamped}
+        before = None
+        for s in stamped:
+            after = s.attrs.get("ready_after", 0.0)
+            began = max(s.start, children_left.get(s.span_id, 0.0), after)
+            own[s.span_id] += max(0.0, s.attrs["ready_at"] - began)
+            if before is not None and before.parent_id in own:
+                # the host came here from `before`, a child of a node
+                # above: what lies between is that node's own work
+                own[before.parent_id] += max(
+                    0.0, s.start - max(after, before.end or after)
+                )
+            before = s
+        return [(s, own[s.span_id]) for s in stamped]
+
     def critical_path(self, topk: int = 5) -> List[Tuple[Span, float]]:
         ranked = sorted(
             self.exclusive_walls(), key=lambda p: p[1], reverse=True
@@ -426,6 +482,149 @@ def count(**amounts) -> None:
             counters[name] = counters.get(name, 0) + amount
 
 
+# -- ready stamps: when what a span sent was done ---------------------------
+
+
+def sent(arrays, queue: str = "device", nbytes: int = 0) -> None:
+    """Note on the calling thread's innermost open span when `arrays`,
+    which this thread has just enqueued the writing of, are READY. Never
+    blocks and dispatches nothing: the host time goes down as
+    `handed_at` and the arrays go to the queue's watcher, a daemon thread
+    (started at first use, one a queue) that takes entries first-in
+    first-out, `block_until_ready`s each and writes `ready_at` onto the
+    entry's span under the trace's lock, on the spans' clock. The span
+    may have closed by then: stamps are plain attributes, not counters
+    `leave` folds. A failed or deleted array stamps `ready_error` and
+    nothing else. `queue` says which of the runtime's queues the work is
+    on, `device` (the compute stream) or `link` (host-to-device copies):
+    entries of one finish in the order sent, entries of different ones
+    do not, so neither's stamps wait behind the other's. `nbytes` is
+    what the entry holds of the device until it is ready. Nothing on a
+    thread with no open span (always so under PRESTO_TPU_TRACE=0): no
+    thread is started."""
+    cur = getattr(_OPEN, "cur", None)
+    if cur is not None:
+        _watcher(queue).hand(cur[0], cur[1], arrays, nbytes)
+
+
+def settle(timeout: float = 10.0) -> bool:
+    """Wait until every entry handed over so far is stamped: for a
+    reader that runs right behind the statement (EXPLAIN ANALYZE, a
+    test), never for the served path. False if `timeout` s passed
+    first."""
+    markers = []
+    for watcher in list(_WATCHERS.values()):
+        marker = threading.Event()
+        watcher.entries.put(marker)
+        markers.append(marker)
+    deadline = time.monotonic() + timeout
+    return all(
+        m.wait(max(0.0, deadline - time.monotonic())) for m in markers
+    )
+
+
+def _stamp_operator(attrs, handed_at, ready_at, queue_ready_at, _inflight):
+    """`device`: one entry a span, its node's output. `ready_after` is
+    when the entry before it on the queue was ready (absent on the
+    queue's first): `Trace.device_spans` reads the three."""
+    attrs["handed_at"] = handed_at
+    attrs["ready_at"] = ready_at
+    if queue_ready_at is not None:
+        attrs["ready_after"] = queue_ready_at
+
+
+def _stamp_batch(attrs, handed_at, ready_at, _queue_ready_at, inflight):
+    """`link`: one entry a batch of a streamed scan, all on the scan's
+    ONE span. Batch k adds to `upload_s` its `ready_k - max(ready_{k-1},
+    handed_k)`: the link's time that nothing on the host covered; and to
+    `link_idle_s` `max(0, handed_k - ready_{k-1})`: the time the link had
+    nothing of this scan to copy because the host had not handed over
+    the next batch. The two sum to the last `ready_at` minus the first
+    `handed_at`. `inflight_peak_bytes`: the most bytes handed to the
+    queue and not yet ready, as a hand-over of this scan found it."""
+    before = attrs.get("ready_at")
+    attrs.setdefault("handed_at", handed_at)
+    attrs["ready_at"] = ready_at
+    attrs["uploads"] = attrs.get("uploads", 0) + 1
+    began = handed_at if before is None else max(before, handed_at)
+    attrs["upload_s"] = attrs.get("upload_s", 0.0) + max(
+        0.0, ready_at - began
+    )
+    attrs["link_idle_s"] = attrs.get("link_idle_s", 0.0) + (
+        0.0 if before is None else max(0.0, handed_at - before)
+    )
+    attrs["inflight_peak_bytes"] = max(
+        attrs.get("inflight_peak_bytes", 0), inflight
+    )
+
+
+_STAMPS = {"device": _stamp_operator, "link": _stamp_batch}
+_WATCHERS: Dict[str, "_Watcher"] = {}
+_watchers_lock = threading.Lock()
+
+
+def _watcher(queue: str) -> "_Watcher":
+    watcher = _WATCHERS.get(queue)
+    if watcher is None:
+        with _watchers_lock:
+            watcher = _WATCHERS.get(queue)
+            if watcher is None:
+                watcher = _WATCHERS[queue] = _Watcher(queue, _STAMPS[queue])
+    return watcher
+
+
+class _Watcher:
+    """One queue's entries, stamped in the order handed over."""
+
+    def __init__(self, name: str, stamp):
+        self.name = name
+        self.stamp = stamp
+        self.entries: "queue_mod.SimpleQueue" = queue_mod.SimpleQueue()
+        self._lock = threading.Lock()  # `_inflight`: two threads write it
+        self._inflight = 0
+        self._ready_at: Optional[float] = None  # of the newest entry
+        threading.Thread(
+            target=self._watch, name=f"presto-ready-{name}", daemon=True
+        ).start()
+
+    def hand(self, trace: Trace, span: Span, arrays, nbytes: int) -> None:
+        handed_at = time.time()
+        with self._lock:
+            self._inflight += nbytes
+            inflight = self._inflight
+        self.entries.put([trace, span, arrays, nbytes, handed_at, inflight])
+
+    def _watch(self) -> None:
+        while True:
+            entry = self.entries.get()
+            if isinstance(entry, threading.Event):  # `settle`'s marker
+                entry.set()
+            else:
+                self._wait(entry)
+            del entry  # nor the trace, while the queue is empty
+
+    def _wait(self, entry: list) -> None:
+        trace, span, arrays, nbytes, handed_at, inflight = entry
+        error = None
+        try:
+            jax.block_until_ready(arrays)
+        except Exception as e:  # noqa: BLE001 — a stamp never raises
+            error = f"{type(e).__name__}: {e}"[:200]
+        ready_at = time.time()
+        entry[2] = arrays = None  # nothing is held past readiness
+        with self._lock:
+            self._inflight -= nbytes
+        with trace._lock:
+            span.attrs["ready_queue"] = self.name
+            if error is not None:
+                span.attrs["ready_error"] = error
+                return
+            self.stamp(
+                span.attrs, handed_at, ready_at, self._ready_at, inflight
+            )
+        self._ready_at = ready_at
+
+
 class Pulled:
     """A span for a body that runs in pieces on one thread: a generator
     that yields batches to a consumer between them (exec/stream.py's
@@ -437,10 +636,16 @@ class Pulled:
     wall is the body's own time, children's included, as an `enter`ed
     span's is, so `Trace.exclusive_walls` still gives every span its
     self time; only its right edge on the timeline is not where the last
-    piece ended. It holds no profiler annotation (one a piece would be
-    one a batch). `open` returns None on a thread with no open span."""
+    piece ended. Each piece is held inside a profiler annotation
+    `presto.<name>`, as an `enter`ed span is (no cost beyond a flag test
+    while no profiler session runs): a profile of a streamed statement
+    has the node's pieces on the host's timeline. `open` returns None on
+    a thread with no open span."""
 
-    __slots__ = ("trace", "span", "parent", "inside_s", "_outer", "_t0")
+    __slots__ = (
+        "trace", "span", "parent", "inside_s", "_outer", "_t0",
+        "_annotation",
+    )
 
     @classmethod
     def open(cls, name: str, **attrs) -> Optional["Pulled"]:
@@ -456,11 +661,17 @@ class Pulled:
     def __enter__(self):
         self._outer = getattr(_OPEN, "cur", None)
         _OPEN.cur = (self.trace, self.span)
+        self._annotation = TraceAnnotation(
+            "presto." + self.span.name, **self.trace._mark
+        )
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self.span
 
     def __exit__(self, *_exc):
         self.inside_s += time.perf_counter() - self._t0
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
         _OPEN.cur = self._outer
         return False
 
@@ -479,6 +690,7 @@ class Pulled:
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _listening = False
 _listening_lock = threading.Lock()
+_compile_totals = [0, 0.0]  # this process's, since the listener began
 
 
 def _listen_for_compiles() -> None:
@@ -498,11 +710,22 @@ def _listen_for_compiles() -> None:
 def _on_duration(event: str, secs: float, **_) -> None:
     if event != _COMPILE_EVENT:
         return
+    with _listening_lock:
+        _compile_totals[0] += 1
+        _compile_totals[1] += secs
     cur = getattr(_OPEN, "cur", None)
     if cur is not None:
         counters = cur[1].counters
         counters["compiles"] = counters.get("compiles", 0) + 1
         counters["compile_s"] = counters.get("compile_s", 0.0) + secs
+
+
+def compile_totals() -> Tuple[int, float]:
+    """(backend compiles, their seconds) of this process since its first
+    trace: what the spans' `compiles` / `compile_s` add up to, and the
+    compiles on threads with no open span."""
+    with _listening_lock:
+        return _compile_totals[0], _compile_totals[1]
 
 
 # process-global: the coordinator's (or single-process session's) view

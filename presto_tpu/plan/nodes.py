@@ -12,7 +12,7 @@ collisions.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from .. import types as T
 from ..expr.ir import RowExpression
@@ -382,6 +382,20 @@ def _sort_key_str(k) -> str:
     if k.nulls_first is not None:
         s += " nulls first" if k.nulls_first else " nulls last"
     return s
+
+
+def plan_positions(root: PlanNode) -> Dict[int, str]:
+    """{id(node): position} over a plan: `0` the root, `0.1.0` child
+    indices from it, as the executors number their spans (`pos`)."""
+    positions: Dict[int, str] = {}
+    todo = [(root, "0")]
+    while todo:
+        node, pos = todo.pop()
+        positions.setdefault(id(node), pos)
+        todo.extend(
+            (c, f"{pos}.{i}") for i, c in enumerate(node.children)
+        )
+    return positions
 
 
 def plan_tree_str(

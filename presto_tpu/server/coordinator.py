@@ -280,7 +280,7 @@ class CoordinatorServer:
                     # Prometheus text exposition 0.0.4 over the unified
                     # MetricsRegistry (obs/metrics.py): every stats silo
                     # — qcache, breakers, exchange, wire, scheduler,
-                    # kernel profile, resource groups — in one scrape
+                    # compile totals, resource groups — in one scrape
                     from ..obs.metrics import METRICS
 
                     self._send(

@@ -1376,10 +1376,8 @@ class Session:
         collector = StatsCollector()
         ex = self._collector_executor(collector)
         from .obs import span as obs_span
-        from .obs.kernelprof import KERNEL_PROFILE
 
         traced = obs_span.enabled()
-        kprof_before = KERNEL_PROFILE.snapshot()
         trace = root = exec_span = None
         if traced:
             # a trace of its own, so that the `-- trace:` footer ranks
@@ -1397,6 +1395,19 @@ class Session:
         # fold parked device row-count scalars in one batch (the lazy
         # collector avoids a blocking host sync per plan node)
         collector.resolve()
+        device_s = {}
+        if traced:
+            # each node's stretch of the device's queue, from the ready
+            # stamps its spans got (the resident executor's; the watcher
+            # may be a moment behind the reads above)
+            obs_span.settle()
+            for span, secs in trace.device_spans():
+                pos = span.attrs.get("pos")
+                device_s[pos] = device_s.get(pos, 0.0) + secs
+            for node_id, pos in N.plan_positions(node).items():
+                stats = collector.by_node.get(node_id)
+                if stats is not None and pos in device_s:
+                    stats.device_s = device_s[pos]
         tree = N.plan_tree_str(node, collector=collector)
         total_ms = collector.total_wall_s() * 1e3
         peak = collector.peak_bytes / (1024 * 1024)
@@ -1523,9 +1534,9 @@ class Session:
         if mgr is not None and mgr.views:
             matview_txt = "\n-- matview: " + mgr.format_summary()
         # observability footers (docs/observability.md): the critical
-        # path from the SAME span-tree renderer the cluster path uses,
-        # and the compile-vs-execute split this run added to the
-        # process-wide kernel profile
+        # path from the SAME span-tree renderer the cluster path uses;
+        # what this run compiled (the spans' listener booked it on the
+        # span that paid) and the device-side spans printed a node above
         trace_txt = kernel_txt = ""
         if traced:
             from .server import knobs as _knobs
@@ -1533,16 +1544,13 @@ class Session:
             trace_txt = "\n-- trace: " + obs_span.render_critical_path(
                 trace, _knobs.trace_topk()
             )
-            kp = KERNEL_PROFILE.snapshot()
-            d_comp = kp["compiles"] - kprof_before["compiles"]
-            d_exec = kp["executions"] - kprof_before["executions"]
-            if d_comp or d_exec:
-                d_comp_s = kp["compile_s"] - kprof_before["compile_s"]
-                d_exec_s = kp["execute_s"] - kprof_before["execute_s"]
+            compiles = root.attrs.get("compiles", 0)
+            if compiles or device_s:
                 kernel_txt = (
-                    f"\n-- kernels: compile +{d_comp}"
-                    f" ({d_comp_s * 1e3:,.1f}ms),"
-                    f" execute +{d_exec} ({d_exec_s * 1e3:,.1f}ms)"
+                    f"\n-- kernels: compile +{compiles}"
+                    f" ({root.attrs.get('compile_s', 0.0) * 1e3:,.1f}ms),"
+                    f" device-side {sum(device_s.values()) * 1e3:,.1f}ms"
+                    f" over {len(device_s)} operators"
                 )
         return (
             f"{tree}{dyn_txt}{breaker_txt}{mem_txt}{exch_txt}{cache_txt}"

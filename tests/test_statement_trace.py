@@ -325,41 +325,78 @@ def test_trace_off_leaves_no_trace_and_the_same_rows(served, monkeypatch):
 # -- (f) the profiler's clock -----------------------------------------------
 
 
-def test_spans_are_annotations_in_a_profile(served, tmp_path):
+def profiled_annotations(tmp_path, run):
+    """[(name, start ns, end ns)] of the `presto.*` events on each line
+    of a profile taken around `run()`."""
     from jax.profiler import ProfileData
 
-    sql = sql_of("q6")
-    serve(served, sql)  # warm: the profile holds a steady statement
     jax.profiler.start_trace(str(tmp_path))
     try:
-        serve(served, sql)
+        run()
     finally:
         jax.profiler.stop_trace()
     paths = glob.glob(
         str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
     )
     assert paths
-    nested = 0
-    names = set()
-    for plane in ProfileData.from_file(paths[-1]).planes:
-        for line in plane.lines:
-            events = [
-                (e.name, e.start_ns, e.start_ns + e.duration_ns)
-                for e in line.events if e.name.startswith("presto.")
-            ]
-            names.update(e[0] for e in events)
-            for name, a, b in events:
-                if name != "presto.execute":
-                    continue
-                # the worker thread's line: the operator inside execute
-                nested += sum(
-                    n == "presto.Aggregate" and a <= c and d <= b
-                    for n, c, d in events
-                )
-    assert nested == 1
+    return [
+        [
+            (e.name, e.start_ns, e.start_ns + e.duration_ns)
+            for e in line.events if e.name.startswith("presto.")
+        ]
+        for plane in ProfileData.from_file(paths[-1]).planes
+        for line in plane.lines
+    ]
+
+
+def nested(events, inner, outer):
+    """Events called `inner` inside an event called `outer`."""
+    return sum(
+        n == inner and a <= c and d <= b
+        for name, a, b in events if name == outer
+        for n, c, d in events
+    )
+
+
+def test_spans_are_annotations_in_a_profile(served, tmp_path):
+    sql = sql_of("q6")
+    serve(served, sql)  # warm: the profile holds a steady statement
+    lines = profiled_annotations(tmp_path, lambda: serve(served, sql))
+    names = {name for events in lines for name, _a, _b in events}
+    # the worker thread's line: the operator inside execute
+    assert sum(
+        nested(ev, "presto.Aggregate", "presto.execute") for ev in lines
+    ) == 1
     assert {"presto.submit", "presto.query", "presto.plan",
             "presto.rows", "presto.TableScan"} <= names
     assert "presto.queued" not in names  # crosses threads: no annotation
+
+
+def test_a_streamed_statements_pieces_are_annotations(tmp_path):
+    """`obs.span.Pulled`: a node that hands batches on has ONE span and
+    an annotation a piece, inside its sink's."""
+    from presto_tpu.connectors import tpch
+
+    session = Session(
+        tpch.TpchCatalog(sf=SF), result_cache=False, streaming=True,
+        batch_rows=4096, memory_budget=64 << 20,
+    )
+    sql = sql_of("q6")
+    session.query(sql).rows()
+    lines = profiled_annotations(
+        tmp_path, lambda: session.query(sql).rows()
+    )
+    trace = TRACES.recent()[-1]
+    scan = span_named(trace, "TableScan")
+    assert scan.attrs["batches"] == 15
+    # a piece a batch and the one that finds the stream at its end
+    assert sum(
+        nested(ev, "presto.TableScan", "presto.Aggregate") for ev in lines
+    ) == scan.attrs["batches"] + 1
+    assert sum(
+        nested(ev, "presto.Aggregate", "presto.execute") for ev in lines
+    ) == 1
+    assert len([s for s in trace.spans() if s.name == "TableScan"]) == 1
 
 
 # -- (g) the benchmark's metric files ----------------------------------------

@@ -658,9 +658,10 @@ def test_the_stream_cells_files_agree():
         with open(os.path.join(BENCH, "sql", old + ".sql")) as a, \
                 open(os.path.join(BENCH, "sql", new + ".sql")) as b:
             assert a.read() == b.read()
-    names = ["stream_batches_per_stmt", "stream_scan_ms", "stream_sink_ms"]
-    for m in bench["per_layer"][-3:]:
-        assert m["name"] == names.pop(0)
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in ("stream_batches_per_stmt", "stream_scan_ms",
+                 "stream_sink_ms"):
+        m = by_name[name]
         assert m["workloads"] == ["sf10s.scan_agg"] and m["moves"] == "stmt_ms"
 
 
@@ -677,7 +678,22 @@ def test_the_sf10_join_cells_files_agree():
     (old,) = bench_json("traffic", "join_full.json")["statements"]
     assert st["id"] == old["id"] == "q3_full"
     assert [dict(s, sf=config["sf"]) for s in old["sets"]] == st["sets"]
-    # no listed metric names the new cells beside the three streamed ones
-    for m in bench["end_to_end"] + bench["per_layer"][:-3]:
-        assert "sf10.join" not in m.get("workloads", [])
-        assert "sf10s.scan_agg" not in m.get("workloads", [])
+    # the listed metrics that name the two cells: the streamed scan's
+    # (PR 35) and the ready stamps' (PR 37), and no end-to-end one
+    naming = {
+        cell: {
+            m["name"] for m in bench["end_to_end"] + bench["per_layer"]
+            if cell in m.get("workloads", [])
+        }
+        for cell in ("sf10.join", "sf10s.scan_agg")
+    }
+    assert naming == {
+        "sf10.join": {
+            "join_device_ms", "filter_device_ms", "aggregate_device_ms",
+        },
+        "sf10s.scan_agg": {
+            "stream_batches_per_stmt", "stream_scan_ms", "stream_sink_ms",
+            "stream_upload_ms", "stream_link_idle_ms",
+            "stream_inflight_peak_mb",
+        },
+    }

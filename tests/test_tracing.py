@@ -3,20 +3,17 @@
 Covers the obs/ package end to end: span trees (begin/finish, remote
 merge idempotence, retry-sibling semantics, exclusive-wall critical
 path), the MetricsRegistry (counters/gauges/histograms, Prometheus
-exposition, scrape-time producers, failure isolation), kernel
-compile-vs-execute profiling, the single-process Session trace +
-EXPLAIN ANALYZE footers, system.runtime.metrics / system.runtime.tasks,
+exposition, scrape-time producers, failure isolation), the
+single-process Session trace + EXPLAIN ANALYZE footers, system.runtime.metrics / system.runtime.tasks,
 the query_completed event's trace fields, NodeStats cumulative output
 accounting, and the coordinator's /v1/metrics endpoint.
 """
 
 import urllib.request
 
-import numpy as np
 import pytest
 
 from presto_tpu.connectors.tpch import TpchCatalog
-from presto_tpu.obs.kernelprof import KERNEL_PROFILE
 from presto_tpu.obs.metrics import METRICS, MetricsRegistry
 from presto_tpu.obs.span import TRACES, Trace, render_critical_path
 from presto_tpu.session import Session
@@ -148,38 +145,6 @@ def test_label_escaping():
     assert '{q="a\\"b\\\\c\\nd"}' in text
 
 
-# -- kernel profiling ---------------------------------------------------------
-
-
-def test_kernel_profile_splits_compile_from_execute():
-    import jax
-
-    KERNEL_PROFILE.reset()
-    fn = KERNEL_PROFILE.wrap(jax.jit(lambda x: x + 1))
-    fn(np.arange(4))
-    fn(np.arange(4))
-    fn(np.arange(4))
-    snap = KERNEL_PROFILE.snapshot()
-    assert snap["compiles"] == 1
-    assert snap["executions"] == 2
-    assert snap["compile_s"] > 0
-
-
-def test_kernel_profile_exceptions_escape_unrecorded():
-    KERNEL_PROFILE.reset()
-
-    def boom(x):
-        raise RuntimeError("XlaRuntimeError: injected")
-
-    fn = KERNEL_PROFILE.wrap(boom)
-    with pytest.raises(RuntimeError):
-        fn(1)
-    snap = KERNEL_PROFILE.snapshot()
-    # a failed first call is NOT booked as the compile — the breaker
-    # protocol (exec/breaker.py) owns failure accounting
-    assert snap["compiles"] == 0 and snap["executions"] == 0
-
-
 # -- single-process session ---------------------------------------------------
 
 
@@ -216,6 +181,8 @@ def test_explain_analyze_trace_and_kernel_footers(sess):
     text = "\n".join(r[0] for r in out.rows())
     assert "-- trace: trace " in text
     assert "top exclusive:" in text
+    # the first run of the text compiled, and every node was stamped
+    assert "-- kernels: compile +" in text and " device-side " in text
     # per-node synthetic spans graft into the same tree shape
     assert "TableScan" in text.split("-- trace:")[1] or "Aggregate" in text
 
