@@ -8,6 +8,17 @@ TPU regression or win is attributable to a stage, not guessed.
 
     python -m presto_tpu.benchmark.profile_join --sf 0.1 --runs 5
 
+`--ranks` times instead how the join programs rank SORTED queries in a
+sorted array (ops/join.py `sorted_rank`), each shape three ways:
+`jnp.searchsorted`'s default gather rounds (`search`), the merge through
+sort (`merge`) and `jnp.searchsorted(method="sort")`, whose rank step is a
+scatter (`sort`); the shapes are the bucket directories and expansion maps
+of the join cells and one map with far fewer queries than values. The
+values are an argument of each timed program, and the compiled program's
+`sort` and `while` ops are counted beside its time:
+
+    python -m presto_tpu.benchmark.profile_join --ranks --runs 5
+
 Reference analog: BenchmarkHashBuildAndJoinOperators breaks build/probe
 phases apart for the same reason.
 """
@@ -19,19 +30,19 @@ import json
 import time
 
 
-def _chained(fn, n_runs=5, reps=3):
+def _chained(fn, n_runs=5, reps=3, args=()):
     import jax
     import jax.numpy as jnp
 
     f = jax.jit(fn)
-    s = f(jnp.int64(0))
+    s = f(*args, jnp.int64(0))
     int(s)  # compile + warm
     best = float("inf")
     for _ in range(reps):
         t0 = time.perf_counter()
         s = jnp.int64(0)
         for _ in range(n_runs):
-            s = f(s)
+            s = f(*args, s)
         int(s)
         best = min(best, (time.perf_counter() - t0) / n_runs)
     return best
@@ -115,9 +126,98 @@ def main(sf: float = 0.1, runs: int = 5):
     return out
 
 
+# (label, values n, queries nq, side): the directory of a build page of
+# n slots (2^bits + 1 queries, bits capped at 22) and join_expand's map of
+# nq output slots over a probe page of n slots: the SF10 cells', a map far
+# below its probe (where a search would beat the merge), the SF1 cells'
+RANK_SHAPES = (
+    ("directory", 2_097_152, 4_194_305, "left"),
+    ("expand_map", 4_194_304, 4_194_304, "right"),
+    ("expand_map", 4_194_304, 16_384, "right"),
+    ("directory", 262_144, 524_289, "left"),
+    ("expand_map", 65_536, 131_072, "right"),
+)
+
+
+def _rank_values(label: str, n: int, nq: int):
+    """Sorted int32 values of a shape: bucket ids of 2^22-capped bits with
+    a dead tail in the last bucket, or the cumulative counts of a probe
+    whose rows have 0-1 candidates."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    key = jax.random.PRNGKey(n ^ nq)
+    if label == "directory":
+        nb = nq - 1
+        b = jax.random.randint(key, (n,), 0, nb, dtype=jnp.int32)
+        b = jnp.where(jnp.arange(n) < n - n // 8, b, nb - 1)
+        return jnp.sort(b)
+    counts = jax.random.randint(key, (n,), 0, 2, dtype=jnp.int32)
+    return jnp.cumsum(counts * np.int32(max(1, nq // max(n, 1))))
+
+
+def main_ranks(runs: int = 5):
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ..ops import join as J
+
+    out = {"backend": jax.default_backend()}
+    for label, n, nq, side in RANK_SHAPES:
+        a = _rank_values(label, n, nq)
+        q = jnp.arange(nq, dtype=jnp.int32)
+        forms = {
+            "search": lambda v: jnp.searchsorted(v, q, side=side),
+            "merge": lambda v: J.sorted_rank(v, nq, side),
+            "sort": lambda v: jnp.searchsorted(v, q, side=side, method="sort"),
+        }
+        want = np.searchsorted(np.asarray(a), np.arange(nq), side=side)
+        row = {"n": n, "nq": nq, "rounds": int(np.ceil(np.log2(n + 1)))}
+        for form, f in forms.items():
+            got = np.asarray(jax.jit(f)(a))
+            if not np.array_equal(got, want):
+                row[f"{form}_ms"] = "error: differs from np.searchsorted"
+                continue
+
+            def timed(v, acc, f=f):
+                # the ranks are never negative, so this adds 0, and only
+                # the device knows it: each run waits for the last
+                r = f(v + (acc < 0).astype(v.dtype))
+                return jnp.sum(r.astype(jnp.int64))
+
+            hlo = jax.jit(timed).lower(a, jnp.int64(0)).compile().as_text()
+            row[f"{form}_hlo"] = {
+                op: len(re.findall(rf"\b{op}\(", hlo))
+                for op in ("sort", "while")
+            }
+            ms = _chained(timed, runs, args=(a,)) * 1e3
+            row[f"{form}_ms"] = round(ms, 3)
+        if isinstance(row["search_ms"], float):
+            row["search_ns_per_index"] = round(
+                row["search_ms"] * 1e6 / (nq * row["rounds"]), 3
+            )
+        if isinstance(row["merge_ms"], float):
+            row["merge_ns_per_element"] = round(
+                row["merge_ms"] * 1e6 / (n + nq), 3
+            )
+        out[f"{label}_{nq}x{n}"] = row
+        print(json.dumps({f"{label}_{nq}x{n}": row}), flush=True)
+    print(json.dumps(out))
+    return out
+
+
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--sf", type=float, default=0.1)
     ap.add_argument("--runs", type=int, default=5)
+    ap.add_argument("--ranks", action="store_true",
+                    help="time the three ways to rank sorted queries")
     a = ap.parse_args()
-    main(a.sf, a.runs)
+    if a.ranks:
+        main_ranks(a.runs)
+    else:
+        main(a.sf, a.runs)

@@ -6,15 +6,20 @@ LookupJoinOperator/JoinProbe (presto-main/.../operator/JoinHash.java:28,
 getJoinPosition :82-89; LookupJoinOperator.java).
 
 TPU-first redesign: the "hash table" is the build side *sorted by key hash* —
-a layout XLA produces with one optimized sort and probes with vectorized
-binary search (jnp.searchsorted), instead of pointer-chasing collision chains.
-Duplicate build keys are contiguous runs, the analog of PositionLinks chains:
+a layout XLA produces with one optimized sort, instead of pointer-chasing
+collision chains. Duplicate build keys are contiguous runs, the analog of
+PositionLinks chains:
 
-  build:  sort by (hash, ...), keep permutation
-  probe:  lo = searchsorted(left), hi = searchsorted(right)  -> match ranges
+  build:  sort by (hash, ...), keep permutation; a bucket directory over
+          the top hash bits gives each bucket its range of sorted positions
+  probe:  lo, hi = directory[bucket], directory[bucket + 1] -> candidates
   1:N expansion: static-capacity output; row r of the output maps back to
-  probe row via searchsorted over cumulative match counts (cumsum trick), the
+  probe row by its rank in the cumulative match counts (cumsum trick), the
   static-shape answer to dynamic join fan-out.
+
+Both the directory and the expansion's slot-to-probe map rank SORTED
+queries (an arange) in a sorted array: `sorted_rank` merges the two
+through sort instead of searchsorted's log2(n) rounds of gathers.
 
 Hash collisions are resolved by verifying actual key equality after gather.
 Composite keys hash-combine then verify each part.
@@ -87,6 +92,37 @@ def _pick_bucket_bits(capacity: int) -> int:
     return min(bits, 22)  # cap the directory at 4M entries
 
 
+def sorted_rank(a: jnp.ndarray, nq: int, side: str = "left") -> jnp.ndarray:
+    """`jnp.searchsorted(a, arange(nq), side)` as int32, exactly, for a
+    non-decreasing, non-negative integer `a`, by a merge through sort.
+
+    Each value and each query becomes ONE integer key
+    with a tag bit below it (query 2q against element 2a+1 for 'left',
+    so an equal element sorts after the query; 2q+1 against 2a for
+    'right'); one sort merges them, a cumsum of the element tags counts
+    the elements before every query, and a second sort of
+    (tag << shift) | count brings the queries' counts to the front in
+    query order (equal keys are equal values, so neither sort need be
+    stable). Single-operand sorts only: no gather rounds, no scatter."""
+    n = a.shape[0]
+    # a value at or past nq ranks every query alike: clamped to nq, the
+    # keys (<= 2nq + 1) and the counts (<= n) fit int32 below 2^30
+    dt, shift = (
+        (jnp.int32, 30) if max(n, nq) < 1 << 30 else (jnp.int64, 62)
+    )
+    v = jnp.minimum(a, nq).astype(dt)
+    q = jnp.arange(nq, dtype=dt)
+    if side == "left":
+        keys = jnp.concatenate([2 * q, 2 * v + 1])
+    else:
+        keys = jnp.concatenate([2 * q + 1, 2 * v])
+    merged = jax.lax.sort(keys)
+    elem = merged & 1 if side == "left" else 1 - (merged & 1)
+    before = jnp.cumsum(elem, dtype=dt)  # a query's own tag adds nothing
+    ranks = jax.lax.sort((elem << shift) | before)[:nq]
+    return (ranks & ((1 << shift) - 1)).astype(jnp.int32)
+
+
 def sorted_probe_layout() -> str:
     """Which probe layout build_sorted produces now: 'directory' unless
     PRESTO_TPU_JOIN_PROBE says otherwise or the join_probe breaker is
@@ -110,8 +146,11 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     then TWO gathers (bucket_start[b], bucket_start[b+1]) instead of
     jnp.searchsorted's ~log2(n) serial gather rounds — binary search is
     the worst memory-access shape for the TPU; a directory lookup is a
-    plain vectorized gather. Candidates inside a bucket that carry a
-    different hash are rejected by the existing true-key-equality check."""
+    plain vectorized gather. The directory itself is the rank of every
+    bucket id in the sorted bucket ids, built by a merge through sort
+    (`sorted_rank`), not by a search. Candidates inside a bucket that
+    carry a different hash are rejected by the existing true-key-equality
+    check."""
     with jax.named_scope("hash"):
         keys = [evaluate(e, page) for e in key_exprs]
         live = page.live_mask()
@@ -135,14 +174,13 @@ def build_sorted(page: Page, key_exprs) -> BuildSide:
     bits = _pick_bucket_bits(page.capacity)
     nb = 1 << bits
     bucket = (sh >> np.uint64(64 - bits)).astype(jnp.int32)
-    # directory from the SORTED bucket ids via vectorized binary search —
-    # pure gather rounds. (A bincount/scatter-add builds the same counts
-    # but XLA:TPU lowers large scatters to a serial loop; at a 1.5M-row
-    # build side that serialization dominates the whole join.)
+    # directory from the SORTED bucket ids by a merge with the sorted
+    # bucket range. (A bincount/scatter-add builds the same counts but
+    # XLA:TPU lowers large scatters to a serial loop; a binary search
+    # is ~log2(n) gather rounds over 2^bits + 1 queries.) Dead rows
+    # (MAX_HASH) land in bucket nb - 1; _probe_ranges clamps them off.
     with jax.named_scope("directory"):
-        starts = jnp.searchsorted(
-            bucket, jnp.arange(nb + 1, dtype=jnp.int32), side="left"
-        ).astype(jnp.int32)
+        starts = sorted_rank(bucket, nb + 1, "left")
     return BuildSide(
         sh, order, page, tuple(keys), page.count, starts, bits,
         value_hashed=value_hashed,
@@ -337,9 +375,8 @@ def join_expand(
         starts = offsets - counts
 
         out_i = jnp.arange(out_capacity, dtype=jnp.int32)
-        src = jnp.searchsorted(
-            offsets, out_i, side="right"
-        ).astype(jnp.int32)
+        # output slot -> probe row: the slot's rank in the offsets
+        src = sorted_rank(offsets, out_capacity, "right")
         src = jnp.minimum(src, probe.capacity - 1)
         within = out_i - starts[src]
         in_bounds = out_i < total

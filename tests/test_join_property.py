@@ -13,11 +13,13 @@ from presto_tpu.connectors.memory import MemoryCatalog
 from presto_tpu.exec.breaker import BREAKERS
 from presto_tpu.expr.ir import col
 from presto_tpu.ops import ragged
+from presto_tpu.ops import join as J
 from presto_tpu.ops.join import (
     build_sorted,
     join_expand,
     join_n1,
     semi_match_mask,
+    sorted_rank,
 )
 from presto_tpu.page import Block, Page, round_capacity
 from presto_tpu.session import Session
@@ -237,6 +239,91 @@ def test_varchar_cross_dictionary_traced_join(shape):
     else:
         got = s.query("select w from p where pk in (select k from b)").rows()
         assert sorted(got) == [(w,) for w, _ in want]
+
+
+# ---------------------------------------------------------------------------
+# ranking sorted queries: the bucket directory, join_expand's slot map
+# ---------------------------------------------------------------------------
+
+
+def _rank_case(case, rng):
+    """(sorted values, nq) of one shape the join programs rank."""
+    if case == "empty":
+        return np.zeros(0, np.int32), 9
+    if case == "one":
+        return np.array([3], np.int32), 7
+    if case == "no_queries":
+        return np.array([0, 2, 2], np.int32), 0
+    if case == "duplicates":  # long runs of equal values
+        return np.sort(rng.integers(0, 6, 500)).astype(np.int32), 8
+    if case == "empty_ranges":  # most queries fall between two values
+        return np.sort(rng.choice([0, 40, 41, 97], 300)).astype(np.int32), 128
+    if case == "past_the_end":  # values at and far beyond the last query
+        return np.sort(rng.integers(0, 3 * 64, 400)).astype(np.int32), 64
+    if case == "all_dead":  # an empty build: every row in the last bucket
+        nb = 1 << 9
+        return np.full(256, nb - 1, np.int32), nb + 1
+    if case == "clamped_offsets":  # int64 offsets whose total passes nq
+        counts = rng.integers(0, 5, 300)
+        return np.cumsum(counts).astype(np.int64), 256
+    raise AssertionError(case)
+
+
+@pytest.mark.parametrize("side", ["left", "right"])
+@pytest.mark.parametrize(
+    "case",
+    ["empty", "one", "no_queries", "duplicates", "empty_ranges",
+     "past_the_end", "all_dead", "clamped_offsets"],
+)
+def test_sorted_rank_is_searchsorted(case, side):
+    """The merge gives np.searchsorted's ranks exactly, as int32."""
+    a, nq = _rank_case(case, np.random.default_rng(len(case)))
+    want = np.searchsorted(a, np.arange(nq), side=side)
+    got = sorted_rank(jnp.asarray(a), nq, side)
+    assert got.dtype == jnp.int32
+    assert np.asarray(got).tolist() == want.tolist(), (case, side)
+
+
+@pytest.mark.parametrize("capacity,count", [(1000, 1000), (4096, 3000), (300, 0)])
+def test_directory_equals_the_searched_one(capacity, count):
+    """build_sorted's bucket_start is the directory jnp.searchsorted
+    built, dead rows (MAX_HASH, the last bucket) included."""
+    rng = np.random.default_rng(capacity)
+    k = rng.integers(0, capacity // 3 + 1, capacity).astype(np.int64)
+    bs = build_sorted(_page({"k": (k, T.BIGINT, None)}, count=count),
+                      (col("k", T.BIGINT),))
+    bits = bs.bucket_bits
+    bucket = (bs.sorted_hash >> np.uint64(64 - bits)).astype(jnp.int32)
+    want = jnp.searchsorted(
+        bucket, jnp.arange((1 << bits) + 1, dtype=jnp.int32), side="left"
+    )
+    assert np.asarray(bs.bucket_start).tolist() == np.asarray(want).tolist()
+
+
+def test_expand_overflow_equals_the_search_form(monkeypatch):
+    """At an out_capacity below the candidates' total, join_expand's
+    overflow and rows are the search form's, slot for slot."""
+    rng = np.random.default_rng(7)
+    bk = rng.integers(0, 40, 600).astype(np.int64)
+    pk = rng.integers(0, 50, 900).astype(np.int64)
+    b = _page({"k": (bk, T.BIGINT, None), "v": (np.arange(600), T.BIGINT, None)})
+    p = _page({"k": (pk, T.BIGINT, None), "w": (np.arange(900), T.BIGINT, None)},
+              count=850)
+    keys = (col("k", T.BIGINT),)
+
+    def run():
+        out, ov = join_expand(p, build_sorted(b, keys), keys, ("w",),
+                              [("v", "bv")], 4096)
+        return int(ov), int(out.count), [
+            np.asarray(out.block(n).data).tolist() for n in ("w", "bv")
+        ]
+
+    merged = run()
+    monkeypatch.setattr(J, "sorted_rank", lambda a, nq, side: jnp.searchsorted(
+        a, jnp.arange(nq, dtype=jnp.int32), side=side).astype(jnp.int32))
+    searched = run()
+    assert merged[0] > 0  # the total passed the capacity
+    assert merged == searched
 
 
 # ---------------------------------------------------------------------------
