@@ -333,17 +333,21 @@ class Executor:
         method = getattr(self, f"_exec_{type(node).__name__.lower()}")
         return method(node, *pages)
 
-    def _shrink(self, page: Page, node: "N.PlanNode" = None) -> Page:
+    def _shrink(
+        self, page: Page, node: "N.PlanNode" = None, n: Optional[int] = None
+    ) -> Page:
         """Slice page capacity down to the live row count's bucket.
 
         Reading the count is a BLOCKING host sync: the host waits for
         everything queued on the device and then for a transfer, and the
         device idles until the next dispatch. So the sync is only paid
         when shrinking can plausibly win: the page is big AND the CBO
-        expects the live count to be well under capacity."""
+        expects the live count to be well under capacity. `n`: the count,
+        where the caller read it already."""
         if not self._shrink_reads(page, node):
             return page
-        n = int(host_read(page.count))
+        if n is None:
+            n = int(host_read(page.count))
         cap = round_capacity(max(n, 1))
         if cap >= page.capacity:
             return page
@@ -1028,11 +1032,17 @@ class Executor:
                 "grouped_aggregate_sorted", (node, mg, me, runs),
                 lambda: lambda p: grouped_aggregate_sorted(
                     p, node.group_exprs, node.group_names, node.aggs, mg,
-                    node.mask, max_elems=me, runs=runs,
+                    node.mask, max_elems=me, runs=runs, status=runs,
                 ),
             )
             out = fn(page)
-            true_groups = int(host_read(out.count))
+            if runs:
+                # the group count and the run-sum form's order test in
+                # ONE read
+                out, status = out
+                true_groups, presorted = (int(x) for x in host_read(status))
+            else:
+                true_groups, presorted = int(host_read(out.count)), -1
             if true_groups > max_groups:
                 max_groups = round_capacity(true_groups)
                 self._agg_groups.put(node, max_groups)
@@ -1057,11 +1067,16 @@ class Executor:
             break
         # the count the loop read anyway, and the slots it last ran with
         self._span_note(groups=true_groups, max_groups=max_groups)
+        if presorted >= 0:  # the run-sum form ran: did it skip its sort
+            self._span_note(presorted=presorted == 1)
+            self._append_detail(
+                node, f"presorted={'true' if presorted else 'false'}"
+            )
         if true_groups > HASH_MAX_GROUPS_HOST and learned is None:
             # no retry taught it (the first guess held): still past the
             # hash-slot cap, so the next execution skips that attempt
             self._agg_groups.put(node, max_groups)
-        return self._shrink(out, node)
+        return self._shrink(out, node, true_groups)
 
     def _pallas_groupby_on(self) -> bool:
         """The `pallas_groupby` knob, resolved at first use (None = auto:

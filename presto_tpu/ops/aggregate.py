@@ -678,16 +678,35 @@ def _exclusive_diff(c):
     return c - jnp.concatenate([jnp.zeros((1,), c.dtype), c[:-1]])
 
 
+def _in_key_order(sort_keys) -> jnp.ndarray:
+    """Whether `lax.sort` by `sort_keys` (the dead flag, then each key's
+    operands) would leave every row where it is, up to ties: no live row
+    after a dead one, and each live row's key tuple no less than the one
+    before it. A dead row's keys are never compared: a padding tail holds
+    whatever its storage held."""
+    dead = sort_keys[0]
+    keys_ok = jnp.ones(dead.shape[0] - 1, jnp.bool_)
+    for k in reversed(sort_keys[1:]):
+        keys_ok = (k[:-1] < k[1:]) | ((k[:-1] == k[1:]) & keys_ok)
+    return jnp.all((dead[:-1] <= dead[1:]) & ((dead[1:] != 0) | keys_ok))
+
+
 def _grouped_aggregate_runs(
     page: Page, live, keys, ins, group_names, aggs, max_groups: int
-) -> Page:
+):
     """`grouped_aggregate_sorted` with no gather and no scatter: ONE sort
     by the key columns themselves carries each aggregate's contribution,
     prefix sums run over the rows in key order, and a second sort moves
     each run's LAST row to the front, where a group's sum is the
     difference of two neighbouring prefix sums (exact: int64 wraps as
     `segment_sum` does, and decimal sums go as 32-bit halves in int64
-    prefix sums, good for 2^31 rows, into the same two lanes)."""
+    prefix sums, good for 2^31 rows, into the same two lanes).
+
+    Rows that arrive in key order (a table stored by its key, as
+    lineitem by `l_orderkey`) skip the first sort: the program tests the
+    order on the device and branches on it (`lax.cond`, so one branch
+    runs). A sum over a run does not depend on the order inside it, so
+    both branches give the same page. Returns (page, in_order)."""
     from . import decimal128 as d128
 
     cap = page.capacity
@@ -726,7 +745,13 @@ def _grouped_aggregate_runs(
                 ("x", id(v.data), id(v.valid)),
             )
         plan.append((flag, val))
-    srt = jax.lax.sort(tuple(operands), num_keys=num_keys, is_stable=False)
+    in_order = _in_key_order(operands[:num_keys])
+    srt = jax.lax.cond(
+        in_order,
+        lambda ops: ops,
+        lambda ops: jax.lax.sort(ops, num_keys=num_keys, is_stable=False),
+        tuple(operands),
+    )
 
     with jax.named_scope("group.runs"):
         live_s = srt[0] == 0
@@ -825,7 +850,7 @@ def _grouped_aggregate_runs(
             _finalize(spec, raw, masked_count > 0, v.type, v.dict_id)
         )
         names.append(spec.name)
-    return Page.from_blocks(blocks, names, count=num_live_groups)
+    return Page.from_blocks(blocks, names, count=num_live_groups), in_order
 
 
 def grouped_aggregate_sorted(
@@ -837,21 +862,27 @@ def grouped_aggregate_sorted(
     pre_mask=None,
     max_elems: int = 128,
     runs: Optional[bool] = None,
-) -> Page:
+    status: bool = False,
+):
     """General grouped aggregation via hash-sort + run detection.
 
     max_groups is the static output capacity (planner-chosen; overflow beyond
     it is a query error the host checks via the returned count). `runs`
     picks the run-sum form (`_grouped_aggregate_runs`) where the shape is
-    eligible; None = by the page's capacity (RUNS_MIN_ROWS)."""
+    eligible; None = by the page's capacity (RUNS_MIN_ROWS). With `status`
+    the result is (page, status): an int32[2] of the live group count and
+    whether the run-sum form found its rows in key order (1: it skipped
+    its first sort; 0: it sorted; -1: the form did not run), one array
+    so that the host reads both at once."""
     live = _masked_live(page, pre_mask)
     keys, ins = _eval_inputs(page, group_exprs, aggs)
     if runs is None:
         runs = page.capacity >= RUNS_MIN_ROWS
     if runs and page.capacity > 1 and _runs_eligible(keys, ins, aggs):
-        return _grouped_aggregate_runs(
+        out, in_order = _grouped_aggregate_runs(
             page, live, keys, ins, group_names, aggs, max_groups
         )
+        return _with_status(out, in_order.astype(jnp.int32), status)
 
     with jax.named_scope("group.hash_sort"):
         h = hash_rows(keys)
@@ -1152,7 +1183,16 @@ def grouped_aggregate_sorted(
             )
         )
         names.append("$collect_need")
-    return Page.from_blocks(blocks, names, count=num_live_groups)
+    out = Page.from_blocks(blocks, names, count=num_live_groups)
+    return _with_status(out, jnp.int32(-1), status)
+
+
+def _with_status(out: Page, presorted, status: bool):
+    """`grouped_aggregate_sorted`'s result: the page, or with `status`
+    (page, [live group count, presorted])."""
+    if not status:
+        return out
+    return out, jnp.stack([out.count, presorted])
 
 
 # ---------------------------------------------------------------------------

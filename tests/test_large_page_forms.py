@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 
 from presto_tpu import types as T
-from presto_tpu.expr.ir import col
+from presto_tpu.expr.ir import Call, Literal, col
 from presto_tpu.ops import aggregate as agg
 from presto_tpu.ops.aggregate import AggSpec, grouped_aggregate_sorted
 from presto_tpu.page import Block, Page
@@ -97,6 +97,78 @@ def test_runs_equal_scatter_form(n, pad, ndv, null_keys, null_inputs, max_groups
     assert _rows(got) == _rows(want)
 
 
+def _ordered_page(order, null_keys, n=600, capacity=1024):
+    """`n` live rows laid out by `order` ("sorted": by the whole key,
+    NULLs last, as the run-sum form's sort puts them; "first_key": by
+    `k1` alone; "shuffled"), then a dead tail whose storage is garbage."""
+    rng = np.random.default_rng(n + null_keys)
+    k1 = rng.integers(-50, 50, capacity).astype(np.int64)
+    k2 = rng.integers(0, 3, capacity).astype(np.int32)
+    k1_valid = rng.random(capacity) < 0.8 if null_keys else None
+    if order != "shuffled":
+        null = np.zeros(n, bool) if k1_valid is None else ~k1_valid[:n]
+        k1_value = np.where(null, 0, k1[:n])
+        by = (k1_value, null) if order == "first_key" else (
+            k2[:n], k1_value, null
+        )
+        rows = np.lexsort(by)
+        k1[:n], k2[:n] = k1[rows], k2[rows]
+        if k1_valid is not None:
+            k1_valid[:n] = k1_valid[rows]
+    blocks = [
+        Block.from_numpy(k1, T.BIGINT, valid=k1_valid),
+        Block.from_numpy(k2, T.INTEGER),
+        Block.from_numpy(
+            rng.integers(-(1 << 40), 1 << 40, capacity).astype(np.int64),
+            T.BIGINT, valid=rng.random(capacity) < 0.7,
+        ),
+        Block.from_numpy(
+            rng.integers(-(1 << 62), 1 << 62, capacity).astype(np.int64), DEC
+        ),
+    ]
+    return Page.from_blocks(blocks, ["k1", "k2", "x", "d"], count=n)
+
+
+K2_POSITIVE = Call("gt", (col("k2", T.INTEGER), Literal(0, T.INTEGER)), T.BOOLEAN)
+
+
+@pytest.mark.parametrize(
+    "order,null_keys,mask,presorted",
+    [
+        ("sorted", False, None, 1),  # the dead tail's garbage is not read
+        ("shuffled", False, None, 0),
+        ("sorted", False, K2_POSITIVE, 0),  # dead rows in the middle
+        ("sorted", True, None, 1),  # the NULL flag is part of the key
+        ("first_key", False, None, 0),  # `k2` out of order inside a run
+    ],
+)
+def test_runs_skip_the_sort_of_rows_in_key_order(
+    order, null_keys, mask, presorted, monkeypatch
+):
+    """The run-sum form tests its rows' order on the device and skips its
+    first sort where that sort would move nothing: the same answer as the
+    scatter form either way, and the status says which branch ran."""
+    page = _ordered_page(order, null_keys)
+    want = grouped_aggregate_sorted(page, *KEYS, AGGS, 1024, mask, runs=False)
+    got, status = jax.jit(
+        lambda p: grouped_aggregate_sorted(
+            p, *KEYS, AGGS, 1024, mask, runs=True, status=True
+        )
+    )(page)
+    assert np.asarray(status).tolist() == [int(want.count), presorted]
+    assert int(got.count) == int(want.count)
+    assert _rows(got) == _rows(want)
+    # without jit `lax.cond` runs the one branch it picks: count the sorts
+    sorts = []
+    real = jax.lax.sort
+    monkeypatch.setattr(
+        jax.lax, "sort", lambda *a, **k: sorts.append(1) or real(*a, **k)
+    )
+    with jax.disable_jit():
+        grouped_aggregate_sorted(page, *KEYS, AGGS, 1024, mask, runs=True)
+    assert len(sorts) == 2 - presorted
+
+
 def test_runs_overflow_reports_true_count():
     """More groups than slots: the count is still the true one (the
     executor's retry reads it), as in the scatter form."""
@@ -108,10 +180,7 @@ def test_runs_overflow_reports_true_count():
 
 def test_runs_mask_and_dead_rows():
     page = _page(5, 900, 1024, 30, True, True)
-    mask = col("k2", T.INTEGER)  # not boolean: build a comparison instead
-    from presto_tpu.expr.ir import Call, Literal
-
-    mask = Call("gt", (col("k2", T.INTEGER), Literal(0, T.INTEGER)), T.BOOLEAN)
+    mask = K2_POSITIVE
     want = grouped_aggregate_sorted(page, *KEYS, AGGS, 256, mask, runs=False)
     got = grouped_aggregate_sorted(page, *KEYS, AGGS, 256, mask, runs=True)
     assert _rows(got) == _rows(want) and int(got.count) == int(want.count)

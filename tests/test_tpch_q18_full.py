@@ -303,8 +303,10 @@ def large_pages(monkeypatch):
 def test_large_page_forms_give_the_reference(
     served, no_hash_slot, large_pages, quantity
 ):
-    """Q18 through the forms SF10 takes: equal to the reference, and the
-    subquery's group-by starts from the planner's estimate (no retry)."""
+    """Q18 through the forms SF10 takes: equal to the reference, the
+    subquery's group-by starts from the planner's estimate (no retry), and
+    its rows, which the connector stores by `l_orderkey`, skip the run-sum
+    form's first sort."""
     TRACES.reset()
     cols, rows = served.execute(sql_text(quantity) + " -- large pages")
     (trace,) = TRACES.recent()
@@ -319,6 +321,7 @@ def test_large_page_forms_give_the_reference(
         if s.name == "Aggregate" and s.attrs.get("groups") == n_orders
     ]
     assert sub["strategy"] == "hash-sort" and "retries" not in sub
+    assert sub["presorted"] is True
     # the group-by is traced once a process, the masks run every time
     # there is something to look for
     if quantity == THRESHOLDS[0]:

@@ -49,7 +49,7 @@ NOTED = (
     "strategy", "partial_strategy", "programs", "compact",
     "compact_capacity", "dyn_pruned", "dyn_strategy", "est_rows",
     "out_capacity", "probe_rows", "build_rows", "out_rows", "groups",
-    "max_groups", "retries", "batches", "uploads", "upload_s",
+    "max_groups", "presorted", "retries", "batches", "uploads", "upload_s",
     "link_idle_s", "inflight_peak_bytes", "scan_s", "ready_error",
 )
 FOLDED = ("batches", "scan_s")
@@ -99,7 +99,10 @@ def rows_of(trace):
 
 def fmt(values, digits=1):
     """Mean of the values that are there; a range where they differ."""
-    values = [v for v in values if v is not None]
+    values = [
+        str(v).lower() if isinstance(v, bool) else v
+        for v in values if v is not None
+    ]
     if not values:
         return ""
     if all(isinstance(v, str) for v in values):
